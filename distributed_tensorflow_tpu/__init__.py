@@ -17,10 +17,6 @@ Conventional import:
     import distributed_tensorflow_tpu as dtx
 """
 
-from distributed_tensorflow_tpu.utils import jax_compat as _jax_compat
-
-_jax_compat.install()   # backfill jax.shard_map & friends on old jax
-
 from distributed_tensorflow_tpu.cluster.topology import (
     Topology,
     DeviceAssignment,

@@ -167,17 +167,15 @@ def make_mesh(axes: Mapping[str, int] | Sequence[tuple] | None = None,
     names, sizes = _normalize_axes(axes, len(devs))
     # Auto axis types: the framework works in GSPMD mode (sharding
     # constraints + propagation), not the explicit-sharding-in-types mode.
-    # Older jax (< AxisType) has only GSPMD meshes — omit the kwarg there.
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    kwargs = ({"axis_types": (axis_type_cls.Auto,) * len(names)}
-              if axis_type_cls is not None else {})
+    axis_types = (jax.sharding.AxisType.Auto,) * len(names)
     if devices is None:
-        try:
-            return jax.make_mesh(sizes, names, **kwargs)
-        except (ValueError, RuntimeError):
-            pass  # fall through to explicit reshaping
+        # jax.make_mesh picks the ICI-aware device order; a shape it
+        # cannot lay out raises rather than silently taking enumeration
+        # order (which changes which chips are neighbours).
+        return jax.make_mesh(sizes, names, axis_types=axis_types)
+    # explicit devices: the caller chose the order
     arr = np.asarray(devs, dtype=object).reshape(sizes)
-    return Mesh(arr, names, **kwargs)
+    return Mesh(arr, names, axis_types=axis_types)
 
 
 def mesh_axis_size(mesh: Mesh, *names: str) -> int:
@@ -237,10 +235,7 @@ def make_hybrid_mesh(dcn_axes: Mapping[str, int],
     ici_names, ici_sizes = _normalize_axes(
         ici_axes, len(devs) // math.prod(dcn_sizes))
     names = dcn_names + ici_names
-    # Auto axis types when the running jax has them (see make_mesh).
-    axis_type_cls = getattr(jax.sharding, "AxisType", None)
-    kwargs = ({"axis_types": (axis_type_cls.Auto,) * len(names)}
-              if axis_type_cls is not None else {})
+    axis_types = (jax.sharding.AxisType.Auto,) * len(names)
 
     multi_slice = len({getattr(d, "slice_index", 0) for d in devs}) > 1
     if multi_slice:
@@ -250,6 +245,6 @@ def make_hybrid_mesh(dcn_axes: Mapping[str, int],
         dcn_shape = tuple(dcn_sizes) + (1,) * len(ici_sizes)
         arr = mesh_utils.create_hybrid_device_mesh(
             ici_shape, dcn_shape, devices=devs)
-        return Mesh(arr, names, **kwargs)
+        return Mesh(arr, names, axis_types=axis_types)
     arr = np.asarray(devs, dtype=object).reshape(dcn_sizes + ici_sizes)
-    return Mesh(arr, names, **kwargs)
+    return Mesh(arr, names, axis_types=axis_types)

@@ -13,26 +13,44 @@ record each); ``write_records`` produces it.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 import numpy as np
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libdtx_pipeline.so")
-_SRC_PATH = os.path.join(_NATIVE_DIR, "pipeline.cc")
+_PKG_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_SRC_PATH = os.path.join(_PKG_DIR, "native", "pipeline.cc")
+_BUILD_DIR = os.path.join(os.path.dirname(_PKG_DIR), ".cache", "native")
 
 _lib = None
 _lib_lock = threading.Lock()
 
 
-def _build_so():
-    subprocess.run(
-        ["g++", "-O3", "-fPIC", "-shared", "-pthread", "-std=c++17",
-         "-o", _SO_PATH, _SRC_PATH, "-lz"],
-        check=True, capture_output=True)
+def _so_path() -> str:
+    """The library is named by the hash of its source, so the file that
+    loads was built from exactly the ``pipeline.cc`` in this checkout —
+    a stale or copied-in build has another name and is never picked up."""
+    with open(_SRC_PATH, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libdtx_pipeline-{digest}.so")
+
+
+def _build_so(so_path: str):
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    # build under a per-process name, then rename: concurrent workers
+    # never load a half-written file
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", "-O3", "-fPIC", "-shared", "-pthread", "-std=c++17",
+             "-o", tmp, _SRC_PATH, "-lz"],
+            check=True, capture_output=True)
+        os.replace(tmp, so_path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
 
 
 def _load():
@@ -40,10 +58,10 @@ def _load():
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_SO_PATH)
-                or os.path.getmtime(_SO_PATH) < os.path.getmtime(_SRC_PATH)):
-            _build_so()
-        lib = ctypes.CDLL(_SO_PATH)
+        so_path = _so_path()
+        if not os.path.exists(so_path):
+            _build_so(so_path)
+        lib = ctypes.CDLL(so_path)
         lib.dtx_pipeline_create.restype = ctypes.c_void_p
         lib.dtx_pipeline_create.argtypes = [
             ctypes.POINTER(ctypes.c_char_p), ctypes.c_int, ctypes.c_int64,

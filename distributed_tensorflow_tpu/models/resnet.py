@@ -28,8 +28,6 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from distributed_tensorflow_tpu.utils.jax_compat import (
-    safe_donate_argnums)
 import optax
 from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -250,7 +248,7 @@ def make_sharded_train_step(cfg: ResNetConfig, mesh: Mesh,
             make_train_step(cfg, model, tx),
             in_shardings=(state_shardings, batch_shardings),
             out_shardings=(state_shardings, replicated),
-            donate_argnums=safe_donate_argnums((0,)))
+            donate_argnums=(0,))
 
     def wrapped(state, batch):
         with mesh:

@@ -32,8 +32,6 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from distributed_tensorflow_tpu.utils.jax_compat import (
-    safe_donate_argnums)
 import optax
 from flax import linen as nn
 from flax.linen import partitioning as nn_partitioning
@@ -132,9 +130,9 @@ class TransformerConfig:
     # (ops/fused_ce.py) — logits tiles never leave VMEM. On sharded
     # meshes the kernels run per-shard under shard_map with a cross-
     # shard logsumexp merge for tp-sharded vocabs
-    # (ops/fused_ce.py sharded_fused_cross_entropy); meshes whose
-    # shapes don't divide fall back to the scan path, whose einsums
-    # GSPMD partitions natively. "kernel" implies the fused loss even
+    # (ops/fused_ce.py sharded_fused_cross_entropy); a mesh whose
+    # shapes don't divide raises (use "scan" there, whose einsums
+    # GSPMD partitions natively). "kernel" implies the fused loss even
     # when loss_chunks == 0.
     loss_impl: str = "scan"
     loss_block_n: int = 512
@@ -585,40 +583,34 @@ def make_loss_fn(cfg: TransformerConfig, model: TransformerLM):
     # The kernel CE path runs everywhere: plain on a single chip,
     # per-shard under shard_map on sharded meshes (tokens over
     # dcn/dp/fsdp/sp, vocab over tp with a cross-shard logsumexp merge
-    # — ops/fused_ce.py sharded_fused_cross_entropy). Only meshes whose
-    # shard counts don't divide the batch/seq/vocab shapes fall back to
-    # the scan path, whose einsums GSPMD partitions natively.
-    # loss_impl="kernel" implies a FUSED loss in every case: the
-    # fallback uses the scan path with a default chunk count rather
-    # than ever materializing full (B, S, vocab) logits.
+    # — ops/fused_ce.py sharded_fused_cross_entropy). A mesh whose
+    # shard counts don't divide the batch/seq/vocab shapes is an error
+    # when the kernel was asked for by name: the step builds the loss
+    # it names or raises, it never quietly becomes the scan path.
+    # loss_impl="kernel" implies a FUSED loss even when loss_chunks == 0.
     use_kernel = cfg.loss_impl == "kernel"
-    fused = cfg.loss_chunks > 0 or cfg.loss_impl == "kernel"
-    if cfg.loss_chunks > 0:
-        scan_chunks = cfg.loss_chunks
-    else:
-        # kernel→scan fallback default: the largest power of two that
-        # divides the sequence length, capped at 8 (a blind 8 would
-        # crash at trace time on seq lens not divisible by 8)
-        scan_chunks = 1
-        while (scan_chunks < 8
-               and cfg.max_seq_len % (scan_chunks * 2) == 0):
-            scan_chunks *= 2
+    fused = cfg.loss_chunks > 0 or use_kernel
 
-    def _kernel_mesh_ok(B, S):
+    def _check_kernel_mesh(B, S):
         mesh = cfg.mesh
         if mesh is None or mesh.size == 1:
-            return True
+            return
         n_batch = 1
         for a in ("dcn", "dp", "fsdp"):
             if a in mesh.shape:
                 n_batch *= mesh.shape[a]
         sp = mesh.shape.get("sp", 1)
         tp = mesh.shape.get("tp", 1)
-        return (B % n_batch == 0 and S % sp == 0
-                and cfg.vocab_size % tp == 0)
+        if B % n_batch or S % sp or cfg.vocab_size % tp:
+            raise ValueError(
+                f"loss_impl='kernel' on mesh {dict(mesh.shape)}: batch "
+                f"{B} / seq {S} / vocab {cfg.vocab_size} must divide by "
+                f"{n_batch} data shards / sp={sp} / tp={tp}; use "
+                f"loss_impl='scan' for shapes the kernel cannot shard")
 
     def objective(out, params, tokens):
-        if use_kernel and _kernel_mesh_ok(*out.shape[:2]):
+        if use_kernel:
+            _check_kernel_mesh(*out.shape[:2])
             return kernel_next_token_loss(
                 out, params["embed"], tokens, compute_dtype=cfg.dtype,
                 block_n=cfg.loss_block_n, block_v=cfg.loss_block_v,
@@ -626,7 +618,7 @@ def make_loss_fn(cfg: TransformerConfig, model: TransformerLM):
         if fused:
             return fused_next_token_loss(
                 out, params["embed"], tokens,
-                num_chunks=scan_chunks, compute_dtype=cfg.dtype,
+                num_chunks=cfg.loss_chunks, compute_dtype=cfg.dtype,
                 chunk_policy=cfg.loss_chunk_policy)
         return next_token_loss(out, tokens)
 
@@ -869,7 +861,7 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh,
             step,
             in_shardings=(state_shardings, batch_shardings),
             out_shardings=(state_shardings, replicated),
-            donate_argnums=safe_donate_argnums((0,)))
+            donate_argnums=(0,))
 
     def wrapped_step(state, batch):
         with mesh, nn_partitioning.axis_rules(rules):
@@ -959,7 +951,7 @@ def _make_bucketed_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
         shard_step,
         in_shardings=(state_shardings, batch_shardings),
         out_shardings=(state_shardings, replicated),
-        donate_argnums=safe_donate_argnums((0,)))
+        donate_argnums=(0,))
 
     def wrapped_step(state, batch):
         with mesh:
@@ -1066,7 +1058,7 @@ def _make_zero_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
         shard_step,
         in_shardings=(state_shardings, batch_shardings),
         out_shardings=(state_shardings, replicated),
-        donate_argnums=safe_donate_argnums((0,)))
+        donate_argnums=(0,))
 
     def wrapped_step(state, batch):
         with mesh:
@@ -1141,7 +1133,7 @@ def _make_zero_gspmd_train_step(cfg: TransformerConfig, mesh: Mesh,
             train_step,
             in_shardings=(state_shardings, batch_shardings),
             out_shardings=(state_shardings, replicated),
-            donate_argnums=safe_donate_argnums((0,)))
+            donate_argnums=(0,))
 
     def wrapped_step(state, batch):
         with mesh, nn_partitioning.axis_rules(rules):
@@ -1472,7 +1464,7 @@ def make_pipelined_train_step(cfg: TransformerConfig, mesh: Mesh,
         step_jit = jax.jit(train_step,
                            in_shardings=(state_shardings, batch_shardings),
                            out_shardings=(state_shardings, replicated),
-                           donate_argnums=safe_donate_argnums((0,)))
+                           donate_argnums=(0,))
 
     def wrapped(state, batch):
         with mesh:

@@ -26,8 +26,6 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from distributed_tensorflow_tpu.utils.jax_compat import (
-    safe_donate_argnums)
 import numpy as np
 import optax
 from flax import linen as nn
@@ -208,7 +206,7 @@ def make_sharded_train_step(cfg: WideDeepConfig, mesh: Mesh,
         step_jit = jax.jit(step,
                            in_shardings=(state_shardings, batch_shardings),
                            out_shardings=(state_shardings, replicated),
-                           donate_argnums=safe_donate_argnums((0,)))
+                           donate_argnums=(0,))
 
     def wrapped(state, batch):
         with mesh, nn_partitioning.axis_rules(rules):
@@ -347,7 +345,7 @@ def make_embedding_train_step(cfg: WideDeepConfig, mesh: Mesh,
         step_jit = jax.jit(train_step,
                            in_shardings=(state_shardings, batch_shardings),
                            out_shardings=(state_shardings, replicated),
-                           donate_argnums=safe_donate_argnums((0,)))
+                           donate_argnums=(0,))
 
     def wrapped(state, batch):
         with mesh:

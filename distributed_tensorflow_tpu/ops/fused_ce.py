@@ -45,8 +45,7 @@ _NEG_BIG = -0.7 * float(jnp.finfo(jnp.float32).max)
 # merged backward legitimately wants ~24 MiB (fp32 accumulator scratch
 # + double-buffered fp32 alias blocks), so raise the cap for these
 # kernels only.
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams",
-                           getattr(pltpu, "TPUCompilerParams", None))(
+_COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=64 * 1024 * 1024)
 
 

@@ -693,9 +693,7 @@ class Strategy:
         This is the ≙ of the reference's TPUStrategy model (SURVEY §3.4):
         one compiled program per step, Python out of the loop.
         """
-        from distributed_tensorflow_tpu.utils.jax_compat import (
-            safe_donate_argnums)
-        donate = safe_donate_argnums((0,)) if donate_state else ()
+        donate = (0,) if donate_state else ()
         return jax.jit(step_fn, donate_argnums=donate)
 
 

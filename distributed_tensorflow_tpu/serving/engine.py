@@ -93,8 +93,6 @@ from distributed_tensorflow_tpu.serving.kv_cache import (
 from distributed_tensorflow_tpu.serving.scheduler import (
     AdmissionQueue, ContinuousBatchingScheduler, OutOfBlocksError,
     Request, Sequence)
-from distributed_tensorflow_tpu.utils.jax_compat import (
-    safe_donate_argnums)
 
 _pool_epochs = itertools.count()
 
@@ -273,13 +271,13 @@ class InferenceEngine:
                 prefill,
                 in_shardings=(shardings, pool_sh, rep, rep, rep),
                 out_shardings=(rep, pool_sh),
-                donate_argnums=safe_donate_argnums((1,)))
+                donate_argnums=(1,))
             self._decode = jax.jit(
                 decode,
                 in_shardings=(shardings, pool_sh, slotv, slotv,
                               slotv, slotv, slotm),
                 out_shardings=(slotm, pool_sh),
-                donate_argnums=safe_donate_argnums((1,))) \
+                donate_argnums=(1,)) \
                 if decode is not None else None
             # the extend program serves two batch shapes: suffix
             # prefill is (1, E) — too narrow to shard over dp, so it
@@ -290,7 +288,7 @@ class InferenceEngine:
                 in_shardings=(shardings, pool_sh, rep, rep, rep, rep,
                               rep),
                 out_shardings=(rep, pool_sh),
-                donate_argnums=safe_donate_argnums((1,))) \
+                donate_argnums=(1,)) \
                 if extend is not None else None
             self._extend_spec = jax.jit(
                 extend,
@@ -298,12 +296,12 @@ class InferenceEngine:
                               slotm, slotm),
                 out_shardings=(NamedSharding(mesh, P(dp, None, None)),
                                pool_sh),
-                donate_argnums=safe_donate_argnums((1,))) \
+                donate_argnums=(1,)) \
                 if extend is not None else None
             self._copy = jax.jit(
                 copy_fn, in_shardings=(pool_sh, rep, rep),
                 out_shardings=pool_sh,
-                donate_argnums=safe_donate_argnums((0,)))
+                donate_argnums=(0,))
             # migration/spill row movers: gather block rows to a
             # replicated (host-fetchable) array, insert host rows into
             # the sharded pool. No donation on gather — the pool
@@ -314,22 +312,17 @@ class InferenceEngine:
             self._insert = jax.jit(
                 insert_fn, in_shardings=(pool_sh, rep, rep),
                 out_shardings=pool_sh,
-                donate_argnums=safe_donate_argnums((0,)))
+                donate_argnums=(0,))
         else:
-            self._prefill = jax.jit(
-                prefill, donate_argnums=safe_donate_argnums((1,)))
-            self._decode = (jax.jit(
-                decode, donate_argnums=safe_donate_argnums((1,)))
-                if decode is not None else None)
-            self._extend_prefill = (jax.jit(
-                extend, donate_argnums=safe_donate_argnums((1,)))
-                if extend is not None else None)
+            self._prefill = jax.jit(prefill, donate_argnums=(1,))
+            self._decode = (jax.jit(decode, donate_argnums=(1,))
+                            if decode is not None else None)
+            self._extend_prefill = (jax.jit(extend, donate_argnums=(1,))
+                                    if extend is not None else None)
             self._extend_spec = self._extend_prefill
-            self._copy = jax.jit(
-                copy_fn, donate_argnums=safe_donate_argnums((0,)))
+            self._copy = jax.jit(copy_fn, donate_argnums=(0,))
             self._gather = jax.jit(gather_fn)
-            self._insert = jax.jit(
-                insert_fn, donate_argnums=safe_donate_argnums((0,)))
+            self._insert = jax.jit(insert_fn, donate_argnums=(0,))
 
         # shared inference namespace (Model.predict reports here too)
         reg = telemetry.get_registry()

@@ -309,13 +309,13 @@ def run_rollout(args) -> dict:
         RecoverySupervisor, seeded_kill_plan)
     from distributed_tensorflow_tpu.resilience.autoscaler import (
         serving_records_fn)
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
 
     tdir = args.telemetry_dir or tempfile.mkdtemp(prefix="dtx_rollout_")
     os.makedirs(tdir, exist_ok=True)
     ckpt_dir = args.ckpt_dir or os.path.join(tdir, "ckpt")
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(_REPO, ".cache", "dtx_jax_cache"))
+    enable_compile_cache()      # exported: spawned workers share it
     os.environ.setdefault(
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     os.environ.setdefault(

@@ -454,6 +454,9 @@ def main():
                     help="skip the random-routing baseline phase")
     args = ap.parse_args()
     os.makedirs(args.run_dir, exist_ok=True)
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()      # exported: spawned workers share it
 
     router_kill_s = None
     if args.kill_seed is not None:

@@ -227,6 +227,10 @@ def main():
                          "(needs --workers >= 2)")
     args = ap.parse_args()
 
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()      # exported: spawned workers share it
+
     if args.write_ckpt:
         if not args.ckpt_dir:
             ap.error("--write-ckpt requires --ckpt-dir")

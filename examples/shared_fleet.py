@@ -81,6 +81,8 @@ def run_fleet(args) -> dict:
         SharedFleetSupervisor,
     )
     from distributed_tensorflow_tpu.serving.replica import serving_replica
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
     from examples.train_mnist import elastic_worker
 
     tdir = args.telemetry_dir or tempfile.mkdtemp(prefix="shared_fleet_")
@@ -91,9 +93,7 @@ def run_fleet(args) -> dict:
     # and without the cache each incarnation pays a multi-second
     # recompile that both slows the reform and poisons the latency SLO
     # stream with compile-tail completions
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(_REPO, ".cache", "dtx_jax_cache"))
+    enable_compile_cache()      # exported: spawned workers share it
     os.environ.setdefault(
         "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
     os.environ.setdefault(

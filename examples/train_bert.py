@@ -11,13 +11,21 @@ pick (dp / fsdp / tp), with GSPMD inserting the gradient allreduce.
 """
 
 import argparse
+import os
+import sys
 import time
 
 import jax
 
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
+
 from distributed_tensorflow_tpu.cluster import bootstrap
 from distributed_tensorflow_tpu.cluster.topology import make_mesh
 from distributed_tensorflow_tpu.models import bert
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    enable_compile_cache)
 
 
 def parse_axes(spec: str) -> dict:
@@ -35,6 +43,7 @@ def main():
                     help="CI-sized model (default on CPU)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     bootstrap.initialize()
     mesh = make_mesh(parse_axes(args.axes))
     tiny = args.tiny or jax.default_backend() == "cpu"

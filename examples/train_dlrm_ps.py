@@ -21,6 +21,12 @@ running `remote_dispatch.run_worker_loop()`.
 from __future__ import annotations
 
 import argparse
+import os
+import sys
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
 
 
 def main():
@@ -33,7 +39,10 @@ def main():
     from distributed_tensorflow_tpu.coordinator.cluster_coordinator import (
         ClusterCoordinator)
     from distributed_tensorflow_tpu.models import wide_deep as wd
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
 
+    enable_compile_cache()
     cfg = wd.WideDeepConfig.tiny()
     coord = ClusterCoordinator(num_workers=args.workers)
     try:

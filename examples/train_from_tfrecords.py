@@ -20,10 +20,15 @@ tf.data pipelines). This script
 
 import argparse
 import os
+import sys
 import tempfile
 
 import jax
 import numpy as np
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
 
 from distributed_tensorflow_tpu.cluster import bootstrap
 from distributed_tensorflow_tpu.cluster.topology import make_mesh
@@ -31,6 +36,8 @@ from distributed_tensorflow_tpu.input import (
     Dataset, FixedLenFeature, encode_example, example_reader)
 from distributed_tensorflow_tpu.input.native_loader import write_tfrecords
 from distributed_tensorflow_tpu.models import wide_deep as wd
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    enable_compile_cache)
 
 
 def write_click_shards(cfg, out_dir: str, n_shards: int = 4,
@@ -63,6 +70,7 @@ def main():
                     help="existing TFRecord dir (default: write synthetic)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     bootstrap.initialize()
     cfg = wd.WideDeepConfig.tiny()
 

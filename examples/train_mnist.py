@@ -544,6 +544,10 @@ def main():
                          "sharded into")
     args = ap.parse_args()
 
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()      # exported: spawned workers share it
+
     if args.data_service:
         run_data_service(args)
         return

@@ -361,6 +361,10 @@ def main():
     ap.add_argument("--telemetry-dir", default=None)
     args = ap.parse_args()
 
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()      # exported: spawned workers share it
+
     if args.supervised:
         run_supervised(args)
     else:

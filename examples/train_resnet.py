@@ -31,7 +31,13 @@ infeed-wait reported so host-boundedness is a number, not a guess:
 """
 
 import argparse
+import os
+import sys
 import time
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
 
 
 def _jpeg_infeed(data_dir: str, runtime, mesh, per_process_batch: int,
@@ -180,6 +186,10 @@ def main():
                     help="spawn N local worker processes with TF_CONFIG "
                          "(multi-worker demo on one box)")
     args = ap.parse_args()
+
+    from distributed_tensorflow_tpu.utils.compile_cache import (
+        enable_compile_cache)
+    enable_compile_cache()      # exported, so --spawn workers share it
 
     data_dir = args.data_dir
     if args.gen_jpegs:

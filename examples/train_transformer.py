@@ -4,6 +4,8 @@
 ≙ the reference's BERT/Transformer-big multi-worker scripts
 (BASELINE.md configs #3/#5), driven through the native SPMD path:
 pick a mesh shape, get ONE compiled train step, feed global batches.
+The model is transformer-big (d_model 1024) unless ``--tiny`` is given;
+on the virtual CPU mesh pass ``--tiny``.
 
     # pure data parallel over all local devices
     python examples/train_transformer.py --axes dp=-1
@@ -22,10 +24,15 @@ pick a mesh shape, get ONE compiled train step, feed global batches.
 """
 
 import argparse
+import os
+import sys
 import time
 
 import jax
-import jax.numpy as jnp
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:
+    sys.path.insert(0, _REPO)
 
 from distributed_tensorflow_tpu.cluster import bootstrap
 from distributed_tensorflow_tpu.cluster.topology import make_mesh
@@ -35,6 +42,8 @@ from distributed_tensorflow_tpu.models.transformer import (
     make_sharded_train_step,
     synthetic_tokens,
 )
+from distributed_tensorflow_tpu.utils.compile_cache import (
+    enable_compile_cache)
 
 
 def parse_axes(spec: str) -> dict:
@@ -53,7 +62,9 @@ def main():
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=None)
     ap.add_argument("--tiny", action="store_true",
-                    help="CI-sized model (default on CPU)")
+                    help="CI-sized model (d_model 64); without it the "
+                         "run is transformer-big on whatever backend "
+                         "jax found")
     ap.add_argument("--microbatches", type=int, default=2,
                     help="pipeline microbatches when the mesh has pp")
     ap.add_argument("--schedule", default="gpipe",
@@ -71,11 +82,11 @@ def main():
                     choices=["ring", "ulysses", "striped"])
     args = ap.parse_args()
 
+    enable_compile_cache()
     bootstrap.initialize()                 # no-op single-process
     mesh = make_mesh(parse_axes(args.axes))
     print(f"mesh: {dict(mesh.shape)} on {jax.default_backend()}")
 
-    tiny = args.tiny or jax.default_backend() != "tpu"
     kw = {}
     if args.seq_len:
         kw["max_seq_len"] = args.seq_len
@@ -83,9 +94,9 @@ def main():
         kw["moe_experts"] = args.moe_experts
     if "sp" in mesh.shape and mesh.shape["sp"] > 1:
         kw["sp_impl"] = args.sp_impl
-        if tiny and args.sp_impl == "striped":
+        if args.tiny and args.sp_impl == "striped":
             kw["sp_attn_impl"] = "interpret"
-    cfg = (TransformerConfig.tiny(**kw) if tiny
+    cfg = (TransformerConfig.tiny(**kw) if args.tiny
            else TransformerConfig.transformer_big(**kw))
 
     if mesh.shape.get("pp", 1) > 1:

@@ -433,17 +433,6 @@ def test_instrument_filters_and_report_file(tmp_path, devices):
     assert "first_nan: none" in text
 
 
-# The tensor-tracer deep-instrumentation tests (flagship forward,
-# scan/while/cond bodies) stall indefinitely on pre-AxisType jax — the
-# jaxpr interpretation the tracer does is incompatible with that
-# vintage and one such test eats the entire tier-1 budget. Simple
-# trace_fn tests above are unaffected.
-_tracer_needs_modern_jax = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="tensor-tracer deep instrumentation stalls on pre-AxisType jax")
-
-
-@_tracer_needs_modern_jax
 def test_instrument_locates_injected_nan_in_flagship(devices):
     """The round-3 'done' criterion: locate an injected NaN inside the
     flagship transformer WITHOUT any model annotation, from the jaxpr
@@ -479,7 +468,6 @@ def test_instrument_locates_injected_nan_in_flagship(devices):
     _, clean = trace_fn(fwd, params, tokens)
     assert clean.first_nan() is None
 
-@_tracer_needs_modern_jax
 def test_instrument_scan_body_per_iteration(devices):
     """Scan bodies are rewritten once and every trip reports stats
     tagged with the carried iteration counter (VERDICT r4 item 5)."""
@@ -507,7 +495,6 @@ def test_instrument_scan_body_per_iteration(devices):
                                rtol=1e-6)
 
 
-@_tracer_needs_modern_jax
 def test_instrument_while_and_cond_bodies(devices):
     import jax.numpy as jnp
     from distributed_tensorflow_tpu.utils.tensor_tracer import trace_fn
@@ -536,7 +523,6 @@ def test_instrument_while_and_cond_bodies(devices):
     np.testing.assert_allclose(float(out), 130.0, rtol=1e-6)
 
 
-@_tracer_needs_modern_jax
 def test_instrument_scan_layers_train_step_localizes_layer(devices):
     """THE VERDICT r4 item-5 'done' criterion: first-NaN localization
     inside a scan_layers=True flagship TRAIN step (value_and_grad +

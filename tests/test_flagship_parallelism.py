@@ -50,15 +50,6 @@ def test_pipelined_step_single_stage_degenerates(devices):
                                rtol=5e-5)
 
 
-# jaxlib <= 0.4.36 (feature-probed via the missing AxisType, the repo's
-# standard vintage gate): part of the pre-existing sharded-parity family
-# (NOTES_r6.md) — dp×ep-sharded execution numerically diverges from the
-# single-device run well beyond tolerance on this XLA-CPU runtime
-# (failing since the seed; tracked as vintage-only, not a model bug).
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jaxlib<=0.4.36 sharded-parity divergence on XLA-CPU "
-           "(pre-existing family, NOTES_r6.md)")
 def test_moe_transformer_ep_matches_single_device(devices):
     """MoE-MLP flagship on dp×ep == the identical model on one device."""
     cfg = TransformerConfig.tiny(moe_experts=4, moe_top_k=2,

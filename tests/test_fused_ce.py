@@ -153,36 +153,29 @@ def test_sharded_train_step_kernel_matches_scan():
     assert losses["kernel"] == pytest.approx(losses["scan"], rel=1e-5)
 
 
-def test_kernel_on_mesh_indivisible_fallback_matches_scan_seq1024():
+def test_kernel_on_indivisible_mesh_raises():
     """When a mesh is attached but its shard counts don't divide the
-    batch (B=2 over dp×fsdp=4 shards), loss_impl='kernel' must fall
-    back to the scan path with its divisor-capped default chunking; pin
-    that the fallback neither OOMs nor changes numerics at a realistic
-    seq len (VERDICT r4 weak #6 / item 8a). State replicated (plain
-    jit) — the batch-indivisible case can't use sharded inputs."""
+    batch (B=2 over dp×fsdp=4 shards), loss_impl='kernel' raises and
+    names the shapes — a loss asked for by name is built or refused,
+    never quietly swapped for the scan path."""
     import optax
     from distributed_tensorflow_tpu.cluster.topology import make_mesh
 
     mesh = make_mesh({"dp": 2, "fsdp": 2, "tp": 2},
                      devices=jax.devices()[:8])
-    losses = {}
-    for impl in ("scan", "kernel"):
-        cfg = transformer.TransformerConfig.tiny(
-            max_seq_len=1024, n_layers=1, mesh=mesh,
-            loss_impl=impl, loss_chunks=4 if impl == "scan" else 0)
-        model = transformer.TransformerLM(cfg)
-        tokens = transformer.synthetic_tokens(2, cfg.max_seq_len,
-                                              cfg.vocab_size, seed=4)
-        with mesh:
-            params = model.init(jax.random.PRNGKey(0),
-                                tokens[:1])["params"]
-            tx = optax.sgd(1e-2)
-            state = {"params": params, "opt_state": tx.init(params),
-                     "step": 0}
-            step = jax.jit(transformer.make_train_step(cfg, model, tx))
-            _, metrics = step(state, {"tokens": tokens})
-        losses[impl] = float(metrics["loss"])
-    assert losses["kernel"] == pytest.approx(losses["scan"], rel=1e-5)
+    cfg = transformer.TransformerConfig.tiny(
+        n_layers=1, mesh=mesh, loss_impl="kernel")
+    model = transformer.TransformerLM(cfg)
+    tokens = transformer.synthetic_tokens(2, cfg.max_seq_len,
+                                          cfg.vocab_size, seed=4)
+    with mesh:
+        params = model.init(jax.random.PRNGKey(0), tokens[:1])["params"]
+        tx = optax.sgd(1e-2)
+        state = {"params": params, "opt_state": tx.init(params),
+                 "step": 0}
+        step = jax.jit(transformer.make_train_step(cfg, model, tx))
+        with pytest.raises(ValueError, match="loss_impl='kernel' on mesh"):
+            step(state, {"tokens": tokens})
 
 
 def test_train_step_with_kernel_loss_impl():

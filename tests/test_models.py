@@ -114,13 +114,6 @@ class TestBert:
             losses.append(float(m["loss"]))
         assert losses[-1] < losses[0], losses
 
-    # jaxlib <= 0.4.36 (missing-AxisType vintage gate): pre-existing
-    # sharded-parity family (NOTES_r6.md) — tp-sharded loss diverges
-    # ~0.6% from dp against a 0.02% bar on this XLA-CPU runtime.
-    @pytest.mark.skipif(
-        not hasattr(jax.sharding, "AxisType"),
-        reason="jaxlib<=0.4.36 sharded-parity divergence on XLA-CPU "
-               "(pre-existing family, NOTES_r6.md)")
     def test_mesh_equivalence(self, setup, devices):
         bert, cfg, batch = setup
         runs = {}
@@ -166,11 +159,6 @@ class TestWideDeep:
         spec = tuple(state["params"]["table_0"].sharding.spec)
         assert spec and spec[0] == "tp", spec
 
-    # same vintage gate + rationale as TestBert.test_mesh_equivalence
-    @pytest.mark.skipif(
-        not hasattr(jax.sharding, "AxisType"),
-        reason="jaxlib<=0.4.36 sharded-parity divergence on XLA-CPU "
-               "(pre-existing family, NOTES_r6.md)")
     def test_tp_matches_dp(self, setup, devices):
         wide_deep, cfg, batch = setup
         runs = {}

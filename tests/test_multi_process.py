@@ -478,19 +478,6 @@ def test_remote_coordinator_dispatch(tmp_path, pool3):
     assert len(workers) == 2     # both worker loops exited via shutdown
 
 
-# jaxlib <= 0.4.36 (missing-AxisType vintage gate): passes standalone,
-# but under full-suite pooled-process state this vintage's runtime
-# intermittently rejects re-executed programs with "Buffer passed to
-# Execute() ... is on device TFRT_CPU_0, but replica is assigned to
-# TFRT_CPU_0" (NOTES_r6.md: the deserialized-executable family). Skip
-# on the broken vintage rather than carry known in-suite noise.
-_legacy_pooled_runtime_bug = pytest.mark.skipif(
-    not hasattr(__import__("jax").sharding, "AxisType"),
-    reason="jaxlib<=0.4.36 pooled-process Execute() buffer-device bug "
-           "under full-suite state (pre-existing, NOTES_r6.md)")
-
-
-@_legacy_pooled_runtime_bug
 def test_per_worker_datasets_on_remote_workers(pool3):
     """create_per_worker_dataset places iterators ON worker processes;
     scheduled closures consume them via resource handles."""
@@ -741,7 +728,6 @@ def _raise_worker():
     raise ValueError("intentional")
 
 
-@_legacy_pooled_runtime_bug
 def test_pool_reuses_processes_across_runs(pool2):
     """The whole point of the pool: consecutive runs land on the SAME
     OS processes (no spawn / jax re-import), and a fresh distributed

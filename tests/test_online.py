@@ -187,7 +187,7 @@ def test_supervised_online_survives_seeded_kill(tmp_path):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     run_dir = str(tmp_path / "run")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     seed = int(os.environ.get("DTX_CHAOS_SEED", "1"))
     proc = subprocess.run(
         [sys.executable,

@@ -120,18 +120,6 @@ def test_striped_attention_matches_full(qkv4, devices):
                                atol=2e-5, rtol=2e-5)
 
 
-# jaxlib <= 0.4.36 (feature-probed via the missing AxisType, the
-# vintage gate PR 3 applied to the fsdp params of
-# test_sharded_training_matches_single_device): this grad program is in
-# the same XLA-CPU family whose mid-suite heap state intermittently
-# escalates to a process-killing SIGSEGV/SIGABRT — both tier-1 runs of
-# 2026-08-04's session died HERE (faulthandler dump at line 131) while
-# the test passes 3/3 standalone. Skip on the broken vintage rather
-# than let it take down the whole tier-1 run.
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jaxlib<=0.4.36 XLA-CPU runtime instability on sharded grad "
-           "executables (intermittent whole-process SIGSEGV mid-suite)")
 def test_striped_attention_grads(qkv4, devices):
     """Striped custom VJP == full-attention gradients."""
     q, k, v = qkv4

@@ -45,26 +45,10 @@ def _single_device_losses(cfg, batch, n_steps, seed=0):
     return losses
 
 
-# jaxlib <= 0.4.36 (feature-probed via the missing AxisType, the same
-# vintage gate the tracer tests use): the XLA-CPU runtime rejects these
-# fsdp-sharded executables with an inconsistent "Buffer passed to
-# Execute() ... is on device TFRT_CPU_0, but replica is assigned to
-# device TFRT_CPU_0" error, and under full-suite process state the
-# failure intermittently escalates to a SIGSEGV that kills pytest
-# outright — skip rather than let a known-broken vintage take down the
-# whole tier-1 run.
-_fsdp_runtime_bug = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="jaxlib<=0.4.36 XLA-CPU runtime bug on fsdp-sharded "
-           "executables (inconsistent Execute() buffer-device error; "
-           "intermittent process SIGSEGV)")
-
-
 @pytest.mark.parametrize("axes", [
     {"dp": 8},
-    pytest.param({"dp": 2, "fsdp": 2, "tp": 2},
-                 marks=_fsdp_runtime_bug),
-    pytest.param({"fsdp": 4, "tp": 2}, marks=_fsdp_runtime_bug),
+    {"dp": 2, "fsdp": 2, "tp": 2},
+    {"fsdp": 4, "tp": 2},
     {"dp": 2, "sp": 4},      # ring-attention sequence parallelism
 ])
 def test_sharded_training_matches_single_device(cfg, batch, axes, devices):

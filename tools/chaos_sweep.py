@@ -173,7 +173,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_seed(seed: int, include_slow: bool, extra: list[str]) -> tuple[bool, float]:
     env = dict(os.environ)
     env["DTX_CHAOS_SEED"] = str(seed)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     marker = "chaos" if include_slow else "chaos and not slow"
     cmd = [sys.executable, "-m", "pytest", "tests/test_chaos.py", "-q",
            "-m", marker, "-p", "no:cacheprovider", *extra]
@@ -258,7 +258,7 @@ def run_kill_seed(seed: int, *, workers: int, steps: int,
     kind = "shrink" if shrink else "kill"
     run_dir = tempfile.mkdtemp(prefix=f"chaos_{kind}_s{seed}_")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, os.path.join(REPO, "examples", "train_mnist.py"),
            "--elastic", "--workers", str(workers), "--steps", str(steps),
            "--save-every", str(save_every), "--kill-seed", str(seed),
@@ -407,7 +407,7 @@ def run_data_seed(seed: int, *, input_workers: int, epochs: int,
     + the goodput-ledger identity (recovery priced)."""
     run_dir = tempfile.mkdtemp(prefix=f"chaos_data_s{seed}_")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable,
            os.path.join(REPO, "examples", "train_mnist.py"),
            "--data-service", "--input-workers", str(input_workers),
@@ -602,7 +602,7 @@ def run_online_seed(seed: int, *, events: int, budget: int,
     re-clear + the goodput-ledger identity (recovery priced)."""
     run_dir = tempfile.mkdtemp(prefix=f"chaos_online_s{seed}_")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable,
            os.path.join(REPO, "examples", "train_online.py"),
            "--supervised", "--events", str(events),
@@ -768,7 +768,7 @@ def run_serve_seed(seed: int, *, workers: int, requests: int,
     kind = "serve_disagg" if disagg else "serve"
     run_dir = tempfile.mkdtemp(prefix=f"chaos_{kind}_s{seed}_")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable,
            os.path.join(REPO, "examples", "serve_transformer.py"),
            "--elastic", "--workers", str(workers),
@@ -914,7 +914,7 @@ def run_router_seed(seed: int, *, workers: int, keep_dirs: bool) \
     ``_router_summary_gates`` over the run's router-summary.json."""
     run_dir = tempfile.mkdtemp(prefix=f"chaos_router_s{seed}_")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable,
            os.path.join(REPO, "examples", "serve_router.py"),
            "--run-dir", run_dir, "--seed", str(seed),
@@ -1028,7 +1028,7 @@ def run_spike_seed(seed: int, *, budget: int, train_workers: int,
     module docstring)."""
     run_dir = tempfile.mkdtemp(prefix=f"chaos_spike_s{seed}_")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable,
            os.path.join(REPO, "examples", "shared_fleet.py"),
            "--seed", str(seed), "--budget", str(budget),
@@ -1192,7 +1192,7 @@ def run_rollout_seed(seed: int, *, replicas: int, duration: float,
     a bad-canary run (must auto-rollback on burn), and the in-process
     delta-publish fault leg (module docstring, ``--rollout``)."""
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.monotonic()
     ok = True
     run_dirs = []

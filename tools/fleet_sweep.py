@@ -255,7 +255,7 @@ def main(argv=None) -> int:
     ap.add_argument("--repo", default=REPO)
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     rc = 0
 
     if args.check:

@@ -232,7 +232,7 @@ def main() -> int:
                                 "below plain 1F1B's")
 
     # gate 3: scaling.* telemetry wiring
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")   # import-safe off-TPU
+    os.environ["JAX_PLATFORMS"] = "cpu"   # import-safe off-TPU
     from distributed_tensorflow_tpu import telemetry
     ev_path = telemetry.event_log_path(tdir, 0)
     try:

@@ -160,7 +160,7 @@ def run_bench(out_path: str, qps, requests, seed, telemetry_dir, *,
               prefix_reuse=None, kv_dtype=None, speculative=None,
               disagg=False) -> int:
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["DTX_TELEMETRY_DIR"] = telemetry_dir
     cmd = [sys.executable, os.path.join(REPO, "bench.py"), "--serving",
            "--out", out_path, "--seed", str(seed)]
