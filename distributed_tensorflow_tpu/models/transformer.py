@@ -216,16 +216,19 @@ def rotary_embedding(x, *, base: float = 10000.0, seq_axis: int = -3):
     layout — projecting straight into kernel layout lets q/k/v skip the
     (B,S,H,d)->(B,H,S,d) transposes."""
     seq, d = x.shape[seq_axis], x.shape[-1]
-    pos = jnp.arange(seq, dtype=jnp.float32)
-    inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    angles = pos[:, None] * inv_freq[None, :]          # (seq, d/2)
-    bshape = [1] * x.ndim
-    bshape[seq_axis], bshape[-1] = seq, d // 2
-    sin = jnp.sin(angles).reshape(bshape)
-    cos = jnp.cos(angles).reshape(bshape)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return out.astype(x.dtype)
+    with jax.named_scope("rotary"):
+        pos = jnp.arange(seq, dtype=jnp.float32)
+        inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32)
+                                   / d))
+        angles = pos[:, None] * inv_freq[None, :]          # (seq, d/2)
+        bshape = [1] * x.ndim
+        bshape[seq_axis], bshape[-1] = seq, d // 2
+        sin = jnp.sin(angles).reshape(bshape)
+        cos = jnp.cos(angles).reshape(bshape)
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                              axis=-1)
+        return out.astype(x.dtype)
 
 
 class MultiHeadAttention(nn.Module):
@@ -608,6 +611,7 @@ def make_loss_fn(cfg: TransformerConfig, model: TransformerLM):
                 f"{n_batch} data shards / sp={sp} / tp={tp}; use "
                 f"loss_impl='scan' for shapes the kernel cannot shard")
 
+    @jax.named_scope("loss")
     def objective(out, params, tokens):
         if use_kernel:
             _check_kernel_mesh(*out.shape[:2])
@@ -681,12 +685,13 @@ def make_train_step(cfg: TransformerConfig, model: TransformerLM, tx,
     def train_step(state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state["params"],
                                                   batch["tokens"])
-        if use_fused_opt:
-            params, opt_state = fused_opt_step(state, grads)
-        else:
-            updates, opt_state = tx.update(grads, state["opt_state"],
-                                           state["params"])
-            params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            if use_fused_opt:
+                params, opt_state = fused_opt_step(state, grads)
+            else:
+                updates, opt_state = tx.update(grads, state["opt_state"],
+                                               state["params"])
+                params = optax.apply_updates(state["params"], updates)
         return ({"params": params, "opt_state": opt_state,
                  "step": state["step"] + 1},
                 {"loss": loss})
@@ -932,9 +937,10 @@ def _make_bucketed_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
         if sync:
             grads = bucketer.all_reduce(grads, op=ReduceOp.MEAN)
             loss = collectives_all_reduce(loss, data_axes, ReduceOp.MEAN)
-        updates, opt_state = tx.update(grads, state["opt_state"],
-                                       state["params"])
-        params = optax.apply_updates(state["params"], updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state["opt_state"],
+                                           state["params"])
+            params = optax.apply_updates(state["params"], updates)
         return ({"params": params, "opt_state": opt_state,
                  "step": state["step"] + 1},
                 {"loss": loss})

@@ -248,6 +248,7 @@ def _flash_forward(q, k, v, sm_scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     return (out[:, :q_len].reshape(batch, heads, q_len, d),
             lse[:, :q_len].reshape(batch, heads, q_len))
@@ -400,6 +401,7 @@ def _flash_backward(res, g, *, sm_scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((bh, qp, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qr, kr, vr, dor, lser, deltar)
 
     # dk/dv: grid over k-blocks, inner loop over q-blocks.
@@ -418,6 +420,7 @@ def _flash_backward(res, g, *, sm_scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qr, kr, vr, dor, lser, deltar)
 
     shape = (batch, heads, q_len, d)

@@ -121,6 +121,7 @@ def _fused_leaf_update(p, g, mu, nu, corrections, *, lr, b1, b2, eps, wd,
         # operands: 0=corrections(SMEM), 1=p, 2=g, 3=mu, 4=nu
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name="fused_adamw",
     )(corrections, prep(p), prep(g), prep(mu), prep(nu))
 
     def unprep(x):

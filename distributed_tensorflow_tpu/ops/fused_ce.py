@@ -312,6 +312,7 @@ def _fwd_call(h, emb, targets, block_n, block_v, interpret):
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fused_ce_fwd",
     )(_pad_rows(h, block_n), emb,
       # pad target rows with -1: matches no vocab column
       _pad_rows_fill(targets[:, None].astype(jnp.int32), block_n, -1))
@@ -337,6 +338,7 @@ def _dh_call(h, emb, targets, lse, g, block_n, block_v, interpret):
         out_shape=jax.ShapeDtypeStruct((nb * block_n, d), h.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_bwd_dh",
     )(_pad_rows(h, block_n), emb,
       _pad_rows_fill(targets[:, None].astype(jnp.int32), block_n, -1),
       _pad_rows(lse[:, None], block_n), _pad_rows(g[:, None], block_n))
@@ -376,6 +378,7 @@ def _bwd_merged_call(h, emb, targets, lse, g, block_n, block_v,
         input_output_aliases={5: 0},
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fused_ce_bwd_dhde_acc_dh",
     )(_pad_rows(h, block_n), emb,
       _pad_rows_fill(targets[:, None].astype(jnp.int32), block_n, -1),
       _pad_rows(lse[:, None], block_n),
@@ -417,6 +420,7 @@ def _bwd_merged_b_call(h, emb, targets, lse, g, block_n, block_v,
         input_output_aliases={5: 1},
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name="fused_ce_bwd_dhde_acc_de",
     )(_pad_rows(h, block_n), emb,
       _pad_rows_fill(targets[:, None].astype(jnp.int32), block_n, -1),
       _pad_rows(lse[:, None], block_n),
@@ -443,6 +447,7 @@ def _de_call(h, emb, targets, lse, g, block_n, block_v, interpret):
         out_shape=jax.ShapeDtypeStruct((vb * block_v, d), emb.dtype),
         scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
         interpret=interpret,
+        name="fused_ce_bwd_de",
     )(_pad_rows(h, block_n), emb,
       _pad_rows_fill(targets[:, None].astype(jnp.int32), block_n, -1),
       _pad_rows(lse[:, None], block_n),

@@ -114,14 +114,16 @@ def rotary_at(x, positions, *, base: float = 10000.0):
     the same whether computed in prefill (positions ``0..S-1``) or one
     at a time during decode."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = positions.astype(jnp.float32)[..., None] * inv_freq  # (B, Q, d/2)
-    sin = jnp.sin(ang)[:, None]                                # (B,1,Q,d/2)
-    cos = jnp.cos(ang)[:, None]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                          axis=-1)
-    return out.astype(x.dtype)
+    with jax.named_scope("rotary"):
+        inv_freq = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32)
+                                   / d))
+        ang = positions.astype(jnp.float32)[..., None] * inv_freq
+        sin = jnp.sin(ang)[:, None]                        # (B,1,Q,d/2)
+        cos = jnp.cos(ang)[:, None]
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                              axis=-1)
+        return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -144,16 +146,19 @@ def _pool_write(pool: dict, l, rows, k, v, quantized: bool) -> dict:
     ``l`` of the pool at flat ``rows``; int8 pools quantize on write
     and store the scales alongside."""
     pool = dict(pool)
-    if quantized:
-        qk, sk = _quantize_rows(k)
-        qv, sv = _quantize_rows(v)
-        pool["k"] = pool["k"].at[l, rows].set(qk)
-        pool["v"] = pool["v"].at[l, rows].set(qv)
-        pool["k_scale"] = pool["k_scale"].at[l, rows].set(sk)
-        pool["v_scale"] = pool["v_scale"].at[l, rows].set(sv)
-    else:
-        pool["k"] = pool["k"].at[l, rows].set(k.astype(pool["k"].dtype))
-        pool["v"] = pool["v"].at[l, rows].set(v.astype(pool["v"].dtype))
+    with jax.named_scope("kv.write"):
+        if quantized:
+            qk, sk = _quantize_rows(k)
+            qv, sv = _quantize_rows(v)
+            pool["k"] = pool["k"].at[l, rows].set(qk)
+            pool["v"] = pool["v"].at[l, rows].set(qv)
+            pool["k_scale"] = pool["k_scale"].at[l, rows].set(sk)
+            pool["v_scale"] = pool["v_scale"].at[l, rows].set(sv)
+        else:
+            pool["k"] = pool["k"].at[l, rows].set(
+                k.astype(pool["k"].dtype))
+            pool["v"] = pool["v"].at[l, rows].set(
+                v.astype(pool["v"].dtype))
     return pool
 
 
@@ -161,13 +166,16 @@ def _pool_window(pool: dict, l, window_rows, dt, quantized: bool):
     """Gather each slot's block window from layer ``l``:
     ``(B, W, H, hd)`` → ``(B, H, W, hd)`` compute-dtype, dequantized
     for int8 pools."""
-    kw = pool["k"][l][window_rows]
-    vw = pool["v"][l][window_rows]
-    if quantized:
-        kw = kw.astype(jnp.float32) * pool["k_scale"][l][window_rows][..., None]
-        vw = vw.astype(jnp.float32) * pool["v_scale"][l][window_rows][..., None]
-    return (kw.transpose(0, 2, 1, 3).astype(dt),
-            vw.transpose(0, 2, 1, 3).astype(dt))
+    with jax.named_scope("kv.gather"):
+        kw = pool["k"][l][window_rows]
+        vw = pool["v"][l][window_rows]
+        if quantized:
+            kw = (kw.astype(jnp.float32)
+                  * pool["k_scale"][l][window_rows][..., None])
+            vw = (vw.astype(jnp.float32)
+                  * pool["v_scale"][l][window_rows][..., None])
+        return (kw.transpose(0, 2, 1, 3).astype(dt),
+                vw.transpose(0, 2, 1, 3).astype(dt))
 
 
 def make_copy_fn():
@@ -206,21 +214,24 @@ def model_forward(cfg: TransformerConfig, params, tokens, lengths=None,
         v = jnp.einsum("bsd,dhk->bhsk", h, att["value"].astype(dt))
         q = rotary_embedding(q, seq_axis=-2)
         k = rotary_embedding(k, seq_axis=-2)
-        o = mha_reference(q, k, v, causal=cfg.causal, lengths=lengths)
+        with jax.named_scope("attn"):
+            o = mha_reference(q, k, v, causal=cfg.causal, lengths=lengths)
         o = jnp.einsum("bhsk,hkd->bsd", o, att["out"].astype(dt))
         x = x + o
         h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
         mlp = p["mlp"]
-        hh = jnp.einsum("bsd,df->bsf", h, mlp["wi"].astype(dt))
-        gate, up = jnp.split(hh, 2, axis=-1)
-        hh = jax.nn.silu(gate) * up
-        x = x + jnp.einsum("bsf,fd->bsd", hh, mlp["wo"].astype(dt))
+        with jax.named_scope("mlp"):
+            hh = jnp.einsum("bsd,df->bsf", h, mlp["wi"].astype(dt))
+            gate, up = jnp.split(hh, 2, axis=-1)
+            hh = jax.nn.silu(gate) * up
+            x = x + jnp.einsum("bsf,fd->bsd", hh, mlp["wo"].astype(dt))
         if return_kv:
             ks.append(k)
             vs.append(v)
     x = _rms_norm(x, params["final_norm"]["scale"], dt)
-    logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(dt))
-    logits = logits.astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(dt))
+        logits = logits.astype(jnp.float32)
     if return_kv:
         return logits, (jnp.stack(ks), jnp.stack(vs))
     return logits
@@ -290,20 +301,23 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None):
             # write THEN gather: the query must see its own position
             pool = _pool_write(pool, l, write_rows, k, v, quantized)
             kw, vw = _pool_window(pool, l, window_rows, dt, quantized)
-            o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
-                              q_positions=positions)     # (B, H, 1, hd)
+            with jax.named_scope("attn"):
+                o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
+                                  q_positions=positions)  # (B, H, 1, hd)
             o = jnp.einsum("bhk,hkd->bd", o[:, :, 0],
                            att["out"].astype(dt))
             x = x + o
             h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
             mlp = p["mlp"]
-            hh = jnp.einsum("bd,df->bf", h, mlp["wi"].astype(dt))
-            gate, up = jnp.split(hh, 2, axis=-1)
-            hh = jax.nn.silu(gate) * up
-            x = x + jnp.einsum("bf,fd->bd", hh, mlp["wo"].astype(dt))
+            with jax.named_scope("mlp"):
+                hh = jnp.einsum("bd,df->bf", h, mlp["wi"].astype(dt))
+                gate, up = jnp.split(hh, 2, axis=-1)
+                hh = jax.nn.silu(gate) * up
+                x = x + jnp.einsum("bf,fd->bd", hh, mlp["wo"].astype(dt))
         x = _rms_norm(x, params["final_norm"]["scale"], dt)
-        logits = jnp.einsum("bd,vd->bv", x, embed.astype(dt))
-        return logits.astype(jnp.float32), pool
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("bd,vd->bv", x, embed.astype(dt))
+            return logits.astype(jnp.float32), pool
 
     return decode
 
@@ -358,19 +372,22 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
                                                      v.shape[3])
             pool = _pool_write(pool, l, rows, flat_k, flat_v, quantized)
             kw, vw = _pool_window(pool, l, window_rows, dt, quantized)
-            o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
-                              q_positions=positions)     # (B, H, E, hd)
+            with jax.named_scope("attn"):
+                o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
+                                  q_positions=positions)  # (B, H, E, hd)
             o = jnp.einsum("bhsk,hkd->bsd", o, att["out"].astype(dt))
             x = x + o
             h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
             mlp = p["mlp"]
-            hh = jnp.einsum("bsd,df->bsf", h, mlp["wi"].astype(dt))
-            gate, up = jnp.split(hh, 2, axis=-1)
-            hh = jax.nn.silu(gate) * up
-            x = x + jnp.einsum("bsf,fd->bsd", hh, mlp["wo"].astype(dt))
+            with jax.named_scope("mlp"):
+                hh = jnp.einsum("bsd,df->bsf", h, mlp["wi"].astype(dt))
+                gate, up = jnp.split(hh, 2, axis=-1)
+                hh = jax.nn.silu(gate) * up
+                x = x + jnp.einsum("bsf,fd->bsd", hh, mlp["wo"].astype(dt))
         x = _rms_norm(x, params["final_norm"]["scale"], dt)
-        logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(dt))
-        return logits.astype(jnp.float32), pool
+        with jax.named_scope("lm_head"):
+            logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(dt))
+            return logits.astype(jnp.float32), pool
 
     return extend
 

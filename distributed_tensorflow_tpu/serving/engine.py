@@ -331,7 +331,7 @@ class InferenceEngine:
             "admission -> completion seconds per serving request")
         self._m_ttft = reg.histogram(
             "inference/time_to_first_token",
-            "admission -> first generated token seconds")
+            "submit -> first generated token seconds (queueing included)")
         self._m_completed = reg.counter("inference/requests_completed")
         self._m_tokens = reg.counter("inference/tokens_generated")
         self._m_replayed = reg.counter(
@@ -697,12 +697,13 @@ class InferenceEngine:
         scales) BEFORE the divergent write they protect."""
         if not copies:
             return
-        src = np.concatenate([np.arange(s, s + n, dtype=np.int32)
-                              for s, _, n in copies])
-        dst = np.concatenate([np.arange(d, d + n, dtype=np.int32)
-                              for _, d, n in copies])
-        self.pool = self._copy(self.pool, jnp.asarray(src),
-                               jnp.asarray(dst))
+        with telemetry.span("kv.copy_on_write", blocks=len(copies)):
+            src = np.concatenate([np.arange(s, s + n, dtype=np.int32)
+                                  for s, _, n in copies])
+            dst = np.concatenate([np.arange(d, d + n, dtype=np.int32)
+                                  for _, d, n in copies])
+            self.pool = self._copy(self.pool, jnp.asarray(src),
+                                   jnp.asarray(dst))
 
     def _prefill_one(self, seq: Sequence):
         """Run one admitted sequence's prompt through the compiled
@@ -722,6 +723,9 @@ class InferenceEngine:
         queue_wait = (seq.admitted_s - submit_mono
                       if submit_mono is not None else None)
         C = seq.cached_tokens
+        S = seq.prompt_len - C                      # suffix to compute
+        E = (min(self.max_seq_len, 1 << max(3, (S - 1).bit_length()))
+             if C else self.max_seq_len)            # program's width
         with telemetry.span(
                 "serve.prefill", id=rid, span_id=request_span_id(rid),
                 model_version=self.weights_version,
@@ -729,42 +733,43 @@ class InferenceEngine:
                 cached_tokens=C or None,
                 queue_wait_s=(round(queue_wait, 6)
                               if queue_wait is not None else None),
-                replayed=len(seq.request.generated_prefix) or None):
+                replayed=len(seq.request.generated_prefix) or None,
+                program="extend" if C else "prefill"):
+            lengths = np.asarray([seq.prompt_len], np.int32)
             if C:
-                S = seq.prompt_len - C              # suffix to compute
-                E = min(self.max_seq_len,
-                        1 << max(3, (S - 1).bit_length()))
-                # a partially-matched tail block is SHARED: copy it
-                # before the suffix writes into it (and before the row
-                # indices below are derived from the table)
-                self._apply_copies(seq.table.ensure_writable(
-                    C, seq.prompt_len, self.scheduler.allocator))
-                toks = np.zeros((1, E), np.int32)
-                toks[0, :S] = seq.request.tokens[C:]
-                pos = np.full((1, E), self.window, np.int32)
-                pos[0, :S] = np.arange(C, seq.prompt_len)
-                rows = np.zeros((1, E), np.int32)   # pad -> trash row
-                rows[0, :S] = seq.table.rows(np.arange(C,
-                                                       seq.prompt_len))
-                win = seq.table.window_rows()[None]
-                lengths = np.asarray([seq.prompt_len], np.int32)
-                logits, self.pool = self._extend_prefill(
-                    self.params, self.pool, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(lengths),
-                    jnp.asarray(rows), jnp.asarray(win))
-                last = logits[0, S - 1]
+                with telemetry.span("serve.prefill.build"):
+                    # a partially-matched tail block is SHARED: copy it
+                    # before the suffix writes into it (and before the
+                    # row indices below are derived from the table)
+                    self._apply_copies(seq.table.ensure_writable(
+                        C, seq.prompt_len, self.scheduler.allocator))
+                    toks = np.zeros((1, E), np.int32)
+                    toks[0, :S] = seq.request.tokens[C:]
+                    pos = np.full((1, E), self.window, np.int32)
+                    pos[0, :S] = np.arange(C, seq.prompt_len)
+                    rows = np.zeros((1, E), np.int32)  # pad -> trash row
+                    rows[0, :S] = seq.table.rows(
+                        np.arange(C, seq.prompt_len))
+                    win = seq.table.window_rows()[None]
+                with telemetry.span("serve.prefill.launch"):
+                    logits, self.pool = self._extend_prefill(
+                        self.params, self.pool, jnp.asarray(toks),
+                        jnp.asarray(pos), jnp.asarray(lengths),
+                        jnp.asarray(rows), jnp.asarray(win))
+                    last = logits[0, S - 1]
             else:
-                P = self.max_seq_len
-                toks = np.zeros((1, P), np.int32)
-                toks[0, :seq.prompt_len] = seq.request.tokens
-                rows = seq.table.rows(np.arange(P))[None]   # (1, P)
-                lengths = np.asarray([seq.prompt_len], np.int32)
-                last, self.pool = self._prefill(
-                    self.params, self.pool, jnp.asarray(toks),
-                    jnp.asarray(lengths), jnp.asarray(rows))
-                last = last[0]
+                with telemetry.span("serve.prefill.build"):
+                    toks = np.zeros((1, E), np.int32)
+                    toks[0, :seq.prompt_len] = seq.request.tokens
+                    rows = seq.table.rows(np.arange(E))[None]   # (1, E)
+                with telemetry.span("serve.prefill.launch"):
+                    last, self.pool = self._prefill(
+                        self.params, self.pool, jnp.asarray(toks),
+                        jnp.asarray(lengths), jnp.asarray(rows))
+                    last = last[0]
             self.scheduler.commit_prefill(seq)
-            first = int(np.asarray(jnp.argmax(last)))
+            with telemetry.span("serve.prefill.wait"):
+                first = int(np.asarray(jnp.argmax(last)))
         self._m_prompt_tokens.increment(seq.prompt_len)
         if C:
             self._m_cached_tokens.increment(C)
@@ -791,38 +796,42 @@ class InferenceEngine:
         program has a fixed (max_slots,) batch; idle slots feed trash
         rows with length 0 and their logits are never read."""
         B, W = self.max_slots, self.window
-        tokens = np.zeros(B, np.int32)
-        positions = np.zeros(B, np.int32)
-        lengths = np.zeros(B, np.int32)
-        write_rows = np.zeros(B, np.int32)     # trash block row 0
-        window_rows = np.zeros((B, W), np.int32)
-        for seq in batch:
-            s = seq.slot
-            if self.prefix_caching:
-                # the write at position length-1 must not land in a
-                # block a prefix-cache sibling shares: copy-on-write
-                # first (without a cache no block is ever shared)
-                self._apply_copies(seq.table.ensure_writable(
-                    seq.length - 1, seq.length,
-                    self.scheduler.allocator))
-            # feed the last banked token at position length-1 (it was
-            # appended by the previous prefill/decode step)
-            tokens[s] = seq.last_token
-            positions[s] = seq.length - 1
-            lengths[s] = seq.length
-            write_rows[s] = seq.table.row_of(seq.length - 1)
-            window_rows[s] = seq.table.window_rows()
-        logits, self.pool = self._decode(
-            self.params, self.pool,
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(lengths), jnp.asarray(write_rows),
-            jnp.asarray(window_rows))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        emit = telemetry.enabled()
-        for seq in batch:
-            self.scheduler.append_token(seq, int(nxt[seq.slot]))
-            if emit:
-                self._emit_token(seq)
+        with telemetry.span("serve.decode.build"):
+            tokens = np.zeros(B, np.int32)
+            positions = np.zeros(B, np.int32)
+            lengths = np.zeros(B, np.int32)
+            write_rows = np.zeros(B, np.int32)     # trash block row 0
+            window_rows = np.zeros((B, W), np.int32)
+            for seq in batch:
+                s = seq.slot
+                if self.prefix_caching:
+                    # the write at position length-1 must not land in
+                    # a block a prefix-cache sibling shares: copy-on-
+                    # write first (without a cache no block is shared)
+                    self._apply_copies(seq.table.ensure_writable(
+                        seq.length - 1, seq.length,
+                        self.scheduler.allocator))
+                # feed the last banked token at position length-1 (it
+                # was appended by the previous prefill/decode step)
+                tokens[s] = seq.last_token
+                positions[s] = seq.length - 1
+                lengths[s] = seq.length
+                write_rows[s] = seq.table.row_of(seq.length - 1)
+                window_rows[s] = seq.table.window_rows()
+        with telemetry.span("serve.decode.launch"):
+            logits, self.pool = self._decode(
+                self.params, self.pool,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(lengths), jnp.asarray(write_rows),
+                jnp.asarray(window_rows))
+        with telemetry.span("serve.decode.wait"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+        with telemetry.span("serve.decode.commit", tokens=len(batch)):
+            emit = telemetry.enabled()
+            for seq in batch:
+                self.scheduler.append_token(seq, int(nxt[seq.slot]))
+                if emit:
+                    self._emit_token(seq)
 
     # -- speculative decoding ---------------------------------------------
     def _spec_span(self, seq: Sequence) -> int:
@@ -849,65 +858,71 @@ class InferenceEngine:
         spans = {seq.slot: self._spec_span(seq) for seq in batch}
 
         # 1. draft proposals: k batched greedy steps, full recompute
-        toks = np.zeros((B, S), np.int32)
-        lens = np.zeros(B, np.int32)
-        for seq in batch:
-            hist = list(seq.request.tokens) + seq.generated
-            toks[seq.slot, :len(hist)] = hist
-            lens[seq.slot] = len(hist)
-        proposals = np.zeros((B, k), np.int32)
-        for i in range(k):
-            nxt = np.asarray(self._draft(self._draft_params,
-                                         jnp.asarray(toks),
-                                         jnp.asarray(lens)))
-            proposals[:, i] = nxt
-            can = lens < S
-            toks[np.arange(B)[can], lens[can]] = nxt[can]
-            lens[can] += 1
+        with telemetry.span("serve.decode.draft"):
+            toks = np.zeros((B, S), np.int32)
+            lens = np.zeros(B, np.int32)
+            for seq in batch:
+                hist = list(seq.request.tokens) + seq.generated
+                toks[seq.slot, :len(hist)] = hist
+                lens[seq.slot] = len(hist)
+            proposals = np.zeros((B, k), np.int32)
+            for i in range(k):
+                nxt = np.asarray(self._draft(self._draft_params,
+                                             jnp.asarray(toks),
+                                             jnp.asarray(lens)))
+                proposals[:, i] = nxt
+                can = lens < S
+                toks[np.arange(B)[can], lens[can]] = nxt[can]
+                lens[can] += 1
 
         # 2. verify all k+1 positions in one extend forward
-        tokens = np.zeros((B, E), np.int32)
-        positions = np.full((B, E), W, np.int32)   # pad -> masked query
-        lengths = np.zeros(B, np.int32)
-        write_rows = np.zeros((B, E), np.int32)    # pad -> trash row
-        window_rows = np.zeros((B, W), np.int32)
-        for seq in batch:
-            s, L, ke = seq.slot, seq.length, spans[seq.slot]
-            if self.prefix_caching:
-                self._apply_copies(seq.table.ensure_writable(
-                    L - 1, L + ke, self.scheduler.allocator))
-            tokens[s, 0] = seq.last_token
-            tokens[s, 1:ke + 1] = proposals[s, :ke]
-            positions[s, :ke + 1] = np.arange(L - 1, L + ke)
-            lengths[s] = L + ke
-            write_rows[s, :ke + 1] = [seq.table.row_of(p)
-                                      for p in range(L - 1, L + ke)]
-            window_rows[s] = seq.table.window_rows()
-        logits, self.pool = self._extend_spec(
-            self.params, self.pool, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(lengths),
-            jnp.asarray(write_rows), jnp.asarray(window_rows))
-        target_next = np.asarray(jnp.argmax(logits, axis=-1))  # (B, E)
+        with telemetry.span("serve.decode.build"):
+            tokens = np.zeros((B, E), np.int32)
+            positions = np.full((B, E), W, np.int32)  # pad -> masked query
+            lengths = np.zeros(B, np.int32)
+            write_rows = np.zeros((B, E), np.int32)   # pad -> trash row
+            window_rows = np.zeros((B, W), np.int32)
+            for seq in batch:
+                s, L, ke = seq.slot, seq.length, spans[seq.slot]
+                if self.prefix_caching:
+                    self._apply_copies(seq.table.ensure_writable(
+                        L - 1, L + ke, self.scheduler.allocator))
+                tokens[s, 0] = seq.last_token
+                tokens[s, 1:ke + 1] = proposals[s, :ke]
+                positions[s, :ke + 1] = np.arange(L - 1, L + ke)
+                lengths[s] = L + ke
+                write_rows[s, :ke + 1] = [seq.table.row_of(p)
+                                          for p in range(L - 1, L + ke)]
+                window_rows[s] = seq.table.window_rows()
+        with telemetry.span("serve.decode.launch"):
+            logits, self.pool = self._extend_spec(
+                self.params, self.pool, jnp.asarray(tokens),
+                jnp.asarray(positions), jnp.asarray(lengths),
+                jnp.asarray(write_rows), jnp.asarray(window_rows))
+        with telemetry.span("serve.decode.wait"):
+            target_next = np.asarray(jnp.argmax(logits, axis=-1))  # (B, E)
 
         # 3. commit the agreeing prefix + the target's next token
-        emit = telemetry.enabled()
-        committed_total = 0
-        for seq in batch:
-            s, ke = seq.slot, spans[seq.slot]
-            j = 0
-            while j < ke and proposals[s, j] == target_next[s, j]:
-                j += 1
-            self._m_spec_proposed.increment(ke)
-            self._m_spec_accepted.increment(j)
-            self._spec_proposed_n += ke
-            self._spec_accepted_n += j
-            for t in target_next[s, :j + 1]:
-                self.scheduler.append_token(seq, int(t))
-                committed_total += 1
-                if emit:
-                    self._emit_token(seq)
-                if seq.done:
-                    break
+        with telemetry.span("serve.decode.commit") as sp:
+            emit = telemetry.enabled()
+            committed_total = 0
+            for seq in batch:
+                s, ke = seq.slot, spans[seq.slot]
+                j = 0
+                while j < ke and proposals[s, j] == target_next[s, j]:
+                    j += 1
+                self._m_spec_proposed.increment(ke)
+                self._m_spec_accepted.increment(j)
+                self._spec_proposed_n += ke
+                self._spec_accepted_n += j
+                for t in target_next[s, :j + 1]:
+                    self.scheduler.append_token(seq, int(t))
+                    committed_total += 1
+                    if emit:
+                        self._emit_token(seq)
+                    if seq.done:
+                        break
+            sp["tokens"] = committed_total
         return committed_total
 
     def step(self) -> list[dict]:
@@ -924,55 +939,69 @@ class InferenceEngine:
         self._poll_pending_swap()
         sched = self.scheduler
         finished: list[dict] = []
+        cache = sched.prefix_cache
         with telemetry.span("serve.step", step=self._step_idx) as sp:
             # 1. retire finished sequences -> blocks free immediately
-            for seq in list(sched.finished()):
-                finished.append(self._complete(seq))
-            defer_p0 = sched.deferred_prefill
-            defer_b0 = sched.deferred_blocks
-            admitted = sched.admit()
+            with telemetry.span("serve.retire") as rsp:
+                free0 = sched.allocator.num_free
+                for seq in list(sched.finished()):
+                    finished.append(self._complete(seq))
+                rsp["finished"] = len(finished)
+                rsp["blocks_freed"] = sched.allocator.num_free - free0
+            # 2. admit: prefix match, token budget, allocation, eviction
+            with telemetry.span("serve.schedule") as ssp:
+                defer_p0 = sched.deferred_prefill
+                defer_b0 = sched.deferred_blocks
+                evict0 = cache.evictions if cache is not None else 0
+                admitted = sched.admit()
+                ssp["admitted"] = len(admitted)
+                # deferral split BY CAUSE (this step's deltas): prefill
+                # budget pressure vs pool exhaustion — the bench reads
+                # these to attribute p99 to interference
+                counts = {
+                    "deferred_prefill": sched.deferred_prefill - defer_p0,
+                    "deferred_blocks": sched.deferred_blocks - defer_b0}
+                if admitted and telemetry.recording():
+                    ssp["prompt_tokens"] = sum(s.prompt_len
+                                               for s in admitted)
+                    counts["cached_tokens"] = sum(s.cached_tokens
+                                                  for s in admitted)
+                counts = {k: v for k, v in counts.items() if v}
+                ssp.update(counts)
+                if cache is not None and cache.evictions > evict0:
+                    ssp["evicted_blocks"] = cache.evictions - evict0
             for seq in admitted:
                 self._prefill_one(seq)
             # scoring requests (max_new_tokens=0) finish at prefill
             for seq in list(sched.finished()):
                 finished.append(self._complete(seq))
-            if self._decode is None:
-                batch = []
-            elif self.spec_k:
-                spec_before = self._spec_proposed_n
-                acc_before = self._spec_accepted_n
-                batch = sched.grow_for_decode(
-                    lambda s: self._spec_span(s) + 1)
-                if batch:
-                    self._speculative_batch(batch)
-                sp["proposed_drafts"] = (self._spec_proposed_n
-                                         - spec_before)
-                sp["accepted_drafts"] = (self._spec_accepted_n
-                                         - acc_before)
-            else:
-                batch = sched.grow_for_decode()
-                if batch:
-                    self._decode_batch(batch)
+            batch = []
+            if self._decode is not None:
+                with telemetry.span("serve.decode") as dsp:
+                    if self.spec_k:
+                        spec_before = self._spec_proposed_n
+                        acc_before = self._spec_accepted_n
+                        batch = sched.grow_for_decode(
+                            lambda s: self._spec_span(s) + 1)
+                        if batch:
+                            self._speculative_batch(batch)
+                        sp["proposed_drafts"] = (self._spec_proposed_n
+                                                 - spec_before)
+                        sp["accepted_drafts"] = (self._spec_accepted_n
+                                                 - acc_before)
+                    else:
+                        batch = sched.grow_for_decode()
+                        if batch:
+                            self._decode_batch(batch)
+                    dsp["live"] = len(batch)
             sp["admitted"] = len(admitted)
             sp["decoded"] = len(batch)
             sp["finished"] = len(finished)
             sp["queued"] = len(sched.queue)
             sp["blocks_free"] = sched.allocator.num_free
-            # deferral split BY CAUSE (this step's deltas): prefill
-            # budget pressure vs pool exhaustion — the bench reads
-            # these off serve.step to attribute p99 to interference
-            if sched.deferred_prefill > defer_p0:
-                sp["deferred_prefill"] = (sched.deferred_prefill
-                                          - defer_p0)
-            if sched.deferred_blocks > defer_b0:
-                sp["deferred_blocks"] = (sched.deferred_blocks
-                                         - defer_b0)
-            if admitted:
-                cached = sum(s.cached_tokens for s in admitted)
-                if cached:
-                    sp["cached_tokens"] = cached
-            if sched.prefix_cache is not None:
-                self._m_cache_blocks.set(len(sched.prefix_cache))
+            sp.update(counts)        # the step's record carries them too
+            if cache is not None:
+                self._m_cache_blocks.set(len(cache))
         self._step_idx += 1
         step_s = time.monotonic() - t0
         self._m_step.record(step_s)
@@ -987,15 +1016,22 @@ class InferenceEngine:
                 sched.preemptions - self._m_preempt.value)
         return finished
 
+    def _ttft(self, seq: Sequence) -> "float | None":
+        """Seconds from ``submit()`` to the sequence's first token:
+        queueing (``queue_wait_s`` of ``serve.prefill``) and prefill."""
+        if seq.first_token_s is None:
+            return None
+        return seq.first_token_s - self._submit_mono.get(
+            seq.request.id, seq.admitted_s)
+
     def _complete(self, seq: Sequence) -> dict:
         self.scheduler.finish(seq)
         req = seq.request
         now = time.time()
         arrival = self._submitted.pop(req.id, now)
-        self._submit_mono.pop(req.id, None)
         latency = max(0.0, now - arrival)
-        ttft = ((seq.first_token_s - seq.admitted_s)
-                if seq.first_token_s is not None else None)
+        ttft = self._ttft(seq)
+        self._submit_mono.pop(req.id, None)
         generated = list(req.generated_prefix) + list(seq.generated)
         tokens = (generated if (req.max_new_tokens > 0
                                 or req.generated_prefix)
@@ -1082,8 +1118,7 @@ class InferenceEngine:
                              jnp.asarray(self._block_rows(blocks)))
             arrays = {n: np.asarray(jax.device_get(a))
                       for n, a in g.items()}
-            ttft = ((seq.first_token_s - seq.admitted_s)
-                    if seq.first_token_s is not None else None)
+            ttft = self._ttft(seq)
             payload = _migrate.MigrationPayload(
                 request_id=rid, tokens=tuple(seq.request.tokens),
                 max_new_tokens=seq.request.max_new_tokens,
@@ -1163,15 +1198,16 @@ class InferenceEngine:
             self.pool = self._insert(
                 self.pool, jnp.asarray(self._block_rows(blocks)), vals)
             seq.preemptions = payload.preemptions
-            if payload.ttft_s is not None:
-                # preserve the SOURCE-measured time-to-first-token
-                # (_complete reports first_token_s - admitted_s)
-                seq.first_token_s = seq.admitted_s + payload.ttft_s
             self._submitted[rid] = (
                 arrival_wall if arrival_wall is not None
                 else payload.arrival_wall
                 if payload.arrival_wall is not None else time.time())
             self._submit_mono[rid] = time.monotonic()
+            if payload.ttft_s is not None:
+                # preserve the SOURCE-measured time-to-first-token
+                # (_complete reports first_token_s - its submit time)
+                seq.first_token_s = (self._submit_mono[rid]
+                                     + payload.ttft_s)
         ledger = _goodput.active_ledger()
         if ledger is not None:
             ledger.record("kv_migrate", time.monotonic() - t0)

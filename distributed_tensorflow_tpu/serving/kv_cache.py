@@ -480,12 +480,12 @@ class PrefixCache:
         partial hop may match a cached block whose tokens extend the
         prompt's sub-block tail. Every returned block's refcount is
         bumped; the caller owns (and must eventually free) those refs.
+        A match counts nothing: the caller reports the one that led to
+        an admission through :meth:`count_admission`.
         """
         tokens = tuple(int(t) for t in tokens)
         limit = len(tokens) - 1
         self._clock += 1
-        self.lookups += 1
-        self.lookup_tokens += max(0, limit)
         bs = self.block_size
         key = None
         blocks: list[int] = []
@@ -518,10 +518,18 @@ class PrefixCache:
                 self._alloc.incref(best.block)
                 blocks.append(best.block)
                 n += len(rest)
-        if n:
-            self.hit_tokens += n
-            self.hit_requests += 1
         return n, blocks
+
+    def count_admission(self, n_tokens: int, n_cached: int) -> None:
+        """Count one admitted request's lookup (``n_tokens`` prompt
+        tokens, ``n_cached`` of them matched). Counted here and not in
+        :meth:`match` because a deferred head request is matched again
+        at every step until it fits, and would count each time."""
+        self.lookups += 1
+        self.lookup_tokens += max(0, n_tokens - 1)
+        if n_cached:
+            self.hit_tokens += n_cached
+            self.hit_requests += 1
 
     def register(self, tokens, blocks) -> int:
         """Index every FULL block of a just-prefilled prompt

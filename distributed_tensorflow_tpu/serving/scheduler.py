@@ -287,6 +287,8 @@ class ContinuousBatchingScheduler:
                     self._m_deferred_blocks.increment()
                     break                   # wait for blocks to free up
             self.queue.pop()
+            if self.prefix_cache is not None:
+                self.prefix_cache.count_admission(len(req.tokens), cached)
             slot = self._free_slots.pop()
             table = BlockTable(self.cache_cfg, self.max_blocks_per_seq)
             table.blocks = list(cblocks)    # match()'s refs transfer here

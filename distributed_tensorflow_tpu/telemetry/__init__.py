@@ -46,7 +46,9 @@ Quick start::
         state, metrics = step_fn(state, batch)
 
 Telemetry is OFF by default: with no event log configured and no
-publisher started, instrumented call sites cost one None check.
+publisher started, instrumented call sites cost one None check; a
+``span`` is also a ``jax.profiler.TraceAnnotation``, one flag test
+while no profiler session runs.
 """
 
 from distributed_tensorflow_tpu.telemetry.registry import (
@@ -72,6 +74,7 @@ from distributed_tensorflow_tpu.telemetry.events import (
     get_event_log,
     read_events,
     read_run,
+    recording,
     shutdown,
     span,
 )
@@ -130,7 +133,7 @@ __all__ = [
     "counter", "gauge", "get_registry", "histogram", "timer",
     "ENV_TELEMETRY_DIR", "EventLog", "EventLogCorruptError", "configure",
     "enabled", "event", "event_log_path", "get_event_log", "read_events",
-    "read_run", "shutdown", "span",
+    "read_run", "recording", "shutdown", "span",
     "FleetAggregator", "MetricsPublisher", "RollupTopology",
     "collect_rollup", "collect_rollup_tree", "merge_rollup",
     "publish_snapshot", "read_snapshots", "rollup_scalars",

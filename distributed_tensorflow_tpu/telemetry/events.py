@@ -27,9 +27,13 @@ API::
     with telemetry.span("checkpoint.save", path=p):
         ...                              # emits dur_s on exit
 
-With no log configured — the production default — ``event``/``span``
-are a single module-global None check: zero overhead, no allocation
-(same contract as resilience/faults.fire).
+With no log configured — the production default — ``event`` is a single
+module-global None check (same contract as resilience/faults.fire).
+``span`` is, besides, the program's one trace annotation: it always
+enters a ``jax.profiler.TraceAnnotation``, which is one flag test in
+C++ while no profiler session runs and, while one does, puts the span
+into the profiler's trace on the clock of the device planes
+(:func:`span`).
 
 Reading back: :func:`read_events` parses a file, tolerating a torn
 final line (a crashed process mid-write) but refusing mid-file
@@ -44,6 +48,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 
 class EventLogCorruptError(ValueError):
@@ -264,6 +270,13 @@ def enabled() -> bool:
     return _LOG is not None
 
 
+def recording() -> bool:
+    """True when a span's fields go anywhere: a profiler session is
+    active or an event log is configured. Span sites compute a field
+    that costs more than a ``len()`` only under this."""
+    return _LOG is not None or TraceAnnotation.is_enabled()
+
+
 def event(name: str, **fields):
     """Module-level event against the process-wide log; no-op (one
     None check) when telemetry is off."""
@@ -273,15 +286,45 @@ def event(name: str, **fields):
     return log.event(name, **fields)
 
 
+def _stats(fields: dict) -> dict:
+    """``fields`` as a trace annotation carries them: ``None`` left out
+    (the annotation would write the word), everything but a number as
+    its ``str``."""
+    return {k: v if isinstance(v, (int, float)) else str(v)
+            for k, v in fields.items() if v is not None}
+
+
 @contextlib.contextmanager
 def span(name: str, **fields):
-    """Module-level span; a plain passthrough when telemetry is off."""
+    """The one way the program opens a span. Yields a dict the body may
+    add result fields to (``sp["bytes"] = n``).
+
+    The span is a ``jax.profiler.TraceAnnotation``: while a profiler
+    session is active it lands in the trace's host plane with ``fields``
+    and the body's additions as its stats, on the clock the device
+    planes use, so idle gaps of the chip can be given to it; with no
+    session it is one flag test and the fields are never formatted.
+    With an event log configured it also writes the JSONL span record
+    of :meth:`EventLog.span`.
+
+    A span names no parent. Parentage is nesting in time on one thread:
+    a span that starts and ends inside another on the same thread is
+    its child (every span inside an engine step nests in that step's
+    ``serve.step``, which carries ``step=``). Spans of one request share
+    ``id=<request id>`` and ``span_id=request_span_id(id)``."""
     log = _LOG
-    if log is None:
-        yield {}
-        return
-    with log.span(name, **fields) as extra:
-        yield extra
+    traced = TraceAnnotation.is_enabled()
+    with TraceAnnotation(name, **(_stats(fields) if traced else {})) as ann:
+        extra: dict = {}
+        try:
+            if log is None:
+                yield extra
+            else:
+                with log.span(name, **fields) as extra:
+                    yield extra
+        finally:
+            if traced and extra:
+                ann.set_metadata(**_stats(extra))
 
 
 # Env activation (≙ faults.DTX_FAULT_SCHEDULE): spawned multi-process

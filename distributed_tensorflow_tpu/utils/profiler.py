@@ -190,89 +190,6 @@ def step_marker(step: int):
 
 
 # ---------------------------------------------------------------------------
-# Op-profile analysis: read the collected XPlane back into a per-op table
-# (≙ the op_profile view of tensorboard_plugin_profile, which cannot load
-# in every environment — this gives the same answer as a plain API).
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class OpTime:
-    name: str          # HLO op name (truncated to the metadata string)
-    total_ms: float    # summed device time across the collected trace
-    fraction: float    # share of total device op time
-    count: int         # number of trace events
-
-
-def _load_xspace(logdir: str):
-    """Locate and parse the newest ``*.xplane.pb`` under ``logdir``."""
-    import glob
-    import os
-    paths = sorted(glob.glob(
-        os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb")))
-    if not paths:
-        raise FileNotFoundError(
-            f"no xplane.pb under {logdir}/plugins/profile — call "
-            f"profiler.start/stop (or profiler.trace) first")
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except Exception as e:                      # pragma: no cover
-        raise ImportError(
-            "op_profile needs the xplane proto bindings (shipped with "
-            "tensorflow); install tensorflow or read the raw trace with "
-            f"xprof: {e}") from e
-    xs = xplane_pb2.XSpace()
-    with open(paths[-1], "rb") as f:
-        xs.ParseFromString(f.read())
-    return xs
-
-
-def op_profile(logdir: str, top: int = 20,
-               device_substr: str = "TPU") -> "list[OpTime]":
-    """Aggregate device op time from a collected trace.
-
-    Returns the ``top`` ops by total device time on the first device
-    plane matching ``device_substr`` (line "XLA Ops" — the serialized
-    op timeline). Use after ``profiler.profile(logdir)``::
-
-        with profiler.profile("/tmp/prof"):
-            train_step(...)
-        for row in profiler.op_profile("/tmp/prof"):
-            print(f"{row.total_ms:8.2f}ms {row.fraction:5.1%} {row.name}")
-    """
-    xs = _load_xspace(logdir)
-    from collections import defaultdict
-    for plane in xs.planes:
-        if device_substr not in plane.name:
-            continue
-        emeta = {k: m.name for k, m in plane.event_metadata.items()}
-        # TPU device planes carry a serialized "XLA Ops" timeline; the
-        # CPU backend instead records per-thread executor lines
-        # (tf_xla-cpu-codegen/...). Prefer the former, fall back to the
-        # latter so the same call works against the CPU test backend.
-        lines = [ln for ln in plane.lines if ln.name == "XLA Ops"]
-        if not lines:
-            lines = [ln for ln in plane.lines
-                     if ln.name.lower().startswith("tf_xla")]
-        tot = defaultdict(lambda: [0, 0])
-        for line in lines:
-            for ev in line.events:
-                cell = tot[emeta.get(ev.metadata_id, "?")]
-                cell[0] += ev.duration_ps
-                cell[1] += 1
-        if not tot:
-            continue
-        total_ps = sum(v[0] for v in tot.values()) or 1
-        rows = [OpTime(name=name, total_ms=ps / 1e9,
-                       fraction=ps / total_ps, count=n)
-                for name, (ps, n) in tot.items()]
-        rows.sort(key=lambda r: -r.total_ms)
-        return rows[:top]
-    raise ValueError(
-        f"no plane matching {device_substr!r} with XLA op events found "
-        f"(planes: {[p.name for p in xs.planes]})")
-
-
-# ---------------------------------------------------------------------------
 # Host input-pipeline telemetry (≙ tf.data's iterator/autotune stats,
 # TF/python/data/experimental/ops/stats_ops.py): every concurrent pipeline
 # stage (parallel map/interleave, prefetch, infeed) owns a StageStats and
