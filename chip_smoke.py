@@ -35,6 +35,7 @@ import json
 import math
 import os
 import random
+import re
 import sys
 import time
 
@@ -252,6 +253,61 @@ def _serve(engine: InferenceEngine, requests: list, tag: str) -> dict:
     return out
 
 
+#: opcodes whose output of the pool's size IS the pool, where it lies:
+#: the argument, views of it, tuple and loop plumbing, a kernel whose
+#: output aliases its operand, an update in place
+_IN_PLACE = frozenset({
+    "parameter", "bitcast", "get-tuple-element", "tuple", "while",
+    "opt-barrier", "custom-call", "dynamic-update-slice"})
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w-]+)\(")
+_HLO_SHAPE = re.compile(r"\w+\[([\d,]+)\]")
+
+
+def pool_sized_ops(hlo_text: str, sizes) -> list[str]:
+    """The instructions of an optimised HLO module that PRODUCE an array
+    of one of ``sizes`` elements (the pool's, one pool layer's, every
+    slot's whole window), in any type and any order of dimensions:
+    copies, gathers, scatters, converts, slices, fusions. What only
+    hands the pool on in place (``_IN_PLACE``) does not count."""
+    sizes = set(sizes)
+    found = []
+    for line in hlo_text.splitlines():
+        m = _HLO_LINE.match(line)
+        if m is None or m.group(2) in _IN_PLACE:
+            continue
+        for dims in _HLO_SHAPE.findall(m.group(1)):
+            if math.prod(int(d) for d in dims.split(",")) in sizes:
+                found.append(line.strip()[:200])
+                break
+    return found
+
+
+def decode_program_check(engine: InferenceEngine) -> None:
+    """Which way the engine's decode program reaches the KV pool; on the
+    paged path (what a TPU takes) the compiled program must hold nothing
+    of the pool's size: a whole-pool relayout or a whole-window gather
+    back in ``jit_decode`` stops a smoke run, not a benchmark."""
+    print(f"  decode kv_path: {engine.kv_path}", flush=True)
+    if engine.kv_path != "paged":
+        return
+    cc, slots = engine.cache_cfg, engine.max_slots
+    row = cc.n_heads * cc.head_dim
+    rows = cc.num_blocks * cc.block_size
+    sizes = {cc.n_layers * rows * row, rows * row,
+             slots * engine.window * row}
+    i32 = jnp.zeros((slots,), jnp.int32)
+    table = jnp.zeros((slots, engine.window // cc.block_size), jnp.int32)
+    hlo = engine._decode.lower(engine.params, engine.pool, i32, i32, i32,
+                               i32, table).compile().as_text()
+    found = pool_sized_ops(hlo, sizes)
+    for line in found[:8]:
+        print(f"    {line}", flush=True)
+    check(not found,
+          f"the compiled decode program produces no array of the pool's, "
+          f"one layer's or every slot's window's size ({sorted(sizes)} "
+          f"elements); found {len(found)}")
+
+
 def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
                 clock: CompileClock, seed: int = 0) -> None:
     """Serve seeded requests through one prefix-caching engine on
@@ -276,6 +332,10 @@ def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
         on = {d for leaf in jax.tree_util.tree_leaves(tree)
               for d in leaf.devices()}
         check(on == {device}, f"engine {name} live on {device}")
+    if device.platform == "tpu":
+        check(engine.kv_path == "paged",
+              "on a TPU the engine's decode program takes the paged path")
+    decode_program_check(engine)
 
     cold = seeded_requests(seed, shapes.n_requests, cfg.vocab_size,
                            prompt_range=shapes.prompt_range,

@@ -1,0 +1,354 @@
+"""Paged attention for the serving decode step (Pallas, TPU).
+
+One query per running slot against the K and V of that slot's LIVE
+blocks only, read out of the block-allocated pool (serving/kv_cache.py)
+where it lies. The window path of ``serving/decode.py`` gathers every
+slot's full ``max_seq_len`` window out of the pool on every step, so
+its cost follows ``max_slots × max_seq_len``; this kernel's follows the
+live tokens.
+
+**The layout decides the shape of the kernel.** The pool is
+``(L, rows, H, hd)`` and the TPU keeps an array whose minor dimension is
+under 128 with its largest dimension minor-most: ``{1,3,2,0}``, rows on
+the lanes, ``hd`` on the sublanes, tiled ``(8,128)(2,1)`` for bfloat16.
+XLA's gather and scatter ask for ``{3,2,1,0}`` and pay a copy of the
+whole pool on each side of every program that uses them. A custom call
+takes its operands major-to-minor, so the kernel is handed the pool
+through the transpose ``(L, H, hd, rows)``, which in that layout is a
+bitcast: per head a ``K^T`` tile with the rows on the lanes. A DMA out of
+such an array moves whole 128-lane tiles, so the unit the kernel reads is
+a *group* of 128 consecutive rows (``128 / block_size`` blocks), and the
+lanes that are not this slot's live rows are masked. Consecutive entries
+of a slot's block table that fall into one group (the allocator hands
+out lowest ids first, so a prompt's blocks mostly do) are merged into
+one *run*: one DMA, one pass.
+
+The grid is flat over the runs of all slots (its size is a traced
+number), with the run list as scalar prefetch, so the pipeline of
+``pallas_call`` itself double-buffers the groups across slot boundaries
+and skips a group it already holds. Per run: float32 logits as an
+elementwise product with the lane-broadcast query reduced over the
+sublanes, online softmax, and the value product accumulated per lane and
+reduced over the lanes once per slot. Arithmetic is ``mha_reference``'s:
+K and V as stored, converted to float32 in VMEM; float32 logits, softmax
+and accumulation; the same ``sm_scale`` and visibility rule.
+
+The token being decoded never comes out of the pool: its K and V are in
+registers when the step runs, so :func:`paged_attention_decode` merges
+that one key into the softmax after the kernel (the query sees its own
+position whatever the order of read and write), and the pool is written
+once per step, after the last layer, by :func:`write_rows`: a second
+kernel that takes the pool in the same view, aliased to its output, and
+rewrites only the groups that hold a written row.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from distributed_tensorflow_tpu.ops.attention import DEFAULT_MASK_VALUE
+
+#: Rows of the pool one DMA moves: the lane width of a TPU tile.
+GROUP_ROWS = 128
+
+
+def supported(rows: int, block_size: int, head_dim: int, dtype) -> bool:
+    """Whether a pool of this shape can be read by groups: the rows
+    tile by 128, blocks tile a group (a power of two, so lane → block is
+    a shift, and at most 31 of them, one bit each), ``head_dim`` fills
+    the dtype's sublane packing, and the pool is floating point."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating):
+        return False
+    packing = 8 * (4 // dtype.itemsize)
+    return (rows % GROUP_ROWS == 0 and block_size & (block_size - 1) == 0
+            and 8 <= block_size <= GROUP_ROWS and head_dim % packing == 0)
+
+
+def decode_plan(block_table, lengths, *, block_size: int) -> dict:
+    """The run list of one decode step, shared by every layer.
+
+    ``block_table`` (B, max_blocks) int32 physical blocks in logical
+    order (anything past a slot's live blocks is ignored), ``lengths``
+    (B,) keys visible in the pool. A *run* is a maximal stretch of
+    consecutive table entries inside one 128-row group. Returns int32
+    arrays: per run (flat, slot-major, ``B * max_blocks`` long) its
+    ``slot``, its ``group`` and the ``bits`` of the group's blocks it
+    holds; per slot the ``first`` run, the ``count`` of runs, and
+    ``tail`` = which block of its group the slot's last block is
+    (high bits) and how many of its rows are live (low 8 bits); and
+    ``n_runs`` ``(1,)``, the grid's size."""
+    B, M = block_table.shape
+    per_group = GROUP_ROWS // block_size
+    lengths = lengths.astype(jnp.int32)
+    n_blocks = (lengths + block_size - 1) // block_size           # (B,)
+    j = jnp.arange(M, dtype=jnp.int32)[None]
+    live = j < n_blocks[:, None]
+    group = block_table // per_group
+    sub = block_table % per_group
+    prev = jnp.concatenate([jnp.full((B, 1), -1, jnp.int32),
+                            group[:, :-1]], axis=1)
+    starts = live & (group != prev)
+    count = jnp.sum(starts, axis=1, dtype=jnp.int32)              # (B,)
+    first = jnp.cumsum(count, dtype=jnp.int32) - count
+    run = first[:, None] + jnp.cumsum(starts, axis=1, dtype=jnp.int32) - 1
+    N = B * M
+    run = jnp.where(live, run, N).reshape(-1)                     # N: dropped
+    slot = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, M))
+    zeros = jnp.zeros((N,), jnp.int32)
+    last = jnp.maximum(n_blocks - 1, 0)
+    tail_sub = jnp.take_along_axis(sub, last[:, None], axis=1)[:, 0]
+    tail_rows = lengths - last * block_size
+    return {
+        "slot": zeros.at[run].set(slot.reshape(-1), mode="drop"),
+        "group": zeros.at[run].set(group.reshape(-1), mode="drop"),
+        "bits": zeros.at[run].add((1 << sub).reshape(-1), mode="drop"),
+        "first": first, "count": count,
+        "tail": tail_sub * 256 + tail_rows,
+        "n_runs": jnp.sum(count, dtype=jnp.int32)[None],
+    }
+
+
+def _decode_kernel(layer_ref, slot_ref, group_ref, bits_ref, first_ref,
+                   count_ref, tail_ref,                     # scalar prefetch
+                   q_ref, k_ref, v_ref,                     # inputs
+                   o_ref, m_ref, l_ref,                     # outputs
+                   qb_scr, acc_scr, s_scr, p_scr, a_scr, m_scr, l_scr,
+                   *, sm_scale: float, block_size: int, n_heads: int):
+    """One run: 128 rows of one slot's K and V, ``(H, hd, 128)`` each."""
+    del layer_ref, group_ref                     # the index maps read them
+    i = pl.program_id(0)
+    b = slot_ref[i]
+    is_first = i == first_ref[b]
+    is_last = i == first_ref[b] + count_ref[b] - 1
+    hd = q_ref.shape[1]
+
+    @pl.when(is_first)
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, DEFAULT_MASK_VALUE, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+        for h in range(n_heads):
+            qb_scr[h] = jnp.broadcast_to(q_ref[0, :, h:h + 1],
+                                         (hd, GROUP_ROWS))
+
+    # which lanes are this slot's live rows
+    shape = (n_heads, GROUP_ROWS)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    shift = block_size.bit_length() - 1
+    lane_sub = lane >> shift
+    held = (jnp.full(shape, bits_ref[i], jnp.int32) >> lane_sub) & 1
+    tail = jnp.where(is_last, tail_ref[b], GROUP_ROWS * 256)
+    cut = (lane_sub == (tail >> 8)) & ((lane & (block_size - 1))
+                                       >= (tail & 255))
+    valid = (held == 1) & jnp.logical_not(cut)
+
+    for h in range(n_heads):
+        kf = k_ref[h].astype(jnp.float32)                    # (hd, 128)
+        s_scr[h:h + 1, :] = jnp.sum(kf * qb_scr[h], axis=0, keepdims=True)
+    s = jnp.where(valid, s_scr[...] * sm_scale, DEFAULT_MASK_VALUE)
+    m_prev = m_scr[...]                                      # (H, 128)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[...] = m_new
+    p_scr[...] = p
+    a_scr[...] = alpha
+    for h in range(n_heads):
+        vf = v_ref[h].astype(jnp.float32)                    # (hd, 128)
+        acc_scr[h] = (acc_scr[h] * a_scr[h:h + 1, :]
+                      + vf * p_scr[h:h + 1, :])
+
+    @pl.when(is_last)
+    def _():
+        for h in range(n_heads):
+            o_ref[0, :, h:h + 1] = jnp.sum(acc_scr[h], axis=1,
+                                           keepdims=True)
+        m_ref[0] = m_scr[...]
+        l_ref[0] = l_scr[...]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("sm_scale", "block_size", "interpret"))
+def _pool_attention(q, k_pool, v_pool, layer, plan, *, sm_scale: float,
+                    block_size: int, interpret: bool):
+    """The kernel call: attention of ``q`` (B, H, hd) over the pool keys
+    the plan lists, unnormalised. Returns float32 ``o`` (B, H, hd) =
+    Σ exp(s − m)·v, ``m`` (B, H) the running maximum and ``l`` (B, H) =
+    Σ exp(s − m); a slot with no run reads ``o = l = 0`` and the mask
+    value for ``m``. Jitted so that a program which calls it once per
+    layer (``layer`` is an operand) traces and lowers the kernel once:
+    lowering a Pallas kernel is Python time that no compile cache saves."""
+    B, H, hd = q.shape
+    L, rows = k_pool.shape[:2]
+    # (L, rows, H, hd) -> (L, H, hd, rows): the layout the pool lies in
+    kt = jnp.transpose(k_pool, (0, 2, 3, 1))
+    vt = jnp.transpose(v_pool, (0, 2, 3, 1))
+    qt = jnp.transpose(q.astype(jnp.float32), (0, 2, 1))         # (B, hd, H)
+
+    def slot_map(i, layer_r, slot_r, *_):
+        return (slot_r[i], 0, 0)
+
+    def pool_map(i, layer_r, slot_r, group_r, *_):
+        return (layer_r[0], 0, 0, group_r[i])
+
+    stat = pl.BlockSpec((1, H, GROUP_ROWS), slot_map)
+    wide = pltpu.VMEM((H, hd, GROUP_ROWS), jnp.float32)
+    row = pltpu.VMEM((H, GROUP_ROWS), jnp.float32)
+    o, m, l = pl.pallas_call(
+        functools.partial(_decode_kernel, sm_scale=sm_scale,
+                          block_size=block_size, n_heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(plan["n_runs"][0],),
+            in_specs=[
+                pl.BlockSpec((1, hd, H), slot_map),
+                pl.BlockSpec((None, H, hd, GROUP_ROWS), pool_map),
+                pl.BlockSpec((None, H, hd, GROUP_ROWS), pool_map),
+            ],
+            out_specs=[pl.BlockSpec((1, hd, H), slot_map), stat, stat],
+            scratch_shapes=[wide, wide, row, row, row, row, row],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, hd, H), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, GROUP_ROWS), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, GROUP_ROWS), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attn_decode",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan["slot"], plan["group"],
+      plan["bits"], plan["first"], plan["count"], plan["tail"], qt, kt, vt)
+    # a slot with no run was never written: take nothing from its rows
+    seen = (plan["count"] > 0)[:, None]
+    o = jnp.where(seen[..., None], jnp.transpose(o, (0, 2, 1)), 0.0)
+    m = jnp.where(seen, m[:, :, 0], DEFAULT_MASK_VALUE)
+    l = jnp.where(seen, l[:, :, 0], 0.0)
+    return o, m, l
+
+
+def paged_attention_decode(q, k_new, v_new, k_pool, v_pool, layer, plan,
+                           lengths, *, block_size: int,
+                           sm_scale: float | None = None,
+                           interpret: bool = False):
+    """Attention of one query per slot over positions ``0..length-1``.
+
+    ``q`` (B, H, hd) sits at position ``lengths - 1``; ``k_new`` /
+    ``v_new`` (B, H, hd) are that position's K and V in the pool's dtype
+    (as the pool will hold them); positions below it are read out of
+    layer ``layer`` of ``k_pool`` / ``v_pool`` (L, rows, H, hd) through
+    ``plan`` = :func:`decode_plan` of the block table and
+    ``lengths - 1``. A slot of length 0 attends nothing and returns
+    zeros. Returns (B, H, hd) in ``q.dtype``."""
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    o, m, l = _pool_attention(q, k_pool, v_pool, layer, plan,
+                              sm_scale=sm_scale, block_size=block_size,
+                              interpret=interpret)
+    s_new = jnp.sum(q.astype(jnp.float32) * k_new.astype(jnp.float32),
+                    axis=-1) * sm_scale                            # (B, H)
+    top = jnp.maximum(m, s_new)
+    w_pool = jnp.exp(m - top)[..., None]
+    w_new = jnp.exp(s_new - top)[..., None]
+    out = ((w_pool * o + w_new * v_new.astype(jnp.float32))
+           / (w_pool * l[..., None] + w_new))
+    return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
+
+
+def _write_kernel(rows_ref, order_ref, start_ref, group_ref,  # prefetch
+                  kn_ref, vn_ref, k_ref, v_ref,               # inputs
+                  ko_ref, vo_ref,                             # outputs
+                  *, n_heads: int):
+    """One 128-row group of one layer, K and V: every slot whose row
+    lies in it puts its ``(H, hd)`` column into the row's lane."""
+    del group_ref                                # the index maps read it
+    s = pl.program_id(1)
+    hd = k_ref.shape[1]
+    ko_ref[...] = k_ref[...]
+    vo_ref[...] = v_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hd, GROUP_ROWS), 1)
+
+    def one(t, carry):
+        b = order_ref[t]
+        here = lane == rows_ref[b] % GROUP_ROWS
+        for new_ref, out_ref in ((kn_ref, ko_ref), (vn_ref, vo_ref)):
+            new = new_ref[b].astype(jnp.float32)             # (hd, H)
+            for h in range(n_heads):
+                col = jnp.broadcast_to(new[:, h:h + 1], (hd, GROUP_ROWS))
+                out_ref[h] = jnp.where(
+                    here, col, out_ref[h].astype(jnp.float32)
+                ).astype(out_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(start_ref[s], start_ref[s + 1], one, 0)
+
+
+def write_rows(k_pool, v_pool, k_new, v_new, rows, active, *,
+               interpret: bool = False):
+    """Both pools (L, R, H, hd) with row ``rows[b]`` of every layer set
+    to ``k_new[:, b]`` / ``v_new[:, b]`` (``(L, B, H, hd)``) for every
+    slot with ``active[b]``, in place when the pools are donated; every
+    other row stays bit for bit, and an inactive slot writes nothing.
+
+    In the layout the pool lies in a row is one lane of 64 tiles, and a
+    DMA moves whole tiles, so the kernel reads a 128-row group, sets the
+    lanes and writes the group back (``input_output_aliases``: no other
+    group is touched). One grid step per layer and per DISTINCT group,
+    the slots sorted by group, so no two steps in flight ever hold the
+    same rows: the pipeline may fetch ahead and write behind. Slots that
+    name one row are applied in slot order."""
+    L, R, H, hd = k_pool.shape
+    B = rows.shape[0]
+    rows = rows.astype(jnp.int32)
+    # inactive slots sort behind every group and open no step
+    group = jnp.where(active, rows // GROUP_ROWS, R)
+    order = jnp.argsort(group, stable=True).astype(jnp.int32)
+    sorted_group = group[order]
+    opens = jnp.concatenate([jnp.ones((1,), bool),
+                             sorted_group[1:] != sorted_group[:-1]])
+    opens &= sorted_group < R
+    step = jnp.cumsum(opens, dtype=jnp.int32) - 1                  # (B,)
+    n_steps = jnp.sum(opens, dtype=jnp.int32)
+    dropped = B + 1
+    step_group = jnp.zeros((B,), jnp.int32).at[
+        jnp.where(opens, step, dropped)].set(sorted_group, mode="drop")
+    n_active = jnp.sum(active, dtype=jnp.int32)
+    start = jnp.full((B + 1,), n_active, jnp.int32).at[
+        jnp.where(opens, step, dropped)].set(
+            jnp.arange(B, dtype=jnp.int32), mode="drop")
+
+    def pool_map(l, s, rows_r, order_r, start_r, group_r):
+        return (l, 0, 0, group_r[s])
+
+    def new_map(l, s, *_):
+        return (l, 0, 0, 0)
+
+    pool_spec = pl.BlockSpec((None, H, hd, GROUP_ROWS), pool_map)
+    new_spec = pl.BlockSpec((None, B, hd, H), new_map)
+    view = (0, 2, 3, 1)            # (L, R, H, hd) -> (L, H, hd, R): a bitcast
+    kt, vt = (jnp.transpose(a, view) for a in (k_pool, v_pool))
+    kn, vn = (jnp.transpose(a.astype(k_pool.dtype), (0, 1, 3, 2))
+              for a in (k_new, v_new))                      # (L, B, hd, H)
+    kt, vt = pl.pallas_call(
+        functools.partial(_write_kernel, n_heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(L, n_steps),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
+        input_output_aliases={6: 0, 7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="paged_kv_write",
+    )(rows, order, start, step_group, kn, vn, kt, vt)
+    back = (0, 3, 1, 2)
+    return jnp.transpose(kt, back), jnp.transpose(vt, back)

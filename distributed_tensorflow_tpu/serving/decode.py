@@ -11,12 +11,17 @@ tree (stacked-layers layout) and the block-allocated KV pool
   rotary-embedded K and V into the sequences' cache blocks and
   returning each prompt's last-position logits.
 - :func:`make_decode_fn` — ONE token per running slot: project q/k/v
-  for the new token, scatter k/v into the slot's current block, gather
-  the slot's block window, and attend the single query against it.
-  Because prefill wrote the same K/V the full forward computes and the
-  mask is the same factored rule, greedy decode through the cache
-  matches argmax over full-sequence recompute — the correctness
-  contract tests/test_serving.py pins on 1 device and on dp×tp meshes.
+  for the new token, put k/v into the slot's current block, and attend
+  the single query against the slot's keys. Two paths, one contract:
+  the *window* path scatters, gathers the slot's whole block window and
+  runs ``mha_reference`` (plain jnp: meshes, int8 pools, the CPU); the
+  *paged* path reads only the live blocks through the block table
+  (``ops/paged_attention.py``, a Pallas kernel) and writes the pool in
+  place, so the program holds nothing of the pool's size. Because
+  prefill wrote the same K/V the full forward computes and the mask is
+  the same factored rule, greedy decode through the cache matches
+  argmax over full-sequence recompute — the correctness contract
+  tests/test_serving.py pins on 1 device and on dp×tp meshes.
 - :func:`make_extend_fn` — the MULTI-token cache-aware forward: E new
   tokens per slot at explicit absolute positions, written then attended
   against each slot's block window. This is both the prefix-cache
@@ -32,10 +37,11 @@ quantize on the way in, gathers dequantize on the way out, so the whole
 quantisation story lives in :func:`_pool_write` / :func:`_pool_window`
 and the attention math never sees anything but the compute dtype.
 
-Everything here is plain jnp (no Pallas custom calls), so on a serving
-mesh GSPMD partitions the programs directly: slots over ``dp``,
-heads/mlp/vocab over ``tp`` (:func:`param_shardings`), the pool laid
-out by ``kv_cache.pool_shardings``.
+Everything but the paged decode path is plain jnp (no Pallas custom
+calls), so on a serving mesh GSPMD partitions the programs directly:
+slots over ``dp``, heads/mlp/vocab over ``tp`` (:func:`param_shardings`),
+the pool laid out by ``kv_cache.pool_shardings``. GSPMD cannot partition
+a ``pallas_call``, so an engine on a mesh asks for the window path.
 """
 
 from __future__ import annotations
@@ -48,6 +54,7 @@ import numpy as np
 
 from distributed_tensorflow_tpu.models.transformer import (
     TransformerConfig, TransformerLM, mesh_axis_rules, rotary_embedding)
+from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.ops.attention import mha_reference
 
 
@@ -265,30 +272,65 @@ def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None):
     return prefill
 
 
-def make_decode_fn(cfg: TransformerConfig, cache_cfg=None):
+def make_decode_fn(cfg: TransformerConfig, cache_cfg=None, *,
+                   implementation: str | None = None):
     """``decode(params, pool, tokens, positions, lengths, write_rows,
-    window_rows)`` → ``(logits, pool)``.
+    table)`` → ``(logits, pool)``; ``decode.kv_path`` says which path
+    was built and so what ``table`` is.
 
     One incremental step for a batch of running slots: ``tokens`` (B,)
     the token being fed, ``positions`` (B,) its absolute position,
     ``lengths`` (B,) the post-append visible length (``positions + 1``
     for active slots, 0 for idle ones — an idle slot attends nothing
     and its logits row is garbage the scheduler never reads),
-    ``write_rows`` (B,) the flat pool row this token's K/V lands in,
-    ``window_rows`` (B, W) each slot's full block-window gather index.
+    ``write_rows`` (B,) the flat pool row this token's K/V lands in.
+
+    implementation: "window" | "paged" | "interpret" | None (auto:
+    paged when the pool is floating point, of a shape the kernel reads
+    (``paged_attention.supported``) and the backend is a TPU; an engine
+    on a mesh asks for "window").
+
+    - ``kv_path == "window"``: ``table`` is ``window_rows`` (B, W), each
+      slot's full block-window gather index.
+    - ``kv_path == "paged"``: ``table`` is the block table (B,
+      max_blocks), each slot's physical blocks in logical order, padded
+      with the trash block: per layer one ``paged_attn_decode`` kernel
+      over the slot's live blocks, and after the last layer one in-place
+      write of every layer's new row (``paged_kv_write``). "interpret"
+      is the same path with the kernels interpreted, for the CPU.
     """
     if not cfg.causal:
         raise ValueError("incremental decode requires a causal model; "
                          "serve bidirectional (BERT) configs through the "
                          "prefill/scoring path")
     quantized = cache_cfg.quantized if cache_cfg is not None else False
+    can_page = cache_cfg is not None and paged_attention.supported(
+        cache_cfg.num_blocks * cache_cfg.block_size, cache_cfg.block_size,
+        cache_cfg.head_dim, cache_cfg.dtype)
+    if implementation is None:
+        implementation = ("paged" if can_page
+                          and jax.default_backend() == "tpu" else "window")
+    if implementation not in ("window", "paged", "interpret"):
+        raise ValueError(f"implementation={implementation!r}; expected "
+                         f"'window', 'paged', 'interpret' or None")
+    paged = implementation != "window"
+    if paged and not can_page:
+        raise ValueError(f"the paged decode path cannot read this pool "
+                         f"({cache_cfg}): see ops.paged_attention.supported")
+    interpret = implementation == "interpret"
 
-    def decode(params, pool, tokens, positions, lengths, write_rows,
-               window_rows):
+    def decode(params, pool, tokens, positions, lengths, write_rows, table):
         dt = cfg.dtype
         embed = params["embed"]
         x = embed.astype(dt)[tokens]                    # (B, D)
         pos_q = positions[:, None]                      # (B, 1)
+        if paged:
+            bs = cache_cfg.block_size
+            with jax.named_scope("kv.gather"):
+                # the keys already in the pool: all but the new token's
+                plan = paged_attention.decode_plan(
+                    table, jnp.maximum(lengths - 1, 0), block_size=bs)
+            ks, vs = [], []
         for l in range(cfg.n_layers):
             p = _layer(params, l)
             h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
@@ -298,14 +340,26 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None):
             v = jnp.einsum("bd,dhk->bhk", h, att["value"].astype(dt))
             q = rotary_at(q[:, :, None], pos_q)          # (B, H, 1, hd)
             k = rotary_at(k[:, :, None], pos_q)[:, :, 0]  # (B, H, hd)
-            # write THEN gather: the query must see its own position
-            pool = _pool_write(pool, l, write_rows, k, v, quantized)
-            kw, vw = _pool_window(pool, l, window_rows, dt, quantized)
-            with jax.named_scope("attn"):
-                o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
-                                  q_positions=positions)  # (B, H, 1, hd)
-            o = jnp.einsum("bhk,hkd->bd", o[:, :, 0],
-                           att["out"].astype(dt))
+            if paged:
+                # the new token's K and V as the pool will hold them:
+                # merged into the softmax from registers, written once
+                # after the last layer
+                ks.append(k.astype(pool["k"].dtype))
+                vs.append(v.astype(pool["v"].dtype))
+                with jax.named_scope("kv.gather"):
+                    o = paged_attention.paged_attention_decode(
+                        q[:, :, 0], ks[-1], vs[-1], pool["k"], pool["v"],
+                        l, plan, lengths, block_size=bs,
+                        interpret=interpret)
+            else:
+                # write THEN gather: the query must see its own position
+                pool = _pool_write(pool, l, write_rows, k, v, quantized)
+                kw, vw = _pool_window(pool, l, table, dt, quantized)
+                with jax.named_scope("attn"):
+                    o = mha_reference(q, kw, vw, causal=True,
+                                      lengths=lengths,
+                                      q_positions=positions)[:, :, 0]
+            o = jnp.einsum("bhk,hkd->bd", o, att["out"].astype(dt))
             x = x + o
             h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
             mlp = p["mlp"]
@@ -317,8 +371,19 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None):
         x = _rms_norm(x, params["final_norm"]["scale"], dt)
         with jax.named_scope("lm_head"):
             logits = jnp.einsum("bd,vd->bv", x, embed.astype(dt))
-            return logits.astype(jnp.float32), pool
+            logits = logits.astype(jnp.float32)
+        if paged:
+            with jax.named_scope("kv.write"):
+                # every kernel has read the pool before a row changes
+                pool_k, pool_v, logits = jax.lax.optimization_barrier(
+                    (pool["k"], pool["v"], logits))
+                pool = dict(pool)
+                pool["k"], pool["v"] = paged_attention.write_rows(
+                    pool_k, pool_v, jnp.stack(ks), jnp.stack(vs),
+                    write_rows, lengths > 0, interpret=interpret)
+        return logits, pool
 
+    decode.kv_path = "paged" if paged else "window"
     return decode
 
 
@@ -341,7 +406,9 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
     speculative-decode verification (positions ``L-1..L+k-1``: the
     banked token plus k draft proposals, scored in one step). Per-query
     math is position-independent, so row 0 of a (B, E) extend is
-    bitwise the row a (B,) decode at the same position produces — the
+    bitwise the row a (B,) decode on the window path produces at the
+    same position (on the paged path the two agree to float32 rounding
+    before the output cast: the kernel's softmax is online) — the
     greedy-parity contract extends to both callers."""
     if not cfg.causal:
         raise ValueError("extend requires a causal model; serve "
@@ -442,7 +509,9 @@ def kv_quantization_probe(cfg: TransformerConfig, params, prompt,
         table.ensure_room(len(prompt) + n_steps + 1, alloc)
         pool = init_pool(cc)
         prefill = jax.jit(make_prefill_fn(cfg, cc))
-        decode = jax.jit(make_decode_fn(cfg, cc))
+        # both pools through the window path: the probe compares
+        # storage types, not the way the step reads them
+        decode = jax.jit(make_decode_fn(cfg, cc, implementation="window"))
         toks = np.asarray([prompt], np.int32)
         rows = table.rows(np.arange(len(prompt)))[None]
         last, pool = prefill(params, pool, jnp.asarray(toks),

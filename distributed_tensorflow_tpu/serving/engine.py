@@ -89,7 +89,7 @@ from distributed_tensorflow_tpu.models.transformer import (
 from distributed_tensorflow_tpu.resilience import faults
 from distributed_tensorflow_tpu.serving import decode as decode_lib
 from distributed_tensorflow_tpu.serving.kv_cache import (
-    CacheConfig, HostTier, init_pool, pool_shardings)
+    TRASH_BLOCK, CacheConfig, HostTier, init_pool, pool_shardings)
 from distributed_tensorflow_tpu.serving.scheduler import (
     AdmissionQueue, ContinuousBatchingScheduler, OutOfBlocksError,
     Request, Sequence)
@@ -247,8 +247,15 @@ class InferenceEngine:
         self.pool = init_pool(cache_cfg, mesh)
 
         prefill = decode_lib.make_prefill_fn(cfg, cache_cfg)
-        decode = (decode_lib.make_decode_fn(cfg, cache_cfg)
-                  if cfg.causal and role != "prefill" else None)
+        # GSPMD cannot partition the paged path's pallas_call: a mesh
+        # keeps the window path; otherwise make_decode_fn chooses
+        decode = (decode_lib.make_decode_fn(
+            cfg, cache_cfg,
+            implementation="window" if mesh is not None else None)
+            if cfg.causal and role != "prefill" else None)
+        #: how the decode program reaches the pool ("paged" / "window"):
+        #: decides which table _decode_batch hands it
+        self.kv_path = decode.kv_path if decode is not None else None
         extend = (decode_lib.make_extend_fn(cfg, cache_cfg)
                   if cfg.causal else None)
         copy_fn = decode_lib.make_copy_fn()
@@ -791,17 +798,23 @@ class InferenceEngine:
                    + len(seq.generated)),
             step=self._step_idx)
 
-    def _decode_batch(self, batch: list[Sequence]):
+    def _decode_batch(self, batch: list[Sequence]) -> int:
         """One incremental token for every running sequence. The decode
         program has a fixed (max_slots,) batch; idle slots feed trash
-        rows with length 0 and their logits are never read."""
+        rows with length 0 and their logits are never read. Returns the
+        blocks the step's KV read touches."""
         B, W = self.max_slots, self.window
+        paged = self.kv_path == "paged"
+        bs = self.cache_cfg.block_size
         with telemetry.span("serve.decode.build"):
             tokens = np.zeros(B, np.int32)
             positions = np.zeros(B, np.int32)
             lengths = np.zeros(B, np.int32)
             write_rows = np.zeros(B, np.int32)     # trash block row 0
-            window_rows = np.zeros((B, W), np.int32)
+            # the paged program walks each slot's block table; the window
+            # program gathers each slot's whole window of rows
+            table = (np.full((B, W // bs), TRASH_BLOCK, np.int32) if paged
+                     else np.zeros((B, W), np.int32))
             for seq in batch:
                 s = seq.slot
                 if self.prefix_caching:
@@ -817,13 +830,16 @@ class InferenceEngine:
                 positions[s] = seq.length - 1
                 lengths[s] = seq.length
                 write_rows[s] = seq.table.row_of(seq.length - 1)
-                window_rows[s] = seq.table.window_rows()
+                if paged:
+                    table[s, :len(seq.table.blocks)] = seq.table.blocks
+                else:
+                    table[s] = seq.table.window_rows()
         with telemetry.span("serve.decode.launch"):
             logits, self.pool = self._decode(
                 self.params, self.pool,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(lengths), jnp.asarray(write_rows),
-                jnp.asarray(window_rows))
+                jnp.asarray(table))
         with telemetry.span("serve.decode.wait"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
         with telemetry.span("serve.decode.commit", tokens=len(batch)):
@@ -832,6 +848,9 @@ class InferenceEngine:
                 self.scheduler.append_token(seq, int(nxt[seq.slot]))
                 if emit:
                     self._emit_token(seq)
+        if not paged:
+            return B * (W // bs)
+        return int(np.sum(-(-lengths // bs)))
 
     # -- speculative decoding ---------------------------------------------
     def _spec_span(self, seq: Sequence) -> int:
@@ -991,8 +1010,9 @@ class InferenceEngine:
                                                  - acc_before)
                     else:
                         batch = sched.grow_for_decode()
+                        dsp["kv_path"] = self.kv_path
                         if batch:
-                            self._decode_batch(batch)
+                            dsp["blocks_read"] = self._decode_batch(batch)
                     dsp["live"] = len(batch)
             sp["admitted"] = len(admitted)
             sp["decoded"] = len(batch)

@@ -1,0 +1,419 @@
+"""The paged decode path: ``ops/paged_attention.py`` and the decode
+program ``serving/decode.make_decode_fn`` builds from it.
+
+On the CPU the kernels run in interpret mode. The contract is the window
+path's: the same keys attended with the same float32 arithmetic
+(``_pool_window`` + ``mha_reference`` on the pool with the new row
+written), the pool changed in the written rows and nowhere else, and an
+engine that gives the same greedy tokens whichever path its decode
+program takes. What the TPU's compiler makes of the program (nothing of
+the pool's size) is checked on a described v5e, no chip needed.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from distributed_tensorflow_tpu.models.transformer import (
+    TransformerConfig, TransformerLM)
+from distributed_tensorflow_tpu.ops import paged_attention as pa
+from distributed_tensorflow_tpu.ops.attention import mha_reference
+from distributed_tensorflow_tpu.serving import (
+    InferenceEngine, decode as decode_lib, engine as engine_lib)
+from distributed_tensorflow_tpu.serving.kv_cache import (
+    TRASH_BLOCK, BlockAllocator, BlockTable, CacheConfig, init_pool)
+
+L, NB, BS, H, HD = 2, 32, 16, 4, 16          # pool: 512 rows, 4 groups
+MAX_BLOCKS = 8                                # window: 128 positions
+TOL = {jnp.float32: dict(rtol=1e-5, atol=2e-6),
+       jnp.bfloat16: dict(rtol=2 ** -7, atol=1e-6)}   # one bf16 ulp
+
+
+def _pool(rng, dtype):
+    return {n: jnp.asarray(rng.standard_normal((L, NB * BS, H, HD)), dtype)
+            for n in ("k", "v")}
+
+
+def _tables(rng, lengths, order="scattered", share=()):
+    """Block tables (B, MAX_BLOCKS) for ``lengths``: blocks handed out
+    ``contiguous``, ``scattered`` or ``descending``; ``share`` lists
+    (slot, from_slot, n_blocks) prefix sharing."""
+    free = list(range(1, NB))
+    if order == "scattered":
+        free = list(rng.permutation(free))
+    elif order == "descending":
+        free.reverse()
+    table = np.full((len(lengths), MAX_BLOCKS), TRASH_BLOCK, np.int32)
+    for b, n in enumerate(lengths):
+        for j in range(-(-int(n) // BS)):
+            table[b, j] = free.pop(0)
+    for b, src, n in share:
+        table[b, :n] = table[src, :n]
+    return table
+
+
+def _write_row(table, lengths):
+    return np.array([table[b, (n - 1) // BS] * BS + (n - 1) % BS if n else 0
+                     for b, n in enumerate(lengths)], np.int32)
+
+
+def _reference(pool, l, q, k_new, v_new, lengths, table):
+    """The window path: write, gather the whole window, mha_reference."""
+    rows = jnp.asarray(_write_row(table, lengths))
+    pool = decode_lib._pool_write(pool, l, rows, k_new, v_new, False)
+    window = (table[:, :, None] * BS + np.arange(BS)).reshape(len(lengths), -1)
+    kw, vw = decode_lib._pool_window(pool, l, jnp.asarray(window), q.dtype,
+                                     False)
+    lengths = jnp.asarray(lengths)
+    return mha_reference(q[:, :, None], kw, vw, causal=True, lengths=lengths,
+                         q_positions=jnp.maximum(lengths - 1, 0))[:, :, 0]
+
+
+def _paged(pool, l, q, k_new, v_new, lengths, table):
+    lengths = jnp.asarray(lengths)
+    plan = pa.decode_plan(jnp.asarray(table), jnp.maximum(lengths - 1, 0),
+                          block_size=BS)
+    return pa.paged_attention_decode(
+        q, k_new, v_new, pool["k"], pool["v"], l, plan, lengths,
+        block_size=BS, interpret=True)
+
+
+def _qkv(rng, n, dtype):
+    return (jnp.asarray(rng.standard_normal((n, H, HD)), dtype)
+            for _ in range(3))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("length", [0, 1, 15, 16, 17, 33, 127, 128])
+def test_kernel_matches_window_reference(length, dtype):
+    """One slot at every length that matters (empty, its own key only,
+    a block's edges, a group's edge, the full window) among slots that
+    are idle, share a prefix, and end mid-block."""
+    rng = np.random.default_rng(length)
+    lengths = [length, 0, 40, 45, 0, 128]
+    table = _tables(rng, lengths, share=[(3, 2, 2)])
+    pool = _pool(rng, dtype)
+    q, k_new, v_new = _qkv(rng, len(lengths), dtype)
+    for l in range(L):
+        out = _paged(pool, l, q, k_new, v_new, lengths, table)
+        ref = _reference(pool, l, q, k_new, v_new, lengths, table)
+        assert out.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), **TOL[dtype])
+    idle = [b for b, n in enumerate(lengths) if n == 0]
+    assert not np.any(np.asarray(out, np.float32)[idle])
+
+
+@pytest.mark.parametrize("order", ["contiguous", "scattered", "descending"])
+def test_kernel_whatever_the_blocks_order(order):
+    """Runs merge consecutive table entries of one 128-row group: the
+    result may not depend on how the allocator laid the blocks out."""
+    rng = np.random.default_rng(7)
+    lengths = [100, 3, 64, 17]
+    table = _tables(rng, lengths, order=order)
+    pool = _pool(rng, jnp.float32)
+    q, k_new, v_new = _qkv(rng, len(lengths), jnp.float32)
+    out = _paged(pool, 1, q, k_new, v_new, lengths, table)
+    ref = _reference(pool, 1, q, k_new, v_new, lengths, table)
+    np.testing.assert_allclose(out, ref, **TOL[jnp.float32])
+
+
+@pytest.mark.parametrize("order", ["contiguous", "scattered", "descending"])
+def test_plan_lists_each_slots_runs(order):
+    """The run list against a plain loop over the table: a run opens
+    where the 128-row group changes, holds one bit per block, and a slot
+    whose pool keys are none (idle, or only its own key) has no run."""
+    rng = np.random.default_rng(11)
+    lengths = np.array([100, 1, 128, 17, 0, 36], np.int32)
+    table = _tables(rng, lengths, order=order)
+    visible = np.maximum(lengths - 1, 0)
+    plan = pa.decode_plan(jnp.asarray(table), jnp.asarray(visible),
+                          block_size=BS)
+    per_group = pa.GROUP_ROWS // BS
+    slot, group, bits, count, tail = [], [], [], [], []
+    for b, n in enumerate(visible):
+        n_blocks = -(-int(n) // BS)
+        prev, count_b = None, 0
+        for j in range(n_blocks):
+            g, sub = divmod(int(table[b, j]), per_group)
+            if g != prev:
+                slot.append(b), group.append(g), bits.append(0)
+                count_b += 1
+            bits[-1] |= 1 << sub
+            prev = g
+        count.append(count_b)
+        if n_blocks:
+            tail.append(int(table[b, n_blocks - 1]) % per_group * 256
+                        + int(n) - BS * (n_blocks - 1))
+    n = int(plan["n_runs"][0])
+    assert n == len(slot)
+    assert list(np.asarray(plan["count"])) == count
+    assert list(np.asarray(plan["first"])) == list(
+        np.cumsum([0] + count[:-1]))
+    assert list(np.asarray(plan["slot"])[:n]) == slot
+    assert list(np.asarray(plan["group"])[:n]) == group
+    assert list(np.asarray(plan["bits"])[:n]) == bits
+    assert [int(t) for t, c in zip(np.asarray(plan["tail"]), count)
+            if c] == tail
+    if order == "contiguous":        # 19 blocks in 5 runs
+        assert n < sum(-(-int(v) // BS) for v in visible)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_write_touches_its_rows_and_no_other(dtype):
+    """Rows in one group, in neighbouring groups and at the pool's end;
+    idle slots name the trash row and write nothing. Every other row of
+    both pools stays bit for bit, in every layer."""
+    rng = np.random.default_rng(3)
+    pool = _pool(rng, dtype)
+    rows = np.array([0, 130, 131, 0, 511, 257, 129, 0], np.int32)
+    active = rows > 0
+    k_new, v_new = (jnp.asarray(rng.standard_normal((L, len(rows), H, HD)),
+                                dtype) for _ in range(2))
+    k, v = pa.write_rows(pool["k"], pool["v"], k_new, v_new,
+                         jnp.asarray(rows), jnp.asarray(active),
+                         interpret=True)
+    for got, old, new in ((k, pool["k"], k_new), (v, pool["v"], v_new)):
+        want = np.array(old, np.float32)
+        for b in np.flatnonzero(active):
+            want[:, rows[b]] = np.asarray(new[:, b], np.float32)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+        assert np.any(want != np.asarray(old, np.float32))
+
+
+def test_write_applies_one_row_in_slot_order():
+    """Two active slots naming one row (no engine does): the later wins,
+    as with the window path's writes one slot after another."""
+    rng = np.random.default_rng(4)
+    pool = _pool(rng, jnp.float32)
+    rows = np.array([200, 77, 200], np.int32)
+    new = jnp.asarray(rng.standard_normal((L, 3, H, HD)), jnp.float32)
+    k, _ = pa.write_rows(pool["k"], pool["v"], new, new, jnp.asarray(rows),
+                         jnp.ones(3, bool), interpret=True)
+    np.testing.assert_array_equal(k[:, 200], new[:, 2])
+    np.testing.assert_array_equal(k[:, 77], new[:, 1])
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = TransformerConfig.tiny(max_seq_len=64)
+    params = TransformerLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    return cfg, params
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
+def test_decode_program_matches_window_path(tiny, kv_dtype):
+    """``make_decode_fn`` both ways from one prefilled pool: the same
+    logits and the same new rows to float32 rounding (a later layer's K
+    and V follow the earlier layers' attention), every row no slot wrote
+    bit for bit as prefill left it."""
+    cfg, params = tiny
+    params = decode_lib.canonical_params(cfg, params)
+    cc = CacheConfig.for_model(cfg, num_blocks=32, block_size=8,
+                               kv_dtype=kv_dtype)
+    prompts = [[5, 9, 2, 7, 1, 3, 8, 4, 6, 2, 9], [3, 1, 4], []]
+    alloc = BlockAllocator(cc.num_blocks)
+    prefill = jax.jit(decode_lib.make_prefill_fn(cfg, cc))
+    pool = init_pool(cc)
+    tables = []
+    for p in prompts:
+        t = BlockTable(cc, max_blocks=8)
+        tables.append(t)
+        if not p:
+            continue
+        t.ensure_room(len(p) + 4, alloc)
+        toks = np.zeros((1, 16), np.int32)
+        toks[0, :len(p)] = p
+        _, pool = prefill(params, pool, jnp.asarray(toks),
+                          jnp.asarray([len(p)], np.int32),
+                          jnp.asarray(t.rows(np.arange(16))[None]))
+        t.length = len(p)
+    outs = {}
+    for impl in ("window", "interpret"):
+        fn = decode_lib.make_decode_fn(cfg, cc, implementation=impl)
+        assert fn.kv_path == ("window" if impl == "window" else "paged")
+        own = {n: jnp.array(a) for n, a in pool.items()}
+        lengths = np.array([len(p) + 1 if p else 0 for p in prompts],
+                           np.int32)
+        for step in range(3):
+            live = lengths > 0
+            table = np.stack([
+                t.window_rows() if impl == "window" else np.pad(
+                    np.asarray(t.blocks, np.int32),
+                    (0, 8 - len(t.blocks)), constant_values=TRASH_BLOCK)
+                for t in tables])
+            rows = np.array([t.row_of(n - 1) if n else 0
+                             for t, n in zip(tables, lengths)], np.int32)
+            logits, own = jax.jit(fn)(
+                params, own, jnp.asarray([7 + step, 11, 0], np.int32),
+                jnp.asarray(np.maximum(lengths - 1, 0)),
+                jnp.asarray(lengths), jnp.asarray(rows), jnp.asarray(table))
+            outs.setdefault(impl, []).append(np.asarray(logits)[live])
+            lengths = lengths + live
+        outs[impl].append({n: np.asarray(a, np.float32)
+                           for n, a in own.items()})
+    for a, b in zip(outs["window"][:-1], outs["interpret"][:-1]):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-5)
+    start = {n: np.asarray(a, np.float32) for n, a in pool.items()}
+    wrote = sorted({t.row_of(len(p) + i) for t, p in zip(tables, prompts)
+                    if p for i in range(3)})
+    kept = np.setdiff1d(np.arange(start["k"].shape[1]), wrote)
+    for n in ("k", "v"):
+        w, i = outs["window"][-1][n], outs["interpret"][-1][n]
+        np.testing.assert_allclose(w[:, wrote], i[:, wrote],
+                                   rtol=2e-5, atol=2e-5)
+        assert np.any(w[:, wrote] != start[n][:, wrote])
+        np.testing.assert_array_equal(i[:, kept], start[n][:, kept])
+        np.testing.assert_array_equal(w[:, kept[8:]], start[n][:, kept[8:]])
+
+
+def _engine(cfg, params, impl, monkeypatch, **kw):
+    """An engine whose decode program is built with ``implementation``:
+    the engine itself passes none, so the test steers the builder."""
+    real = decode_lib.make_decode_fn
+    monkeypatch.setattr(
+        engine_lib.decode_lib, "make_decode_fn",
+        lambda c, cc, implementation=None: real(c, cc, implementation=impl))
+    engine = InferenceEngine(cfg, params, **kw)
+    monkeypatch.undo()
+    return engine
+
+
+SCENARIOS = {
+    "mixed_batch": dict(
+        engine=dict(num_blocks=32, block_size=8, max_slots=4),
+        prompts=[[5, 6, 7], [9] * 11, [1, 2], [3] * 17, [4, 4, 4, 4]]),
+    "prefix_hit": dict(
+        engine=dict(num_blocks=32, block_size=8, max_slots=4,
+                    prefix_caching=True),
+        prompts=[list(range(1, 21)) + s
+                 for s in ([30], [31, 32], [33] * 9, [34, 35, 36])]),
+    "preemption": dict(
+        engine=dict(num_blocks=16, block_size=8, max_slots=4,
+                    max_prompt_len=16),
+        prompts=[[7] * 9, [8] * 12, [9] * 5, [6] * 10, [5] * 14, [4] * 3]),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+def test_engine_tokens_equal_on_both_paths(tiny, scenario, monkeypatch):
+    cfg, params = tiny
+    spec = SCENARIOS[scenario]
+    outs, engines = {}, {}
+    for impl in ("window", "interpret"):
+        e = engines[impl] = _engine(cfg, params, impl, monkeypatch,
+                                    **spec["engine"])
+        outs[impl] = e.generate(spec["prompts"], max_new_tokens=32)
+    assert engines["window"].kv_path == "window"
+    assert engines["interpret"].kv_path == "paged"
+    assert outs["interpret"] == outs["window"]
+    for e in engines.values():
+        acct = e.block_accounting()
+        assert acct["conserved"] and acct["leaked_refs"] == 0
+    if scenario == "preemption":
+        assert engines["interpret"].scheduler.preemptions > 0
+    if scenario == "prefix_hit":
+        assert engines["interpret"].stats()["prefix_cache"]["hit_tokens"] > 0
+
+
+def test_engine_on_the_cpu_takes_the_window_path(tiny):
+    cfg, params = tiny
+    e = InferenceEngine(cfg, params, num_blocks=32, block_size=8,
+                        max_slots=2)
+    assert e.kv_path == "window"
+
+
+@pytest.mark.parametrize("why,cache,impl", [
+    ("unknown name", dict(num_blocks=32, block_size=8), "pallas"),
+    ("int8 pool", dict(num_blocks=32, block_size=8, kv_dtype="int8"),
+     "paged"),
+    ("rows not a multiple of 128", dict(num_blocks=9, block_size=8),
+     "interpret"),
+    ("block size not a power of two", dict(num_blocks=32, block_size=12),
+     "paged"),
+])
+def test_implementation_argument_is_checked(tiny, why, cache, impl):
+    cfg, _ = tiny
+    cc = CacheConfig.for_model(cfg, **cache)
+    with pytest.raises(ValueError):
+        decode_lib.make_decode_fn(cfg, cc, implementation=impl)
+    # and left alone, such a pool quietly takes the window path
+    assert decode_lib.make_decode_fn(cfg, cc).kv_path == "window"
+
+
+# -- what the TPU's compiler makes of it: a described v5e, no chip ----------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described device is written to the persistent
+    cache and cannot be read back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("impl,clean", [("paged", True), ("window", False)])
+def test_compiled_decode_holds_nothing_of_the_pools_size(
+        one_chip, no_compile_cache, impl, clean):
+    """transformer-big at the benchmark's serving shapes, compiled for
+    one v5e chip: the paged program produces no array of the pool's, a
+    layer's or the 64 x 1024-row window's size (``chip_smoke``'s check,
+    which the window program fails 200-fold), and the Mosaic kernels
+    compile at these widths."""
+    import dataclasses
+
+    import chip_smoke
+
+    cfg = TransformerConfig.transformer_big(max_seq_len=1024,
+                                            scan_layers=False)
+    cc = CacheConfig.for_model(cfg, num_blocks=4096, block_size=16,
+                               dtype=jnp.bfloat16)
+    slots, window = 64, 1024
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    model = TransformerLM(dataclasses.replace(cfg, scan_layers=True))
+    shapes = jax.eval_shape(
+        lambda r: model.init(r, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype), decode_lib._plain(shapes["params"]))
+    rows = cc.num_blocks * cc.block_size
+    row = cc.n_heads * cc.head_dim
+    pool = {n: spec((cc.n_layers, rows, cc.n_heads, cc.head_dim), cc.dtype)
+            for n in ("k", "v")}
+    vec = spec((slots,), jnp.int32)
+    table = spec((slots, window // cc.block_size if impl == "paged"
+                  else window), jnp.int32)
+    fn = decode_lib.make_decode_fn(cfg, cc, implementation=impl)
+    hlo = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, vec, vec, vec, vec, table).compile().as_text()
+    found = chip_smoke.pool_sized_ops(
+        hlo, {cc.n_layers * rows * row, rows * row, slots * window * row})
+    assert (not found) == clean, found[:5]
+    assert ("paged_attn_decode" in hlo) == clean

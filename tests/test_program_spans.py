@@ -183,7 +183,11 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
             assert tail == ["serve.decode", "serve.decode.build",
                             "serve.decode.launch", "serve.decode.wait",
                             "serve.decode.commit"]
-            assert inside[-5][3] == {"live": step[3]["decoded"]}
+            # the CPU takes the window path: every slot's whole window
+            assert inside[-5][3] == {
+                "live": step[3]["decoded"], "kv_path": "window",
+                "blocks_read": e.max_slots * e.window
+                // e.cache_cfg.block_size}
             assert inside[-1][3] == {"tokens": step[3]["decoded"]}
     first = [s for s in spans
              if steps[0][1] <= s[1] and s[2] <= steps[0][2]]
