@@ -84,6 +84,23 @@ def serve_config() -> TransformerConfig:
                                              scan_layers=False)
 
 
+def looped_config(n_layers: int = 3) -> TransformerConfig:
+    """The looped model of ``benchmark/configs/ouro_serve.json`` at its
+    published widths and passes and a small depth: ``n_layers`` layers
+    run four times, a KV cache per pass, ``head_dim`` 128, bfloat16
+    weights. (Three layers and not two: ``program_check`` goes by element
+    counts, and at two a projection's stacked weights, 2 x 2048 x 2048,
+    count what a cache layer of 4096 rows counts; for the same reason the
+    smoke run's pool has 192 blocks: 12 cache layers of 4096 rows count
+    what the 49152-row head counts.)"""
+    return TransformerConfig(
+        vocab_size=49152, d_model=2048, n_layers=n_layers, n_heads=16,
+        d_ff=5632, max_seq_len=512, dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16, passes=4, post_norms=True,
+        tie_embeddings=False, exit_gate=True, rope_base=1e6,
+        scan_layers=True, remat=False)
+
+
 @dataclasses.dataclass(frozen=True)
 class ServeShapes:
     """Engine and workload shapes (defaults: bench.py ``--serving``'s
@@ -258,9 +275,13 @@ def _serve(engine: InferenceEngine, requests: list, tag: str) -> dict:
 #: output aliases its operand, an update in place
 _IN_PLACE = frozenset({
     "parameter", "bitcast", "get-tuple-element", "tuple", "while",
-    "opt-barrier", "custom-call", "dynamic-update-slice"})
+    "opt-barrier", "custom-call", "dynamic-update-slice", "scatter"})
 _HLO_LINE = re.compile(r"^\s*(?:ROOT )?%\S+ = (\(.*?\)|\S+) ([\w-]+)\(")
 _HLO_SHAPE = re.compile(r"\w+\[([\d,]+)\]")
+#: a fusion that IS a scatter (XLA wraps one in a kCustom fusion): it
+#: updates its operand where it lies, as the bare instruction does. A
+#: relayout it needs shows as a ``copy`` beside it, and that counts.
+_SCATTER_FUSION = re.compile(r'op_name="[^"]*/scatter"')
 
 
 def pool_sized_ops(hlo_text: str, sizes) -> list[str]:
@@ -273,7 +294,8 @@ def pool_sized_ops(hlo_text: str, sizes) -> list[str]:
     found = []
     for line in hlo_text.splitlines():
         m = _HLO_LINE.match(line)
-        if m is None or m.group(2) in _IN_PLACE:
+        if m is None or m.group(2) in _IN_PLACE or (
+                m.group(2) == "fusion" and _SCATTER_FUSION.search(line)):
             continue
         for dims in _HLO_SHAPE.findall(m.group(1)):
             if math.prod(int(d) for d in dims.split(",")) in sizes:
@@ -282,11 +304,18 @@ def pool_sized_ops(hlo_text: str, sizes) -> list[str]:
     return found
 
 
-def decode_program_check(engine: InferenceEngine) -> None:
+def program_check(engine: InferenceEngine) -> None:
     """Which way the engine's decode program reaches the KV pool; on the
     paged path (what a TPU takes) the compiled program must hold nothing
     of the pool's size: a whole-pool relayout or a whole-window gather
-    back in ``jit_decode`` stops a smoke run, not a benchmark."""
+    back in ``jit_decode`` stops a smoke run, not a benchmark. Neither
+    by the text of the program (no instruction produces an array of the
+    pool's, one cache layer's or every slot's window's element count,
+    but in place) nor by its memory (its temporaries are smaller than
+    one of the pool's two arrays, and its output pool is its input).
+    The same is asked of the prefill program where the device keeps
+    the pool row-major (XLA's scatter writes that layout in place; the
+    other costs prefill two pool copies, PERF.md section 7)."""
     print(f"  decode kv_path: {engine.kv_path}", flush=True)
     if engine.kv_path != "paged":
         return
@@ -297,15 +326,33 @@ def decode_program_check(engine: InferenceEngine) -> None:
              slots * engine.window * row}
     i32 = jnp.zeros((slots,), jnp.int32)
     table = jnp.zeros((slots, engine.window // cc.block_size), jnp.int32)
-    hlo = engine._decode.lower(engine.params, engine.pool, i32, i32, i32,
-                               i32, table).compile().as_text()
-    found = pool_sized_ops(hlo, sizes)
-    for line in found[:8]:
-        print(f"    {line}", flush=True)
-    check(not found,
-          f"the compiled decode program produces no array of the pool's, "
-          f"one layer's or every slot's window's size ({sorted(sizes)} "
-          f"elements); found {len(found)}")
+    wide = jnp.zeros((1, engine.max_seq_len), jnp.int32)
+    programs = {"decode": (engine._decode, (i32, i32, i32, i32, table),
+                           sizes)}
+    if engine._kv_layout == "rows":
+        # prefill gathers no window (and its K and V stacks of a whole
+        # prompt may well count what a few slots' windows count)
+        programs["prefill"] = (
+            engine._prefill, (wide, jnp.ones((1,), jnp.int32), wide),
+            {cc.n_layers * rows * row, rows * row})
+    pool_bytes = engine.pool["k"].nbytes
+    for name, (program, args, sizes) in programs.items():
+        compiled = program.lower(engine.params, engine.pool,
+                                 *args).compile()
+        found = pool_sized_ops(compiled.as_text(), sizes)
+        for line in found[:8]:
+            print(f"    {line}", flush=True)
+        check(not found,
+              f"the compiled {name} program produces no array of the "
+              f"pool's, one layer's or every slot's window's size "
+              f"({sorted(sizes)} elements); found {len(found)}")
+        memory = compiled.memory_analysis()
+        check(memory.temp_size_in_bytes < pool_bytes
+              and memory.alias_size_in_bytes >= 2 * pool_bytes,
+              f"the {name} program's temporaries "
+              f"({memory.temp_size_in_bytes} B) are smaller than one "
+              f"pool array ({pool_bytes} B) and its pool is updated in "
+              f"place ({memory.alias_size_in_bytes} B aliased)")
 
 
 def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
@@ -317,7 +364,8 @@ def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
     then everything twice more through the warm engine. The third pass
     takes exactly the second's path — same programs, same cached
     blocks — so its tokens must be bit-identical."""
-    print(f"[serve] d_model {cfg.d_model}, {cfg.n_layers} layers, "
+    print(f"[serve] d_model {cfg.d_model}, {cfg.n_layers} layers x "
+          f"{cfg.passes} passes, "
           f"max_seq_len {cfg.max_seq_len}; {shapes.num_blocks} blocks x "
           f"{shapes.block_size}, {shapes.max_slots} slots, prompts <= "
           f"{shapes.max_prompt_len}", flush=True)
@@ -335,7 +383,7 @@ def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
     if device.platform == "tpu":
         check(engine.kv_path == "paged",
               "on a TPU the engine's decode program takes the paged path")
-    decode_program_check(engine)
+    program_check(engine)
 
     cold = seeded_requests(seed, shapes.n_requests, cfg.vocab_size,
                            prompt_range=shapes.prompt_range,
@@ -439,6 +487,11 @@ def main() -> int:
     gc.collect()
 
     serve_phase(serve_config(), ServeShapes(), devices[0], clock=clock)
+    gc.collect()
+    # the looped shape: head_dim 128 (a row-major pool, the other
+    # kernel), 3 layers x 4 passes = 12 cache layers
+    serve_phase(looped_config(), ServeShapes(num_blocks=192, max_slots=8),
+                devices[0], clock=clock)
     gc.collect()
 
     if len(devices) >= 4:

@@ -152,6 +152,33 @@ class TransformerConfig:
     # "reference" | None (auto: pallas on TPU).
     fused_optimizer: bool = False
     optimizer_impl: str | None = None
+    # The shape of a looped ("universal") stack: the ``n_layers`` blocks
+    # run ``passes`` times with the same weights, the final norm after
+    # every pass (its output feeds the next pass), and every pass of
+    # every layer attends to the keys and values that pass produced
+    # (serving keeps ``n_layers * passes`` cache layers). ``post_norms``
+    # adds an RMSNorm on each sub-layer's output before the residual
+    # (sandwich normalisation); ``tie_embeddings=False`` gives the output
+    # head a matrix of its own (``lm_head``); ``exit_gate`` gives the
+    # model the parameters of a per-pass exit gate sigma(w.h + b). At an
+    # exit threshold of 1 the cumulative exit probability reaches the
+    # threshold only at the last pass, so every token runs every pass
+    # and the last pass's logits are the output: that is what the model
+    # and the serving programs compute, and the gate is not evaluated
+    # (``benchmark/reference_looped.py`` returns its distribution).
+    passes: int = 1
+    post_norms: bool = False
+    tie_embeddings: bool = True
+    exit_gate: bool = False
+    rope_base: float = 10000.0
+    # the type the parameters are created and kept in (the compute type
+    # stays ``dtype``); bfloat16 is a serving configuration's choice
+    param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        if self.passes < 1:
+            raise ValueError(f"passes={self.passes}; a stack runs at "
+                             f"least once")
 
     @property
     def head_dim(self) -> int:
@@ -186,11 +213,14 @@ class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
     eps: float = 1e-6
     mesh: Any = None
+    param_dtype: Any = jnp.float32
+    scale_init: float = 1.0
 
     @nn.compact
     def __call__(self, x):
-        scale = param_with_axes("scale", nn.initializers.ones, (x.shape[-1],),
-                                jnp.float32, axes=("norm",))
+        scale = param_with_axes(
+            "scale", nn.initializers.constant(self.scale_init),
+            (x.shape[-1],), self.param_dtype, axes=("norm",))
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = x32 * jax.lax.rsqrt(var + self.eps) * scale
@@ -246,12 +276,12 @@ class MultiHeadAttention(nn.Module):
             # ~5 ms/step in pure copies (profile: 96 copy ops/step).
             kernel = param_with_axes(
                 name, nn.initializers.normal(D ** -0.5), (D, H, hd),
-                jnp.float32, axes=("embed", "heads", "kv"))
+                cfg.param_dtype, axes=("embed", "heads", "kv"))
             return jnp.einsum("bsd,dhk->bhsk", x,
                               kernel.astype(cfg.dtype))
 
-        q = rotary_embedding(proj("query"), seq_axis=-2)
-        k = rotary_embedding(proj("key"), seq_axis=-2)
+        q = rotary_embedding(proj("query"), base=cfg.rope_base, seq_axis=-2)
+        k = rotary_embedding(proj("key"), base=cfg.rope_base, seq_axis=-2)
         v = proj("value")
         mesh = cfg.mesh
         if lengths is not None:
@@ -302,7 +332,7 @@ class MultiHeadAttention(nn.Module):
 
         out_kernel = param_with_axes(
             "out", nn.initializers.normal(D ** -0.5), (H, hd, D),
-            jnp.float32, axes=("heads", "kv", "embed"))
+            cfg.param_dtype, axes=("heads", "kv", "embed"))
         # Contract straight from kernel layout — no transpose back.
         o = jnp.einsum("bhsk,hkd->bsd", o, out_kernel.astype(cfg.dtype))
         return with_sharding_constraint(o, ("batch", "seq", "embed"),
@@ -318,9 +348,10 @@ class MLP(nn.Module):
         cfg = self.cfg
         D, F = cfg.d_model, cfg.d_ff
         wi = param_with_axes("wi", nn.initializers.normal(D ** -0.5),
-                             (D, 2 * F), jnp.float32, axes=("embed", "mlp"))
+                             (D, 2 * F), cfg.param_dtype,
+                             axes=("embed", "mlp"))
         wo = param_with_axes("wo", nn.initializers.normal(F ** -0.5),
-                             (F, D), jnp.float32, axes=("mlp", "embed"))
+                             (F, D), cfg.param_dtype, axes=("mlp", "embed"))
         h = jnp.einsum("bsd,df->bsf", x, wi.astype(cfg.dtype))
         gate, up = jnp.split(h, 2, axis=-1)
         h = nn.silu(gate) * up
@@ -364,9 +395,30 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, lengths=None):
         cfg = self.cfg
-        x = x + MultiHeadAttention(cfg, name="attn")(
-            RMSNorm(cfg.dtype, mesh=cfg.mesh)(x), lengths)
-        h = RMSNorm(cfg.dtype, mesh=cfg.mesh)(x)
+
+        def norm(name=None, scale_init=1.0):
+            return RMSNorm(cfg.dtype, mesh=cfg.mesh,
+                           param_dtype=cfg.param_dtype,
+                           scale_init=scale_init, name=name)
+
+        def post(name, y):
+            # sandwich normalisation: the sub-layer's output is normed
+            # before it joins the residual stream. Its scale starts at
+            # 1/sqrt(branches the stream takes in): the residual scaling
+            # deep stacks are initialised with, carried here by the norm
+            # (a normed branch has an RMS of its scale whatever the
+            # projection before it). At 1 a stream of passes x layers x 2
+            # unit branches is a chaotic map of its weights: two
+            # roundings of one model part by whole logits on some seeds
+            # (PERF.md section 6, PR 28).
+            if not cfg.post_norms:
+                return y
+            with jax.named_scope("norm.post"):
+                return norm(name, (2 * cfg.n_layers * cfg.passes) ** -0.5)(y)
+
+        x = x + post("post_attn_norm", MultiHeadAttention(
+            cfg, name="attn")(norm()(x), lengths))
+        h = norm()(x)
         if cfg.moe_experts > 0:
             from distributed_tensorflow_tpu.parallel.moe import (
                 MoEConfig, MoELayer)
@@ -378,9 +430,9 @@ class Block(nn.Module):
             out, aux = MoELayer(moe_cfg, name="moe")(h)
             self.sow("losses", "moe_aux", aux,
                      reduce_fn=lambda a, b: a + b, init_fn=lambda: 0.0)
-            x = x + out
+            x = x + post("post_mlp_norm", out)
         else:
-            x = x + MLP(cfg, name="mlp")(h)
+            x = x + post("post_mlp_norm", MLP(cfg, name="mlp")(h))
         return x, None
 
 
@@ -398,7 +450,7 @@ class TransformerLM(nn.Module):
         cfg = self.cfg
         embed = param_with_axes(
             "embed", nn.initializers.normal(0.02),
-            (cfg.vocab_size, cfg.d_model), jnp.float32,
+            (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
             axes=("vocab", "embed"))
         # Unshard the table's d_model axis (fsdp) BEFORE the lookup: a
         # gather from the fsdp-sharded table inherits D-over-fsdp output
@@ -424,24 +476,47 @@ class TransformerLM(nn.Module):
             variable_axes = {"params": 0}
             if cfg.moe_experts > 0:
                 variable_axes["losses"] = 0     # per-layer aux stack
-            x, _ = nn_partitioning.scan_with_axes(
+            stack = nn_partitioning.scan_with_axes(
                 block,
                 variable_axes=variable_axes,
                 split_rngs={"params": True},
                 in_axes=nn.broadcast,
                 length=cfg.n_layers,
                 axis_name="layers",
-            )(cfg, name="layers")(x, lengths)
+            )(cfg, name="layers")
+            layers = [lambda x: stack(x, lengths)[0]]
         else:
-            for i in range(cfg.n_layers):
-                x, _ = block(cfg, name=f"layer_{i}")(x, lengths)
+            layers = [
+                (lambda x, b=block(cfg, name=f"layer_{i}"): b(x, lengths)[0])
+                for i in range(cfg.n_layers)]
+        final_norm = RMSNorm(cfg.dtype, mesh=cfg.mesh,
+                             param_dtype=cfg.param_dtype, name="final_norm")
+        # a looped stack: the same modules (so the same weights) at every
+        # pass, the final norm after each, its output the next pass's input
+        for _ in range(cfg.passes):
+            for layer in layers:
+                x = layer(x)
+            x = final_norm(x)
 
-        x = RMSNorm(cfg.dtype, mesh=cfg.mesh, name="final_norm")(x)
+        head = embed
+        if not cfg.tie_embeddings:
+            head = param_with_axes(
+                "lm_head", nn.initializers.normal(0.02),
+                (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
+                axes=("vocab", "embed"))
+        if cfg.exit_gate:
+            # sigma(w.h + b) after each pass; not evaluated at the exit
+            # threshold of 1 this model computes (see TransformerConfig)
+            param_with_axes("exit_gate_kernel", nn.initializers.normal(0.02),
+                            (cfg.d_model,), cfg.param_dtype,
+                            axes=("embed",))
+            param_with_axes("exit_gate_bias", nn.initializers.zeros, (),
+                            cfg.param_dtype, axes=())
         if return_hidden:
             # Fused-loss path: the caller computes chunked logits + CE
             # against the tied embedding itself (fused_next_token_loss).
             return x
-        logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(cfg.dtype))
+        logits = jnp.einsum("bsd,vd->bsv", x, head.astype(cfg.dtype))
         return logits.astype(jnp.float32)
 
 

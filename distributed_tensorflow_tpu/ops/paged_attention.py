@@ -33,6 +33,20 @@ reduced over the lanes once per slot. Arithmetic is ``mha_reference``'s:
 K and V as stored, converted to float32 in VMEM; float32 logits, softmax
 and accumulation; the same ``sm_scale`` and visibility rule.
 
+**At ``head_dim`` 128 the layout is the other one**, and so is the
+kernel. A minor dimension of 128 fills the lanes, so the device keeps the
+pool as written, ``{3,2,1,0}``: a row is one ``(H, hd)`` tile, a block of
+``block_size`` rows is contiguous, and the transpose above would be a copy
+of the whole pool. :func:`supported` says which layout a pool lies in
+(``"lanes"`` or ``"rows"``); the ``"rows"`` kernel (``paged_attn_decode_rows``)
+takes the pool as it is and reads whole blocks and nothing else: a grid
+step takes ``BLOCKS_PER_STEP`` consecutive entries of a slot's table (each
+its own DMA; a slot's last step is padded with the trash block), the logits
+of all heads are one matrix product of a block's ``(rows * H, hd)`` keys
+with the queries, of which the entries whose key head is the query's head
+are kept, and the value product is a second one. XLA's scatter writes that
+layout in place, so :func:`write_rows` needs no kernel there.
+
 The token being decoded never comes out of the pool: its K and V are in
 registers when the step runs, so :func:`paged_attention_decode` merges
 that one key into the softmax after the kernel (the query sees its own
@@ -55,23 +69,45 @@ from distributed_tensorflow_tpu.ops.attention import DEFAULT_MASK_VALUE
 
 #: Rows of the pool one DMA moves: the lane width of a TPU tile.
 GROUP_ROWS = 128
+#: Blocks of a row-major pool one grid step of its kernel reads. A step
+#: costs about 0.35 us whatever it moves and a 16-row block of K and V is
+#: 128 KB (0.16 us at the v5e's 819 GB/s): one block a step read 17,700
+#: blocks in 9.1 ms (0.51 us each, 30% of the roofline; PERF.md, PR 28).
+BLOCKS_PER_STEP = 4
 
 
-def supported(rows: int, block_size: int, head_dim: int, dtype) -> bool:
-    """Whether a pool of this shape can be read by groups: the rows
-    tile by 128, blocks tile a group (a power of two, so lane → block is
-    a shift, and at most 31 of them, one bit each), ``head_dim`` fills
-    the dtype's sublane packing, and the pool is floating point."""
+def supported(rows: int, block_size: int, head_dim: int, dtype,
+              n_heads: int | None = None) -> str | None:
+    """The layout a floating-point pool of this shape lies in on the
+    device, if a kernel here reads it, else ``None``.
+
+    ``"lanes"``: ``head_dim`` under 128, which the device keeps with the
+    rows on the lanes; read by 128-row groups, so the rows tile by 128,
+    blocks tile a group (a power of two, so lane → block is a shift, and
+    at most 31 of them, one bit each) and ``head_dim`` fills the dtype's
+    sublane packing. ``"rows"``: ``head_dim`` a multiple of 128, kept
+    row-major; read by blocks, whose ``(block_size * n_heads, head_dim)``
+    keys are one matrix operand, so the heads fill the sublane packing
+    (asked only where ``n_heads`` is given) and blocks are a power of two
+    of at most 128 rows. Any other ``head_dim`` pads on the device in a
+    way no kernel here reads."""
     dtype = jnp.dtype(dtype)
     if not jnp.issubdtype(dtype, jnp.floating):
-        return False
+        return None
     packing = 8 * (4 // dtype.itemsize)
-    return (rows % GROUP_ROWS == 0 and block_size & (block_size - 1) == 0
-            and 8 <= block_size <= GROUP_ROWS and head_dim % packing == 0)
+    if block_size & (block_size - 1) or not 8 <= block_size <= GROUP_ROWS:
+        return None
+    if head_dim < GROUP_ROWS:
+        return ("lanes" if rows % GROUP_ROWS == 0
+                and head_dim % packing == 0 else None)
+    if head_dim % GROUP_ROWS or (n_heads or packing) % packing:
+        return None
+    return "rows"
 
 
 def decode_plan(block_table, lengths, *, block_size: int) -> dict:
-    """The run list of one decode step, shared by every layer.
+    """The run list of one decode step over a ``"lanes"`` pool, shared by
+    every layer.
 
     ``block_table`` (B, max_blocks) int32 physical blocks in logical
     order (anything past a slot's live blocks is ignored), ``lengths``
@@ -112,6 +148,63 @@ def decode_plan(block_table, lengths, *, block_size: int) -> dict:
         "tail": tail_sub * 256 + tail_rows,
         "n_runs": jnp.sum(count, dtype=jnp.int32)[None],
     }
+
+
+def block_plan(block_table, lengths, *, block_size: int) -> dict:
+    """The run list of one decode step over a ``"rows"`` pool, shared by
+    every cache layer: a *run* is ``BLOCKS_PER_STEP`` consecutive entries
+    of a slot's table. Arguments as :func:`decode_plan`. Returns int32
+    arrays: per run (flat, slot-major) its ``slot``, the ``rows`` of it
+    that are live (counted from its first row) and its ``blocks``
+    (``BLOCKS_PER_STEP`` a run, flat; past a slot's live blocks the trash
+    block 0, whose rows are never live); per slot the ``first`` run and
+    the ``count`` of runs; and ``n_runs`` ``(1,)``, the grid's size."""
+    B, M = block_table.shape
+    P = BLOCKS_PER_STEP
+    C = -(-M // P)                                   # runs a slot at most
+    lengths = lengths.astype(jnp.int32)
+    n_blocks = (lengths + block_size - 1) // block_size           # (B,)
+    count = (n_blocks + P - 1) // P
+    first = jnp.cumsum(count, dtype=jnp.int32) - count
+    c = jnp.arange(C, dtype=jnp.int32)[None]                      # (1, C)
+    N = B * C
+    run = jnp.where(c < count[:, None], first[:, None] + c, N).reshape(-1)
+    j = jnp.arange(C * P, dtype=jnp.int32)[None]
+    table = jnp.pad(block_table, ((0, 0), (0, C * P - M)))
+    table = jnp.where(j < n_blocks[:, None], table, 0).reshape(N, P)
+    rows = jnp.clip(lengths[:, None] - c * (P * block_size), 0,
+                    P * block_size)
+    slot = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, C))
+    zeros = jnp.zeros((N,), jnp.int32)
+    return {
+        "slot": zeros.at[run].set(slot.reshape(-1), mode="drop"),
+        "rows": zeros.at[run].set(rows.reshape(-1), mode="drop"),
+        "blocks": jnp.zeros((N, P), jnp.int32).at[run].set(
+            table, mode="drop").reshape(-1),
+        "first": first, "count": count,
+        "n_runs": jnp.sum(count, dtype=jnp.int32)[None],
+    }
+
+
+def plan_for(layout: str, block_table, lengths, *, block_size: int) -> dict:
+    """The run list the ``layout``'s kernel takes."""
+    plan = decode_plan if layout == "lanes" else block_plan
+    return plan(block_table, lengths, block_size=block_size)
+
+
+def count_runs(layout: str, block_table, n_blocks, block_size: int) -> int:
+    """The runs (the kernel's grid steps per cache layer) of a step whose
+    slots hold ``n_blocks`` (B,) live blocks each: what the plan's
+    ``n_runs`` reads, computed on the host with numpy for the engine's
+    ``runs_read`` counter."""
+    import numpy as np
+    if layout == "rows":
+        return int(np.sum(-(-n_blocks // BLOCKS_PER_STEP)))
+    group = block_table // (GROUP_ROWS // block_size)
+    opens = np.ones_like(group, bool)
+    opens[:, 1:] = group[:, 1:] != group[:, :-1]
+    live = np.arange(block_table.shape[1])[None] < n_blocks[:, None]
+    return int(np.sum(opens & live))
 
 
 def _decode_kernel(layer_ref, slot_ref, group_ref, bits_ref, first_ref,
@@ -174,10 +267,84 @@ def _decode_kernel(layer_ref, slot_ref, group_ref, bits_ref, first_ref,
         l_ref[0] = l_scr[...]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("sm_scale", "block_size", "interpret"))
+def _dot(a, b, contract: int):
+    """``a`` (M, K) times ``b`` contracted over its dimension
+    ``contract``, accumulated in float32 and as exact as float32 products
+    of the values the operands hold: operands of one type are one product
+    (float32 ones at the highest precision); a float32 ``a`` against a
+    narrower ``b`` goes in two parts of ``b``'s type (16 bits of its
+    mantissa), so that ``b`` is never converted."""
+    dims = (((1,), (contract,)), ((), ()))
+
+    def dot(x):
+        return jax.lax.dot_general(
+            x, b, dims, preferred_element_type=jnp.float32,
+            precision=(jax.lax.Precision.HIGHEST
+                       if b.dtype == jnp.float32 else None))
+
+    if a.dtype.itemsize <= b.dtype.itemsize:
+        return dot(a.astype(b.dtype))
+    hi = a.astype(b.dtype)
+    return dot(hi) + dot((a - hi.astype(a.dtype)).astype(b.dtype))
+
+
+def _decode_rows_kernel(layer_ref, slot_ref, rows_ref, blocks_ref, first_ref,
+                        count_ref,                          # scalar prefetch
+                        q_ref, *refs, sm_scale: float):
+    """One run of a row-major pool: ``BLOCKS_PER_STEP`` blocks of one
+    slot's K and V, ``(G, H, hd)`` each. Column ``c`` of the ``(H, P * G
+    * H)`` logits is row ``c // H`` of the run against key head ``c %
+    H``; a query head keeps its own, of the run's live rows."""
+    del layer_ref, blocks_ref                    # the index maps read them
+    P = BLOCKS_PER_STEP
+    k_refs, v_refs = refs[:P], refs[P:2 * P]
+    o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr = refs[2 * P:]
+    i = pl.program_id(0)
+    b = slot_ref[i]
+    G, H, hd = k_refs[0].shape
+
+    @pl.when(i == first_ref[b])
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, DEFAULT_MASK_VALUE, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    shape = (H, P * G * H)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if H & (H - 1):
+        row = jax.lax.div(col, jnp.int32(H))
+    else:
+        row = col >> (H.bit_length() - 1)
+    valid = ((col - row * H) == jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+             ) & (row < rows_ref[i])
+
+    q = q_ref[0]
+    s = jnp.concatenate([_dot(q, k[...].reshape(G * H, hd), 1)
+                         for k in k_refs], axis=1) * sm_scale
+    s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
+    m_prev = m_scr[...]                                      # (H, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[...] = m_new
+    acc = acc_scr[...] * alpha
+    for n, v in enumerate(v_refs):
+        acc += _dot(p[:, n * G * H:(n + 1) * G * H],
+                    v[...].reshape(G * H, hd), 0)
+    acc_scr[...] = acc
+
+    @pl.when(i == first_ref[b] + count_ref[b] - 1)
+    def _():
+        o_ref[0] = acc_scr[...]
+        m_ref[0] = jnp.broadcast_to(m_scr[...], (H, GROUP_ROWS))
+        l_ref[0] = jnp.broadcast_to(l_scr[...], (H, GROUP_ROWS))
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block_size",
+                                             "interpret", "layout"))
 def _pool_attention(q, k_pool, v_pool, layer, plan, *, sm_scale: float,
-                    block_size: int, interpret: bool):
+                    block_size: int, interpret: bool, layout: str):
     """The kernel call: attention of ``q`` (B, H, hd) over the pool keys
     the plan lists, unnormalised. Returns float32 ``o`` (B, H, hd) =
     Σ exp(s − m)·v, ``m`` (B, H) the running maximum and ``l`` (B, H) =
@@ -187,18 +354,49 @@ def _pool_attention(q, k_pool, v_pool, layer, plan, *, sm_scale: float,
     lowering a Pallas kernel is Python time that no compile cache saves."""
     B, H, hd = q.shape
     L, rows = k_pool.shape[:2]
+
+    def slot_map(i, layer_r, slot_r, *_):
+        return (slot_r[i], 0, 0)
+
+    stat = pl.BlockSpec((1, H, GROUP_ROWS), slot_map)
+    stats = [jax.ShapeDtypeStruct((B, H, GROUP_ROWS), jnp.float32)] * 2
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    if layout == "rows":
+        P = BLOCKS_PER_STEP
+
+        def block_map(n):
+            def index(i, layer_r, slot_r, rows_r, blocks_r, *_):
+                return (layer_r[0], blocks_r[i * P + n], 0, 0)
+            return pl.BlockSpec((None, block_size, H, hd), index)
+
+        blocks = [block_map(n) for n in range(P)]
+        q_spec = pl.BlockSpec((1, H, hd), slot_map)
+        col = pltpu.VMEM((H, 1), jnp.float32)
+        o, m, l = pl.pallas_call(
+            functools.partial(_decode_rows_kernel, sm_scale=sm_scale),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=6,
+                grid=(plan["n_runs"][0],),
+                in_specs=[q_spec] + blocks + blocks,
+                out_specs=[q_spec, stat, stat],
+                scratch_shapes=[pltpu.VMEM((H, hd), jnp.float32), col, col],
+            ),
+            out_shape=[jax.ShapeDtypeStruct((B, H, hd), jnp.float32)] + stats,
+            compiler_params=params,
+            interpret=interpret,
+            name="paged_attn_decode_rows",
+        )(jnp.asarray(layer, jnp.int32).reshape(1), plan["slot"],
+          plan["rows"], plan["blocks"], plan["first"], plan["count"], q,
+          *([k_pool] * P), *([v_pool] * P))
+        return _unseen_masked(plan, o, m, l)
     # (L, rows, H, hd) -> (L, H, hd, rows): the layout the pool lies in
     kt = jnp.transpose(k_pool, (0, 2, 3, 1))
     vt = jnp.transpose(v_pool, (0, 2, 3, 1))
     qt = jnp.transpose(q.astype(jnp.float32), (0, 2, 1))         # (B, hd, H)
 
-    def slot_map(i, layer_r, slot_r, *_):
-        return (slot_r[i], 0, 0)
-
     def pool_map(i, layer_r, slot_r, group_r, *_):
         return (layer_r[0], 0, 0, group_r[i])
 
-    stat = pl.BlockSpec((1, H, GROUP_ROWS), slot_map)
     wide = pltpu.VMEM((H, hd, GROUP_ROWS), jnp.float32)
     row = pltpu.VMEM((H, GROUP_ROWS), jnp.float32)
     o, m, l = pl.pallas_call(
@@ -215,41 +413,46 @@ def _pool_attention(q, k_pool, v_pool, layer, plan, *, sm_scale: float,
             out_specs=[pl.BlockSpec((1, hd, H), slot_map), stat, stat],
             scratch_shapes=[wide, wide, row, row, row, row, row],
         ),
-        out_shape=[jax.ShapeDtypeStruct((B, hd, H), jnp.float32),
-                   jax.ShapeDtypeStruct((B, H, GROUP_ROWS), jnp.float32),
-                   jax.ShapeDtypeStruct((B, H, GROUP_ROWS), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        out_shape=[jax.ShapeDtypeStruct((B, hd, H), jnp.float32)] + stats,
+        compiler_params=params,
         interpret=interpret,
         name="paged_attn_decode",
     )(jnp.asarray(layer, jnp.int32).reshape(1), plan["slot"], plan["group"],
       plan["bits"], plan["first"], plan["count"], plan["tail"], qt, kt, vt)
-    # a slot with no run was never written: take nothing from its rows
+    return _unseen_masked(plan, o, m, l, heads_last=True)
+
+
+def _unseen_masked(plan, o, m, l, heads_last: bool = False):
+    """A slot with no run was never written: take nothing from its rows.
+    ``o`` is ``(B, H, hd)``, or ``(B, hd, H)`` with ``heads_last``."""
     seen = (plan["count"] > 0)[:, None]
-    o = jnp.where(seen[..., None], jnp.transpose(o, (0, 2, 1)), 0.0)
-    m = jnp.where(seen, m[:, :, 0], DEFAULT_MASK_VALUE)
-    l = jnp.where(seen, l[:, :, 0], 0.0)
-    return o, m, l
+    wide = seen[..., None]
+    if heads_last:
+        o = jnp.transpose(o, (0, 2, 1))
+    return (jnp.where(wide, o, 0.0),
+            jnp.where(seen, m[:, :, 0], DEFAULT_MASK_VALUE),
+            jnp.where(seen, l[:, :, 0], 0.0))
 
 
 def paged_attention_decode(q, k_new, v_new, k_pool, v_pool, layer, plan,
                            lengths, *, block_size: int,
                            sm_scale: float | None = None,
-                           interpret: bool = False):
+                           interpret: bool = False, layout: str = "lanes"):
     """Attention of one query per slot over positions ``0..length-1``.
 
     ``q`` (B, H, hd) sits at position ``lengths - 1``; ``k_new`` /
     ``v_new`` (B, H, hd) are that position's K and V in the pool's dtype
     (as the pool will hold them); positions below it are read out of
     layer ``layer`` of ``k_pool`` / ``v_pool`` (L, rows, H, hd) through
-    ``plan`` = :func:`decode_plan` of the block table and
-    ``lengths - 1``. A slot of length 0 attends nothing and returns
-    zeros. Returns (B, H, hd) in ``q.dtype``."""
+    ``plan`` = :func:`plan_for` the ``layout`` of the block table and
+    ``lengths - 1``. A slot of
+    length 0 attends nothing and returns zeros. Returns (B, H, hd) in
+    ``q.dtype``."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     o, m, l = _pool_attention(q, k_pool, v_pool, layer, plan,
                               sm_scale=sm_scale, block_size=block_size,
-                              interpret=interpret)
+                              interpret=interpret, layout=layout)
     s_new = jnp.sum(q.astype(jnp.float32) * k_new.astype(jnp.float32),
                     axis=-1) * sm_scale                            # (B, H)
     top = jnp.maximum(m, s_new)
@@ -289,22 +492,29 @@ def _write_kernel(rows_ref, order_ref, start_ref, group_ref,  # prefetch
 
 
 def write_rows(k_pool, v_pool, k_new, v_new, rows, active, *,
-               interpret: bool = False):
+               interpret: bool = False, layout: str = "lanes"):
     """Both pools (L, R, H, hd) with row ``rows[b]`` of every layer set
     to ``k_new[:, b]`` / ``v_new[:, b]`` (``(L, B, H, hd)``) for every
     slot with ``active[b]``, in place when the pools are donated; every
     other row stays bit for bit, and an inactive slot writes nothing.
 
-    In the layout the pool lies in a row is one lane of 64 tiles, and a
-    DMA moves whole tiles, so the kernel reads a 128-row group, sets the
-    lanes and writes the group back (``input_output_aliases``: no other
-    group is touched). One grid step per layer and per DISTINCT group,
-    the slots sorted by group, so no two steps in flight ever hold the
-    same rows: the pipeline may fetch ahead and write behind. Slots that
+    A row-major pool (``layout="rows"``) takes XLA's scatter, which
+    writes that layout where it lies (an inactive slot's index is out of
+    range and dropped; active slots name distinct rows). In the
+    ``"lanes"`` layout a row is one lane of 64 tiles, and a DMA moves
+    whole tiles, so the kernel reads a 128-row group, sets the lanes and
+    writes the group back (``input_output_aliases``: no other group is
+    touched). One grid step per layer and per DISTINCT group, the slots
+    sorted by group, so no two steps in flight ever hold the same rows:
+    the pipeline may fetch ahead and write behind. There, slots that
     name one row are applied in slot order."""
     L, R, H, hd = k_pool.shape
     B = rows.shape[0]
     rows = rows.astype(jnp.int32)
+    if layout == "rows":
+        at = jnp.where(active, rows, R)
+        return tuple(pool.at[:, at].set(new.astype(pool.dtype), mode="drop")
+                     for pool, new in ((k_pool, k_new), (v_pool, v_new)))
     # inactive slots sort behind every group and open no step
     group = jnp.where(active, rows // GROUP_ROWS, R)
     order = jnp.argsort(group, stable=True).astype(jnp.int32)

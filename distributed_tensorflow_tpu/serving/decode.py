@@ -22,6 +22,9 @@ tree (stacked-layers layout) and the block-allocated KV pool
   the same factored rule, greedy decode through the cache matches
   argmax over full-sequence recompute — the correctness contract
   tests/test_serving.py pins on 1 device and on dp×tp meshes.
+- :func:`make_multi_decode_fn` — k of those steps in one program, each
+  fed the token the last one chose: the host is needed once per k
+  tokens a sequence.
 - :func:`make_extend_fn` — the MULTI-token cache-aware forward: E new
   tokens per slot at explicit absolute positions, written then attended
   against each slot's block window. This is both the prefix-cache
@@ -83,6 +86,48 @@ def canonical_params(cfg: TransformerConfig, params):
     params["layers"] = jax.tree_util.tree_map(
         lambda *xs: jnp.stack(xs), *layers)
     return params
+
+
+def resident_params(cfg: TransformerConfig, params):
+    """The canonical tree with the query, key and value kernels as plain
+    matrices, output features major: ``(L, D, H, hd)`` → ``(L, H * hd,
+    D)``: the form in which weights that stay on the device in the
+    compute type cost no relayout. The device tiles an array's last two
+    dimensions, so it keeps ``(D, H, hd)`` as one ``(H, hd)`` tile per
+    input feature, which no matrix unit can contract over ``D``; the
+    compiler then copies all three stacks into another layout at the
+    head of every program run (for the looped model of PR 28 three
+    copies of 403 MB: 3.7 ms of a 42 ms decode step and 1.2 GB of
+    temporaries in each program). With ``D`` minor the compiled decode
+    and extend programs hold no temporaries to speak of (compiled for a
+    described v5e; ``(L, D, H * hd)`` still cost two of the copies). The
+    programs read either form (:func:`_heads`); the engine keeps this
+    one where the weights arrive in a 16-bit compute type and there is
+    no mesh (float32 weights are converted in every run anyway, and a
+    mesh shards the head axis)."""
+    params = dict(canonical_params(cfg, params))
+    layers = dict(params["layers"])
+    attn = dict(layers["attn"])
+    for name in ("query", "key", "value"):
+        w = jnp.asarray(attn[name])
+        if w.ndim == 4:
+            attn[name] = w.reshape(w.shape[:2] + (-1,)).transpose(0, 2, 1)
+    layers["attn"] = attn
+    params["layers"] = layers
+    return params
+
+
+def _heads(h, w, n_heads: int):
+    """``h`` through a projection kernel into heads, in the attention
+    layout: ``(B, D)`` → ``(B, H, hd)``, ``(B, S, D)`` → ``(B, H, S,
+    hd)``. ``w`` is one layer's ``(D, H, hd)`` kernel as the model keeps
+    it, or ``(H * hd, D)`` as :func:`resident_params` does."""
+    if w.ndim == 3:
+        return jnp.einsum("bd,dhk->bhk" if h.ndim == 2 else "bsd,dhk->bhsk",
+                          h, w)
+    y = jnp.einsum("...d,nd->...n", h, w)
+    y = y.reshape(y.shape[:-1] + (n_heads, -1))
+    return y if h.ndim == 2 else y.transpose(0, 2, 1, 3)
 
 
 def truncated_draft(cfg: TransformerConfig, params, n_layers=None):
@@ -199,48 +244,138 @@ def make_copy_fn():
     return copy
 
 
+# ---------------------------------------------------------------------------
+# the stack: one layer body per program, run once or looped
+# ---------------------------------------------------------------------------
+
+def _run_stack(cfg: TransformerConfig, params, x, carry, layer):
+    """``x`` through the whole stack. ``layer(x, carry, p, cl)`` →
+    ``(x, carry, out)`` is one layer: ``p`` its parameters, ``cl`` its
+    CACHE layer (``pass * n_layers + layer``), ``carry`` whatever the
+    program threads through the layers (the pool, or None), ``out`` what
+    it hands back per layer (or None). The final norm follows every pass
+    and its output feeds the next. Returns the normed ``x``, the carry,
+    and the layers' outs: a list of them for one pass (the caller stacks
+    them with :func:`_stacked` where it always did), stacked over the
+    cache layers for a looped stack.
+
+    One pass unrolls in Python with ``cl`` a Python int. A looped stack
+    (``cfg.passes > 1``) is ONE compiled body: a scan over the passes of
+    a scan over the layers, ``cl`` traced, so ``passes x n_layers``
+    bodies and kernel calls are never unrolled; the body of a pass lies
+    under the scope ``loop.pass``."""
+    def final_norm(x):
+        return _rms_norm(x, params["final_norm"]["scale"], cfg.dtype)
+
+    if cfg.passes == 1:
+        outs = []
+        for l in range(cfg.n_layers):
+            x, carry, out = layer(x, carry, _layer(params, l), l)
+            outs.append(out)
+        return final_norm(x), carry, outs
+
+    layers = dict(params["layers"])
+    index = jnp.arange(cfg.n_layers, dtype=jnp.int32)
+    # the stream that passes x layers x 2 branches are added into lives
+    # in float32 between the layers (every branch is still computed in
+    # cfg.dtype from its norm): with post norms each branch has an RMS
+    # near 1 and the stream grows to 10, where a bfloat16 stream drops
+    # 2% of every branch it takes in, and the next pass multiplies what
+    # the last one lost (PERF.md section 6, PR 28)
+    stream = jnp.promote_types(cfg.dtype, jnp.float32)
+
+    def one_pass(state, t):
+        def one_layer(state, inp):
+            p, l = inp
+            x, carry, out = layer(*state, p, t * cfg.n_layers + l)
+            return (x, carry), out
+
+        with jax.named_scope("loop.pass"):
+            (x, carry), outs = jax.lax.scan(one_layer, state,
+                                            (layers, index))
+            return (final_norm(x).astype(stream), carry), outs
+
+    (x, carry), outs = jax.lax.scan(
+        one_pass, (x.astype(stream), carry),
+        jnp.arange(cfg.passes, dtype=jnp.int32))
+    # (passes, n_layers, ...) -> (cache layers, ...)
+    outs = jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), outs)
+    return x.astype(cfg.dtype), carry, outs
+
+
+def _stacked(outs):
+    """:func:`_run_stack`'s outs stacked over the cache layers: a list of
+    per-layer trees (one pass, unrolled) is stacked here; a looped
+    stack's scans have stacked theirs."""
+    if isinstance(outs, list):
+        return jax.tree_util.tree_map(lambda *a: jnp.stack(a), *outs)
+    return outs
+
+
+def _post_norm(cfg: TransformerConfig, p, name: str, y):
+    """Sandwich normalisation: a sub-layer's output normed before it
+    joins the residual stream, where the model has such norms."""
+    if not cfg.post_norms:
+        return y
+    with jax.named_scope("norm.post"):
+        return _rms_norm(y, p[name]["scale"], cfg.dtype)
+
+
+def _mlp_residual(cfg: TransformerConfig, p, x):
+    """``x`` plus the gated feed-forward of its norm."""
+    dt = cfg.dtype
+    h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
+    mlp = p["mlp"]
+    with jax.named_scope("mlp"):
+        hh = jnp.einsum("...d,df->...f", h, mlp["wi"].astype(dt))
+        gate, up = jnp.split(hh, 2, axis=-1)
+        hh = jax.nn.silu(gate) * up
+        return x + _post_norm(
+            cfg, p, "post_mlp_norm",
+            jnp.einsum("...f,fd->...d", hh, mlp["wo"].astype(dt)))
+
+
+def _logits(cfg: TransformerConfig, params, x):
+    """float32 logits of the normed ``x`` against the output head: the
+    embedding, or the head's own matrix where it is not tied."""
+    head = params["embed" if cfg.tie_embeddings else "lm_head"]
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum("...d,vd->...v", x, head.astype(cfg.dtype))
+        return logits.astype(jnp.float32)
+
+
 def model_forward(cfg: TransformerConfig, params, tokens, lengths=None,
                   *, return_kv: bool = False):
     """Full-sequence forward over the canonical parameter tree — the
     serving-side twin of ``TransformerLM.__call__`` (same einsums, same
     order, no sharding-constraint machinery; GSPMD lays it out from the
     caller's in_shardings). ``lengths`` masks a right-padded batch via
-    the factored rule. ``return_kv`` additionally returns the per-layer
-    post-RoPE K and V stacks ``(L, B, H, S, hd)`` — exactly what prefill
-    writes into the cache blocks."""
+    the factored rule. ``return_kv`` additionally returns the per-cache-
+    layer post-RoPE K and V stacks ``(L, B, H, S, hd)`` — exactly what
+    prefill writes into the cache blocks."""
     dt = cfg.dtype
-    embed = params["embed"]
-    x = embed.astype(dt)[tokens]                       # (B, S, D)
-    ks, vs = [], []
-    for l in range(cfg.n_layers):
-        p = _layer(params, l)
+    x = params["embed"].astype(dt)[tokens]             # (B, S, D)
+
+    def layer(x, carry, p, cl):
         h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
         att = p["attn"]
-        q = jnp.einsum("bsd,dhk->bhsk", h, att["query"].astype(dt))
-        k = jnp.einsum("bsd,dhk->bhsk", h, att["key"].astype(dt))
-        v = jnp.einsum("bsd,dhk->bhsk", h, att["value"].astype(dt))
-        q = rotary_embedding(q, seq_axis=-2)
-        k = rotary_embedding(k, seq_axis=-2)
+        q = _heads(h, att["query"].astype(dt), cfg.n_heads)
+        k = _heads(h, att["key"].astype(dt), cfg.n_heads)
+        v = _heads(h, att["value"].astype(dt), cfg.n_heads)
+        q = rotary_embedding(q, base=cfg.rope_base, seq_axis=-2)
+        k = rotary_embedding(k, base=cfg.rope_base, seq_axis=-2)
         with jax.named_scope("attn"):
             o = mha_reference(q, k, v, causal=cfg.causal, lengths=lengths)
         o = jnp.einsum("bhsk,hkd->bsd", o, att["out"].astype(dt))
-        x = x + o
-        h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
-        mlp = p["mlp"]
-        with jax.named_scope("mlp"):
-            hh = jnp.einsum("bsd,df->bsf", h, mlp["wi"].astype(dt))
-            gate, up = jnp.split(hh, 2, axis=-1)
-            hh = jax.nn.silu(gate) * up
-            x = x + jnp.einsum("bsf,fd->bsd", hh, mlp["wo"].astype(dt))
-        if return_kv:
-            ks.append(k)
-            vs.append(v)
-    x = _rms_norm(x, params["final_norm"]["scale"], dt)
-    with jax.named_scope("lm_head"):
-        logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(dt))
-        logits = logits.astype(jnp.float32)
+        x = x + _post_norm(cfg, p, "post_attn_norm", o)
+        x = _mlp_residual(cfg, p, x)
+        return x, carry, ((k, v) if return_kv else None)
+
+    x, _, kv = _run_stack(cfg, params, x, None, layer)
+    logits = _logits(cfg, params, x)
     if return_kv:
-        return logits, (jnp.stack(ks), jnp.stack(vs))
+        return logits, _stacked(kv)
     return logits
 
 
@@ -263,12 +398,19 @@ def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None):
         rows = write_rows.reshape(-1)                       # (B*S,)
         flat_k = ks.transpose(0, 1, 3, 2, 4).reshape(L, B * S, H, hd)
         flat_v = vs.transpose(0, 1, 3, 2, 4).reshape(L, B * S, H, hd)
-        for l in range(L):
-            pool = _pool_write(pool, l, rows, flat_k[l], flat_v[l],
+        if cfg.passes == 1:
+            for l in range(L):
+                pool = _pool_write(pool, l, rows, flat_k[l], flat_v[l],
+                                   quantized)
+        else:
+            # every cache layer in one write: a looped stack has too
+            # many to unroll one scatter each
+            pool = _pool_write(pool, slice(None), rows, flat_k, flat_v,
                                quantized)
         last = logits[jnp.arange(B), jnp.maximum(lengths, 1) - 1]
         return last, pool
 
+    prefill.passes = cfg.passes
     return prefill
 
 
@@ -294,84 +436,89 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None, *,
       slot's full block-window gather index.
     - ``kv_path == "paged"``: ``table`` is the block table (B,
       max_blocks), each slot's physical blocks in logical order, padded
-      with the trash block: per layer one ``paged_attn_decode`` kernel
-      over the slot's live blocks, and after the last layer one in-place
-      write of every layer's new row (``paged_kv_write``). "interpret"
-      is the same path with the kernels interpreted, for the CPU.
+      with the trash block: per cache layer one paged-attention kernel
+      over the slot's live blocks (``decode.kv_layout`` names the layout
+      the pool lies in and so the kernel), and after the last layer one
+      in-place write of every cache layer's new row
+      (``paged_attention.write_rows``). "interpret" is the same path
+      with the kernels interpreted, for the CPU.
     """
     if not cfg.causal:
         raise ValueError("incremental decode requires a causal model; "
                          "serve bidirectional (BERT) configs through the "
                          "prefill/scoring path")
     quantized = cache_cfg.quantized if cache_cfg is not None else False
-    can_page = cache_cfg is not None and paged_attention.supported(
+    layout = cache_cfg is not None and paged_attention.supported(
         cache_cfg.num_blocks * cache_cfg.block_size, cache_cfg.block_size,
-        cache_cfg.head_dim, cache_cfg.dtype)
+        cache_cfg.head_dim, cache_cfg.dtype, cache_cfg.n_heads)
     if implementation is None:
-        implementation = ("paged" if can_page
+        implementation = ("paged" if layout
                           and jax.default_backend() == "tpu" else "window")
     if implementation not in ("window", "paged", "interpret"):
         raise ValueError(f"implementation={implementation!r}; expected "
                          f"'window', 'paged', 'interpret' or None")
     paged = implementation != "window"
-    if paged and not can_page:
+    if paged and not layout:
         raise ValueError(f"the paged decode path cannot read this pool "
                          f"({cache_cfg}): see ops.paged_attention.supported")
     interpret = implementation == "interpret"
 
     def decode(params, pool, tokens, positions, lengths, write_rows, table):
         dt = cfg.dtype
-        embed = params["embed"]
-        x = embed.astype(dt)[tokens]                    # (B, D)
+        x = params["embed"].astype(dt)[tokens]          # (B, D)
         pos_q = positions[:, None]                      # (B, 1)
         if paged:
             bs = cache_cfg.block_size
             with jax.named_scope("kv.gather"):
                 # the keys already in the pool: all but the new token's
-                plan = paged_attention.decode_plan(
-                    table, jnp.maximum(lengths - 1, 0), block_size=bs)
-            ks, vs = [], []
-        for l in range(cfg.n_layers):
-            p = _layer(params, l)
+                plan = paged_attention.plan_for(
+                    layout, table, jnp.maximum(lengths - 1, 0),
+                    block_size=bs)
+
+        def layer(x, pool, p, cl):
             h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
             att = p["attn"]
-            q = jnp.einsum("bd,dhk->bhk", h, att["query"].astype(dt))
-            k = jnp.einsum("bd,dhk->bhk", h, att["key"].astype(dt))
-            v = jnp.einsum("bd,dhk->bhk", h, att["value"].astype(dt))
-            q = rotary_at(q[:, :, None], pos_q)          # (B, H, 1, hd)
-            k = rotary_at(k[:, :, None], pos_q)[:, :, 0]  # (B, H, hd)
+            q = _heads(h, att["query"].astype(dt), cfg.n_heads)
+            k = _heads(h, att["key"].astype(dt), cfg.n_heads)
+            v = _heads(h, att["value"].astype(dt), cfg.n_heads)
+            q = rotary_at(q[:, :, None], pos_q,
+                          base=cfg.rope_base)            # (B, H, 1, hd)
+            k = rotary_at(k[:, :, None], pos_q,
+                          base=cfg.rope_base)[:, :, 0]   # (B, H, hd)
             if paged:
                 # the new token's K and V as the pool will hold them:
                 # merged into the softmax from registers, written once
                 # after the last layer
-                ks.append(k.astype(pool["k"].dtype))
-                vs.append(v.astype(pool["v"].dtype))
+                new = (k.astype(pool["k"].dtype), v.astype(pool["v"].dtype))
                 with jax.named_scope("kv.gather"):
                     o = paged_attention.paged_attention_decode(
-                        q[:, :, 0], ks[-1], vs[-1], pool["k"], pool["v"],
-                        l, plan, lengths, block_size=bs,
-                        interpret=interpret)
+                        q[:, :, 0], *new, pool["k"], pool["v"],
+                        cl, plan, lengths, block_size=bs,
+                        interpret=interpret, layout=layout)
             else:
                 # write THEN gather: the query must see its own position
-                pool = _pool_write(pool, l, write_rows, k, v, quantized)
-                kw, vw = _pool_window(pool, l, table, dt, quantized)
+                new = None
+                pool = _pool_write(pool, cl, write_rows, k, v, quantized)
+                kw, vw = _pool_window(pool, cl, table, dt, quantized)
                 with jax.named_scope("attn"):
                     o = mha_reference(q, kw, vw, causal=True,
                                       lengths=lengths,
                                       q_positions=positions)[:, :, 0]
             o = jnp.einsum("bhk,hkd->bd", o, att["out"].astype(dt))
-            x = x + o
-            h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
-            mlp = p["mlp"]
-            with jax.named_scope("mlp"):
-                hh = jnp.einsum("bd,df->bf", h, mlp["wi"].astype(dt))
-                gate, up = jnp.split(hh, 2, axis=-1)
-                hh = jax.nn.silu(gate) * up
-                x = x + jnp.einsum("bf,fd->bd", hh, mlp["wo"].astype(dt))
-        x = _rms_norm(x, params["final_norm"]["scale"], dt)
-        with jax.named_scope("lm_head"):
-            logits = jnp.einsum("bd,vd->bv", x, embed.astype(dt))
-            logits = logits.astype(jnp.float32)
+            x = x + _post_norm(cfg, p, "post_attn_norm", o)
+            return _mlp_residual(cfg, p, x), pool, new
+
+        # the paged path only reads the pool inside the stack, so the
+        # layers close over it; the window path threads it through them
+        if paged:
+            def reading(x, _, p, cl):
+                x, _, new = layer(x, pool, p, cl)
+                return x, None, new
+
+            x, _, new_rows = _run_stack(cfg, params, x, None, reading)
+        else:
+            x, pool, _ = _run_stack(cfg, params, x, pool, layer)
+        logits = _logits(cfg, params, x)
         if paged:
             with jax.named_scope("kv.write"):
                 # every kernel has read the pool before a row changes
@@ -379,11 +526,58 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None, *,
                     (pool["k"], pool["v"], logits))
                 pool = dict(pool)
                 pool["k"], pool["v"] = paged_attention.write_rows(
-                    pool_k, pool_v, jnp.stack(ks), jnp.stack(vs),
-                    write_rows, lengths > 0, interpret=interpret)
+                    pool_k, pool_v, *_stacked(new_rows), write_rows,
+                    lengths > 0, interpret=interpret, layout=layout)
         return logits, pool
 
     decode.kv_path = "paged" if paged else "window"
+    decode.kv_layout = layout if paged else None
+    decode.passes = cfg.passes
+    return decode
+
+
+def make_multi_decode_fn(step, steps: int):
+    """``steps`` greedy runs of ``step`` (a :func:`make_decode_fn`
+    program) in ONE program: ``decode(params, pool, tokens, positions,
+    lengths, write_rows, table, budget)`` → ``(tokens, pool)`` with
+    ``tokens`` (B, steps) the greedy choice of every inner step.
+
+    Inner step ``i`` is ``step`` as the engine would call it ``i`` steps
+    later: slot ``b`` is fed the token inner step ``i - 1`` chose (the
+    given ``tokens`` (B,) at ``i = 0``) at ``positions + i``, sees
+    ``lengths + i`` keys and writes its K and V to ``write_rows[b, i]``
+    (``write_rows`` is (B, steps)), while ``i < budget[b]``. Past its
+    ``budget`` (B,) a slot idles as an empty one does (length 0, the
+    trash row 0) and what it yields is garbage nobody reads; ``table``
+    covers every position the budgets reach. The same body, the same
+    inputs: the tokens are those of ``steps`` single launches.
+
+    What the host costs between two launches (uploads, dispatch, the
+    result's way back), and what a stall of the host costs the device,
+    is then paid once per ``steps`` tokens a sequence (PERF.md section
+    6, PR 28)."""
+    if steps < 2:
+        raise ValueError(f"a multi-step decode runs 2 steps or more, "
+                         f"not {steps}")
+
+    def decode(params, pool, tokens, positions, lengths, write_rows, table,
+               budget):
+        def one(carry, i):
+            pool, tokens = carry
+            live = i < budget
+            logits, pool = step(
+                params, pool, tokens, positions + i,
+                jnp.where(live, lengths + i, 0),
+                jnp.where(live, write_rows[:, i], 0), table)
+            tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (pool, tokens), tokens
+
+        (pool, _), chosen = jax.lax.scan(
+            one, (pool, tokens), jnp.arange(steps, dtype=jnp.int32))
+        return chosen.T, pool
+
+    decode.kv_path, decode.kv_layout = step.kv_path, step.kv_layout
+    decode.passes = step.passes
     return decode
 
 
@@ -420,41 +614,33 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
                window_rows):
         dt = cfg.dtype
         B, E = tokens.shape
-        embed = params["embed"]
-        x = embed.astype(dt)[tokens]                    # (B, E, D)
+        x = params["embed"].astype(dt)[tokens]          # (B, E, D)
         rows = write_rows.reshape(-1)                   # (B*E,)
-        for l in range(cfg.n_layers):
-            p = _layer(params, l)
+
+        def layer(x, pool, p, cl):
             h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
             att = p["attn"]
-            q = jnp.einsum("bsd,dhk->bhsk", h, att["query"].astype(dt))
-            k = jnp.einsum("bsd,dhk->bhsk", h, att["key"].astype(dt))
-            v = jnp.einsum("bsd,dhk->bhsk", h, att["value"].astype(dt))
-            q = rotary_at(q, positions)                  # (B, H, E, hd)
-            k = rotary_at(k, positions)
+            q = _heads(h, att["query"].astype(dt), cfg.n_heads)
+            k = _heads(h, att["key"].astype(dt), cfg.n_heads)
+            v = _heads(h, att["value"].astype(dt), cfg.n_heads)
+            q = rotary_at(q, positions, base=cfg.rope_base)  # (B, H, E, hd)
+            k = rotary_at(k, positions, base=cfg.rope_base)
             # write THEN gather: query i must see keys 0..i of the span
             flat_k = k.transpose(0, 2, 1, 3).reshape(B * E, k.shape[1],
                                                      k.shape[3])
             flat_v = v.transpose(0, 2, 1, 3).reshape(B * E, v.shape[1],
                                                      v.shape[3])
-            pool = _pool_write(pool, l, rows, flat_k, flat_v, quantized)
-            kw, vw = _pool_window(pool, l, window_rows, dt, quantized)
+            pool = _pool_write(pool, cl, rows, flat_k, flat_v, quantized)
+            kw, vw = _pool_window(pool, cl, window_rows, dt, quantized)
             with jax.named_scope("attn"):
                 o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
                                   q_positions=positions)  # (B, H, E, hd)
             o = jnp.einsum("bhsk,hkd->bsd", o, att["out"].astype(dt))
-            x = x + o
-            h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
-            mlp = p["mlp"]
-            with jax.named_scope("mlp"):
-                hh = jnp.einsum("bsd,df->bsf", h, mlp["wi"].astype(dt))
-                gate, up = jnp.split(hh, 2, axis=-1)
-                hh = jax.nn.silu(gate) * up
-                x = x + jnp.einsum("bsf,fd->bsd", hh, mlp["wo"].astype(dt))
-        x = _rms_norm(x, params["final_norm"]["scale"], dt)
-        with jax.named_scope("lm_head"):
-            logits = jnp.einsum("bsd,vd->bsv", x, embed.astype(dt))
-            return logits.astype(jnp.float32), pool
+            x = x + _post_norm(cfg, p, "post_attn_norm", o)
+            return _mlp_residual(cfg, p, x), pool, None
+
+        x, pool, _ = _run_stack(cfg, params, x, pool, layer)
+        return _logits(cfg, params, x), pool
 
     return extend
 

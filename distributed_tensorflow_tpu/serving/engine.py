@@ -86,6 +86,7 @@ from distributed_tensorflow_tpu import telemetry
 from distributed_tensorflow_tpu.telemetry import goodput as _goodput
 from distributed_tensorflow_tpu.models.transformer import (
     TransformerConfig, TransformerLM)
+from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.resilience import faults
 from distributed_tensorflow_tpu.serving import decode as decode_lib
 from distributed_tensorflow_tpu.serving.kv_cache import (
@@ -112,18 +113,40 @@ def migrate_span_id(request_id: str) -> str:
     return f"kvmig/{request_id}"
 
 
+def _leaf_checksum(leaf):
+    """Two 32-bit sums over a leaf's bits, computed where the leaf lies:
+    the plain sum of its words and the sum weighted by position, both
+    modulo 2**32 (a leaf of 1-, 2- or 4-byte elements)."""
+    bits = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32}[leaf.dtype.itemsize]
+    words = jax.lax.bitcast_convert_type(leaf, bits).astype(
+        jnp.uint32).reshape(-1)
+    weight = jax.lax.iota(jnp.uint32, words.size) * jnp.uint32(
+        2654435761) + jnp.uint32(1)
+    return jnp.stack([jnp.sum(words, dtype=jnp.uint32),
+                      jnp.sum(words * weight, dtype=jnp.uint32)])
+
+
 def params_digest(params) -> str:
     """Content digest of a parameter tree: crc32 over the tree
-    structure plus every leaf's raw bytes, host-fetched. Two engines
-    serving byte-identical weights get the same digest regardless of
-    how the weights arrived (fresh init, restore tier, hot-swap) — the
-    content half of the ``weights_version`` identity stamped on every
-    serving event."""
+    structure and every leaf's shape, type and checksum. The checksums
+    are computed on the device that holds the leaves and eight bytes a
+    leaf come back to the host, so a multi-gigabyte tree costs no host
+    round trip. Two engines serving byte-identical weights get the same
+    digest regardless of how the weights arrived (fresh init, restore
+    tier, hot-swap) — the content half of the ``weights_version``
+    identity stamped on every serving event."""
     leaves, treedef = jax.tree_util.tree_flatten(params)
+    leaves = [jnp.asarray(leaf) for leaf in leaves]
+    # a jit of this call's own: its programs are unloaded with it, so a
+    # digest leaves nothing on the device and what is allocated next (the
+    # engine's pool) lies where it would lie without one
+    checksum = jax.jit(lambda leaf: _leaf_checksum(leaf))
+    sums = jax.device_get([checksum(leaf) for leaf in leaves])
     crc = zlib.crc32(repr(treedef).encode())
-    for leaf in leaves:
-        a = np.ascontiguousarray(np.asarray(jax.device_get(leaf)))
-        crc = zlib.crc32(a.tobytes(), crc)
+    for leaf, pair in zip(leaves, sums):
+        crc = zlib.crc32(
+            f"{leaf.shape}{leaf.dtype}{int(pair[0])},{int(pair[1])}"
+            .encode(), crc)
     return f"{crc & 0xFFFFFFFF:08x}"
 
 
@@ -142,6 +165,12 @@ class InferenceEngine:
     prefixes across requests; ``speculative_k=k`` drafts k tokens per
     slot and verifies them in one forward (``draft_params``/
     ``draft_cfg`` override the default truncated-target draft);
+    ``decode_steps=k`` runs k greedy decode steps in one launch of the
+    decode program, each sequence up to its remaining budget, so a
+    ``step()`` releases up to k tokens a sequence and the host stands
+    between two launches once per k tokens (a sequence that ends inside
+    a launch frees its slot at the launch's end, and ``token_budget``
+    still counts one decode token a running sequence);
     ``kv_dtype`` in {"f32", "bf16", "int8"} picks the pool's storage
     dtype (``cache_dtype`` remains the raw-dtype spelling)."""
 
@@ -156,6 +185,7 @@ class InferenceEngine:
                  prefix_caching: bool = False,
                  speculative_k: int = 0,
                  draft_params=None, draft_cfg=None,
+                 decode_steps: int = 1,
                  role: str = "both",
                  spill_tier: "HostTier | int | None" = None,
                  snapshot_step: int | None = None):
@@ -200,6 +230,13 @@ class InferenceEngine:
             raise ValueError("speculative decoding requires a causal "
                              "model")
         self.spec_k = int(speculative_k)
+        self.decode_steps = int(decode_steps)
+        if self.decode_steps < 1 or (self.decode_steps > 1 and self.spec_k):
+            raise ValueError(
+                f"decode_steps={decode_steps} with speculative_k="
+                f"{speculative_k}: at least 1, and more than 1 only "
+                f"without speculation (both decide how many tokens a "
+                f"step releases)")
         self._draft_default = False
         if self.spec_k:
             if draft_params is None:
@@ -219,7 +256,7 @@ class InferenceEngine:
                                                  draft_params)))
             self._draft = decode_lib.make_draft_fn(draft_cfg)
 
-        params = decode_lib.canonical_params(cfg, params)
+        params = self._serving_tree(params)
         if mesh is not None:
             shardings = decode_lib.param_shardings(cfg, mesh)
             params = jax.tree_util.tree_map(
@@ -253,9 +290,18 @@ class InferenceEngine:
             cfg, cache_cfg,
             implementation="window" if mesh is not None else None)
             if cfg.causal and role != "prefill" else None)
+        if decode is not None and self.decode_steps > 1:
+            decode = decode_lib.make_multi_decode_fn(decode,
+                                                     self.decode_steps)
         #: how the decode program reaches the pool ("paged" / "window"):
         #: decides which table _decode_batch hands it
         self.kv_path = decode.kv_path if decode is not None else None
+        #: the passes over the stack each program was BUILT to run (the
+        #: spans report what the step's program does, not a config file)
+        self.prefill_passes = prefill.passes
+        self.decode_passes = decode.passes if decode is not None else None
+        #: the layout the paged kernel reads the pool in (what a run is)
+        self._kv_layout = decode.kv_layout if decode is not None else None
         extend = (decode_lib.make_extend_fn(cfg, cache_cfg)
                   if cfg.causal else None)
         copy_fn = decode_lib.make_copy_fn()
@@ -279,10 +325,14 @@ class InferenceEngine:
                 in_shardings=(shardings, pool_sh, rep, rep, rep),
                 out_shardings=(rep, pool_sh),
                 donate_argnums=(1,))
+            # one step: write_rows (B,), logits out; several: write_rows
+            # (B, k) and each slot's budget, tokens (B, k) out
+            many = self.decode_steps > 1
             self._decode = jax.jit(
                 decode,
-                in_shardings=(shardings, pool_sh, slotv, slotv,
-                              slotv, slotv, slotm),
+                in_shardings=(shardings, pool_sh, slotv, slotv, slotv,
+                              slotm if many else slotv, slotm)
+                + ((slotv,) if many else ()),
                 out_shardings=(slotm, pool_sh),
                 donate_argnums=(1,)) \
                 if decode is not None else None
@@ -411,6 +461,19 @@ class InferenceEngine:
             self.spill_tier = tier
 
     # -- weights -----------------------------------------------------------
+    def _serving_tree(self, params):
+        """``params`` in the layout the programs index: stacked layers,
+        and where the weights arrive in a 16-bit compute type on one
+        device, the projection kernels as plain matrices
+        (``decode.resident_params``: such weights are not converted in a
+        program run, so what is left to save is their relayout)."""
+        dtype = jnp.dtype(self.cfg.dtype)
+        leaf = jax.tree_util.tree_leaves(params)[0]
+        if (self.mesh is None and dtype.itemsize == 2
+                and jnp.dtype(leaf.dtype) == dtype):
+            return decode_lib.resident_params(self.cfg, params)
+        return decode_lib.canonical_params(self.cfg, params)
+
     @property
     def weights_version(self) -> str:
         """``<step>@<digest>`` — the identity stamped on serving
@@ -507,7 +570,7 @@ class InferenceEngine:
         t0 = started_mono if started_mono is not None \
             else time.monotonic()
         raw = params
-        params = decode_lib.canonical_params(self.cfg, params)
+        params = self._serving_tree(params)
         if self.mesh is not None:
             shardings = decode_lib.param_shardings(self.cfg, self.mesh)
             params = jax.tree_util.tree_map(
@@ -741,7 +804,8 @@ class InferenceEngine:
                 queue_wait_s=(round(queue_wait, 6)
                               if queue_wait is not None else None),
                 replayed=len(seq.request.generated_prefix) or None,
-                program="extend" if C else "prefill"):
+                program="extend" if C else "prefill",
+                passes=self.prefill_passes):
             lengths = np.asarray([seq.prompt_len], np.int32)
             if C:
                 with telemetry.span("serve.prefill.build"):
@@ -798,11 +862,15 @@ class InferenceEngine:
                    + len(seq.generated)),
             step=self._step_idx)
 
-    def _decode_batch(self, batch: list[Sequence]) -> int:
+    def _decode_batch(self, batch: list[Sequence]) -> dict:
         """One incremental token for every running sequence. The decode
         program has a fixed (max_slots,) batch; idle slots feed trash
-        rows with length 0 and their logits are never read. Returns the
-        blocks the step's KV read touches."""
+        rows with length 0 and their logits are never read. Returns what
+        the step's KV read touches, for the ``serve.decode`` span:
+        ``blocks_read``, ``token_steps`` (the tokens the launch computed:
+        one a sequence) and, while a span is recorded, ``rows_read`` (the
+        live rows: Σ lengths) and on the paged path ``runs_read`` (the
+        kernel's grid steps per cache layer)."""
         B, W = self.max_slots, self.window
         paged = self.kv_path == "paged"
         bs = self.cache_cfg.block_size
@@ -849,8 +917,98 @@ class InferenceEngine:
                 if emit:
                     self._emit_token(seq)
         if not paged:
-            return B * (W // bs)
-        return int(np.sum(-(-lengths // bs)))
+            read = {"blocks_read": B * (W // bs)}
+        else:
+            read = {"blocks_read": int(np.sum(-(-lengths // bs)))}
+        read["token_steps"] = len(batch)
+        if telemetry.recording():
+            read["rows_read"] = int(lengths.sum())
+            if paged:
+                read["runs_read"] = paged_attention.count_runs(
+                    self._kv_layout, table,
+                    -(-np.maximum(lengths - 1, 0) // bs), bs)
+        return read
+
+    # -- several decode steps a launch ------------------------------------
+    def _decode_span(self, seq: Sequence) -> int:
+        """The tokens one launch of the multi-step decode program yields
+        ``seq``: ``decode_steps``, capped by the request's remaining
+        output budget and the sequence-length ceiling."""
+        return max(1, min(
+            self.decode_steps,
+            seq.request.max_new_tokens - len(seq.generated),
+            self.max_seq_len - seq.length + 1))
+
+    def _decode_steps_batch(self, batch: list[Sequence]) -> dict:
+        """:meth:`_decode_batch` for ``decode_steps`` > 1: ONE launch of
+        ``make_multi_decode_fn``'s program gives every running sequence
+        up to ``decode_steps`` tokens (:meth:`_decode_span`), each inner
+        step fed the token the last one chose; the host reads them all
+        at once and commits each sequence's up to its end. Returns what
+        :meth:`_decode_batch` returns, summed over the inner steps, and
+        ``token_steps``: the tokens the launch computed (Σ spans)."""
+        B, W, K = self.max_slots, self.window, self.decode_steps
+        paged = self.kv_path == "paged"
+        bs = self.cache_cfg.block_size
+        with telemetry.span("serve.decode.build"):
+            tokens = np.zeros(B, np.int32)
+            positions = np.zeros(B, np.int32)
+            lengths = np.zeros(B, np.int32)
+            budget = np.zeros(B, np.int32)
+            write_rows = np.zeros((B, K), np.int32)  # trash block row 0
+            table = (np.full((B, W // bs), TRASH_BLOCK, np.int32) if paged
+                     else np.zeros((B, W), np.int32))
+            for seq in batch:
+                s, n = seq.slot, self._decode_span(seq)
+                if self.prefix_caching:
+                    self._apply_copies(seq.table.ensure_writable(
+                        seq.length - 1, seq.length - 1 + n,
+                        self.scheduler.allocator))
+                tokens[s] = seq.last_token
+                positions[s] = seq.length - 1
+                lengths[s] = seq.length
+                budget[s] = n
+                write_rows[s, :n] = [seq.table.row_of(seq.length - 1 + i)
+                                     for i in range(n)]
+                if paged:
+                    table[s, :len(seq.table.blocks)] = seq.table.blocks
+                else:
+                    table[s] = seq.table.window_rows()
+        with telemetry.span("serve.decode.launch"):
+            chosen, self.pool = self._decode(
+                self.params, self.pool,
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(lengths), jnp.asarray(write_rows),
+                jnp.asarray(table), jnp.asarray(budget))
+        with telemetry.span("serve.decode.wait"):
+            chosen = np.asarray(chosen)                      # (B, K)
+        with telemetry.span("serve.decode.commit") as sp:
+            emit = telemetry.enabled()
+            committed = 0
+            for seq in batch:
+                for t in chosen[seq.slot, :budget[seq.slot]]:
+                    self.scheduler.append_token(seq, int(t))
+                    committed += 1
+                    if emit:
+                        self._emit_token(seq)
+                    if seq.done:            # an end-of-sequence token
+                        break
+            sp["tokens"] = committed
+        # inner step i of a slot sees lengths + i rows while i < budget
+        rows = np.where(np.arange(K) < budget[:, None],
+                        lengths[:, None] + np.arange(K), 0)     # (B, K)
+        read = {"token_steps": int(budget.sum()),
+                "blocks_read": (B * (W // bs) * K if not paged
+                                else int(np.sum(-(-rows // bs))))}
+        if telemetry.recording():
+            read["rows_read"] = int(rows.sum())
+            if paged:
+                read["runs_read"] = sum(
+                    paged_attention.count_runs(
+                        self._kv_layout, table,
+                        -(-np.maximum(rows[:, i] - 1, 0) // bs), bs)
+                    for i in range(K))
+        return read
 
     # -- speculative decoding ---------------------------------------------
     def _spec_span(self, seq: Sequence) -> int:
@@ -1009,10 +1167,16 @@ class InferenceEngine:
                         sp["accepted_drafts"] = (self._spec_accepted_n
                                                  - acc_before)
                     else:
-                        batch = sched.grow_for_decode()
+                        many = self.decode_steps > 1
+                        batch = sched.grow_for_decode(
+                            self._decode_span if many else 1)
                         dsp["kv_path"] = self.kv_path
                         if batch:
-                            dsp["blocks_read"] = self._decode_batch(batch)
+                            dsp.update(self._decode_steps_batch(batch)
+                                       if many
+                                       else self._decode_batch(batch))
+                            dsp["passes"] = self.decode_passes
+                            dsp["cache_layers"] = self.cache_cfg.n_layers
                     dsp["live"] = len(batch)
             sp["admitted"] = len(admitted)
             sp["decoded"] = len(batch)

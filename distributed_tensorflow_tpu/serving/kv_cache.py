@@ -81,7 +81,13 @@ class CacheConfig:
     ``kv_dtype`` (``"f32"``/``"bf16"``/``"int8"``) overrides ``dtype``
     by name; ``"int8"`` switches the pool to quantized storage with
     per-(row, head) f32 scales (:func:`init_pool` adds ``k_scale`` /
-    ``v_scale`` arrays)."""
+    ``v_scale`` arrays).
+
+    ``n_layers`` counts CACHE layers: one per layer of the model and per
+    pass of a looped stack (every pass attends to the keys and values
+    that pass produced), pass-major: cache layer ``pass * model layers
+    + layer``. A block of the table stands for that many layer slices,
+    so sharing, copy-on-write and eviction never see the passes."""
 
     n_layers: int
     n_heads: int
@@ -119,14 +125,14 @@ class CacheConfig:
 
     @property
     def bytes_per_token(self) -> int:
-        """Pool bytes one cached token costs (K + V, scales included
-        for quantized dtypes) — the slots-per-chip arithmetic behind
-        the README's KV-dtype table."""
+        """Pool bytes one cached token costs (K + V over every cache
+        layer, scales included for quantized dtypes) — the
+        slots-per-chip arithmetic behind the README's KV-dtype table."""
         per = 2 * self.n_heads * self.head_dim \
             * jnp.dtype(self.dtype).itemsize
         if self.quantized:
             per += 2 * self.n_heads * 4          # f32 scale per head
-        return per
+        return per * self.n_layers
 
     def blocks_for_budget(self, pool_bytes: int) -> int:
         """Usable blocks (+1 trash) a device-memory budget affords at
@@ -142,8 +148,11 @@ class CacheConfig:
     def for_model(cls, model_cfg, *, num_blocks: int,
                   block_size: int = 16, dtype=None,
                   kv_dtype: str | None = None) -> "CacheConfig":
-        """Pool sized for a TransformerConfig-shaped model config."""
-        return cls(n_layers=model_cfg.n_layers, n_heads=model_cfg.n_heads,
+        """Pool sized for a TransformerConfig-shaped model config: one
+        cache layer per layer and pass."""
+        return cls(n_layers=model_cfg.n_layers
+                   * getattr(model_cfg, "passes", 1),
+                   n_heads=model_cfg.n_heads,
                    head_dim=model_cfg.head_dim, num_blocks=num_blocks,
                    block_size=block_size,
                    dtype=dtype if dtype is not None else model_cfg.dtype,

@@ -63,6 +63,21 @@ def _jit_cache_pressure_guard():
         gc.collect()
 
 
+@pytest.fixture(scope="module")
+def leave_no_programs_behind():
+    """For a file that builds many programs (a looped model's): drop the
+    process's jit caches when the file ends, whatever the map count, so
+    that the worker's later files find its heap no larger than this file
+    found it. The benchmark's serve runner collects garbage once before
+    its window; with the 1.1 million objects a worker held after the
+    looped files that took 0.6 s, the whole of a rehearsal's window.
+    Ask for it with ``pytestmark = pytest.mark.usefixtures(...)``."""
+    yield
+    import gc
+    jax.clear_caches()
+    gc.collect()
+
+
 def pytest_configure(config):
     config.addinivalue_line(
         "markers",

@@ -67,6 +67,19 @@ def test_phases_run_green_at_tiny_size(devices):
                 new_range=(4, 12), shared_len=40, suffix_range=(2, 6),
                 n_requests=6),
             devices[0], clock=clock)
+        # the looped shape, small: 2 layers x 3 passes, 6 cache layers
+        import dataclasses
+        looped = dataclasses.replace(
+            chip_smoke.looped_config(), vocab_size=256, d_model=64,
+            n_heads=4, d_ff=128, max_seq_len=64, passes=3,
+            dtype=jnp.float32, param_dtype=jnp.float32)
+        chip_smoke.serve_phase(
+            looped, chip_smoke.ServeShapes(
+                num_blocks=96, block_size=8, max_slots=8,
+                max_prompt_len=48, prompt_range=(4, 16),
+                new_range=(4, 12), shared_len=40, suffix_range=(2, 6),
+                n_requests=6),
+            devices[0], clock=clock)
         for axes in ({"dp": 4}, {"fsdp": 2, "tp": 2}):
             chip_smoke.train_phase(
                 cfg, axes, devices[:4], steps=2, clock=clock,

@@ -417,3 +417,93 @@ def test_compiled_decode_holds_nothing_of_the_pools_size(
         hlo, {cc.n_layers * rows * row, rows * row, slots * window * row})
     assert (not found) == clean, found[:5]
     assert ("paged_attn_decode" in hlo) == clean
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_steps", "prefill"])
+def test_compiled_looped_programs_hold_nothing_of_the_pools_size(
+        one_chip, no_compile_cache, program):
+    """The looped model at its published widths (``chip_smoke
+    .looped_config``: 3 layers x 4 passes, head_dim 128, bfloat16
+    weights) over a pool of 192 blocks of 16 rows (at the benchmark's 256
+    the pool would count the elements the 49152-row head counts), compiled
+    for one v5e chip: the pool lies row-major there, the decode program
+    reads it with the ``rows`` kernel and writes it with a scatter in
+    place (alone, and eight steps of it in one program:
+    ``make_multi_decode_fn``), prefill writes every cache layer in one
+    scatter, and none produces or holds an array of the pool's or a cache
+    layer's size."""
+    import chip_smoke
+
+    cfg = chip_smoke.looped_config()
+    cc = CacheConfig.for_model(cfg, num_blocks=192, block_size=16)
+    assert cc.n_layers == 12 and cc.bytes_per_token == 12 * 2 * 2048 * 2
+    slots, window = 8, cfg.max_seq_len
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    # the tree as the engine keeps bfloat16 weights: projection kernels
+    # as (H * hd, D) matrices, which the compiler does not relayout
+    shapes = jax.eval_shape(
+        lambda r: decode_lib.resident_params(cfg, TransformerLM(cfg).init(
+            r, jnp.zeros((1, 8), jnp.int32))["params"]),
+        jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype), decode_lib._plain(shapes))
+    rows = cc.num_blocks * cc.block_size
+    row = cc.n_heads * cc.head_dim
+    pool = {n: spec((cc.n_layers, rows, cc.n_heads, cc.head_dim), cc.dtype)
+            for n in ("k", "v")}
+    vec = spec((slots,), jnp.int32)
+    wide = spec((1, window), jnp.int32)
+    decode = program != "prefill"
+    if decode:
+        fn = decode_lib.make_decode_fn(cfg, cc, implementation="paged")
+        table = spec((slots, window // cc.block_size), jnp.int32)
+        args = (vec, vec, vec, vec, table)
+        if program == "decode_steps":
+            fn = decode_lib.make_multi_decode_fn(fn, 8)
+            args = (vec, vec, vec, spec((slots, 8), jnp.int32), table, vec)
+        assert (fn.kv_path, fn.kv_layout, fn.passes) == ("paged", "rows", 4)
+    else:
+        fn = decode_lib.make_prefill_fn(cfg, cc)
+        args = (wide, spec((1,), jnp.int32), wide)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    hlo = compiled.as_text()
+    sizes = {cc.n_layers * rows * row, rows * row}
+    if decode:
+        sizes.add(slots * window * row)
+    found = chip_smoke.pool_sized_ops(hlo, sizes)
+    assert not found, found[:5]
+    memory = compiled.memory_analysis()
+    pool_bytes = cc.n_layers * rows * row * 2
+    assert memory.temp_size_in_bytes < pool_bytes
+    if decode:
+        # no copy of the stacked weights either (25 MB a projection
+        # here): what is left is the step's own activations
+        assert memory.temp_size_in_bytes < 8 << 20
+        assert "copy(%params" not in hlo
+    assert memory.alias_size_in_bytes >= 2 * pool_bytes
+    assert ("paged_attn_decode_rows" in hlo) == decode
+    # one compiled body for all four passes and all layers: the kernel
+    # is called from a loop and stands once in the program's text
+    if decode:
+        assert hlo.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+def test_compiled_checksum_holds_no_copy_of_its_leaf(one_chip,
+                                                     no_compile_cache):
+    """``params_digest`` computes on the device; at a hot swap it runs
+    beside the old weights and the pool (11.78 of 16 GB for the looped
+    model), so its checksum of the largest leaf (the stacked gate and up
+    projections, 2.2 GB of bfloat16) must be one fused reduction:
+    compiled for a v5e it holds no widened or flattened copy of the
+    leaf."""
+    from distributed_tensorflow_tpu.serving.engine import _leaf_checksum
+
+    leaf = jax.ShapeDtypeStruct((48, 2048, 11264), jnp.bfloat16,
+                                sharding=one_chip)
+    memory = jax.jit(_leaf_checksum).lower(leaf).compile().memory_analysis()
+    assert memory.argument_size_in_bytes == 48 * 2048 * 11264 * 2
+    assert memory.temp_size_in_bytes < 1 << 20

@@ -184,10 +184,14 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
                             "serve.decode.launch", "serve.decode.wait",
                             "serve.decode.commit"]
             # the CPU takes the window path: every slot's whole window
-            assert inside[-5][3] == {
+            decode = dict(inside[-5][3])
+            assert decode.pop("rows_read") >= step[3]["decoded"]
+            assert decode == {
                 "live": step[3]["decoded"], "kv_path": "window",
+                "token_steps": step[3]["decoded"],
                 "blocks_read": e.max_slots * e.window
-                // e.cache_cfg.block_size}
+                // e.cache_cfg.block_size,
+                "passes": 1, "cache_layers": e.cfg.n_layers}
             assert inside[-1][3] == {"tokens": step[3]["decoded"]}
     first = [s for s in spans
              if steps[0][1] <= s[1] and s[2] <= steps[0][2]]

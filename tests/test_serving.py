@@ -250,14 +250,18 @@ class TestDecodeParity:
         assert (engine.scheduler.allocator.num_free
                 == engine.cache_cfg.usable_blocks)
 
-    def test_greedy_decode_matches_recompute_dp_tp_mesh(self, tiny,
-                                                        mesh2d):
+    @pytest.mark.parametrize("decode_steps", [1, 3])
+    def test_greedy_decode_matches_recompute_dp_tp_mesh(self, tiny, mesh2d,
+                                                        decode_steps):
         """Same contract on a dp=4 × tp=2 mesh: slots sharded over dp,
-        heads/vocab over tp, KV pool heads over tp."""
+        heads/vocab over tp, KV pool heads over tp; one decode step a
+        launch, and three (the multi-step program's write rows and
+        budgets shard over dp with the slots)."""
         cfg, params = tiny
         engine = InferenceEngine(cfg, params, mesh=mesh2d, num_blocks=32,
                                  block_size=8, max_slots=8,
-                                 max_prompt_len=16)
+                                 max_prompt_len=16,
+                                 decode_steps=decode_steps)
         outs = engine.generate(PROMPTS, max_new_tokens=6)
         for p, o in zip(PROMPTS, outs):
             assert o == reference_greedy(cfg, params, p, 6)

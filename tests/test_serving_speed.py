@@ -21,6 +21,7 @@ from distributed_tensorflow_tpu.models.transformer import (
 from distributed_tensorflow_tpu.serving import (
     BlockAllocator, CacheConfig, InferenceEngine, PrefixCache, Request,
     kv_quantization_probe, truncated_draft)
+from distributed_tensorflow_tpu.serving.kv_cache import init_pool
 
 #: Documented int8 KV logit-error bound for the CI-sized config (the
 #: probe measures ~0.004 on this box; README's KV-dtype table cites
@@ -366,6 +367,14 @@ class TestQuantizedKV:
             assert i8.blocks_for_budget(budget) \
                 >= 2 * f32.blocks_for_budget(budget)
             assert f32.bytes_per_token >= 2 * i8.bytes_per_token
+            # a token costs its K and V in EVERY cache layer (two here):
+            # the pool's own bytes say so, and the budget follows
+            for cc in (f32, i8):
+                pool_bytes = sum(a.nbytes for a in init_pool(cc).values())
+                assert pool_bytes == (cc.num_blocks * cc.block_size
+                                      * cc.bytes_per_token)
+                assert cc.blocks_for_budget(pool_bytes) == cc.num_blocks
+            assert f32.bytes_per_token == 2 * (2 * 4 * head_dim * 4)
 
     def test_kv_dtype_spelling_validated(self):
         with pytest.raises(ValueError):
