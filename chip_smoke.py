@@ -305,38 +305,57 @@ def pool_sized_ops(hlo_text: str, sizes) -> list[str]:
 
 
 def program_check(engine: InferenceEngine) -> None:
-    """Which way the engine's decode program reaches the KV pool; on the
-    paged path (what a TPU takes) the compiled program must hold nothing
-    of the pool's size: a whole-pool relayout or a whole-window gather
-    back in ``jit_decode`` stops a smoke run, not a benchmark. Neither
-    by the text of the program (no instruction produces an array of the
-    pool's, one cache layer's or every slot's window's element count,
-    but in place) nor by its memory (its temporaries are smaller than
-    one of the pool's two arrays, and its output pool is its input).
-    The same is asked of the prefill program where the device keeps
-    the pool row-major (XLA's scatter writes that layout in place; the
-    other costs prefill two pool copies, PERF.md section 7)."""
+    """Which way the engine's programs reach the KV pool; on the paged
+    paths (what a TPU takes) a compiled program must hold nothing of the
+    pool's size: a whole-pool relayout or a whole-window gather back in
+    ``jit_decode`` or ``jit_prefill`` stops a smoke run, not a
+    benchmark. Neither by the text of the program (no instruction
+    produces an array of the pool's, one cache layer's or every slot's
+    window's element count, but in place) nor by its memory (its
+    temporaries are smaller than one of the pool's two arrays, and its
+    output pool is its input). Asked of decode, of prefill where it
+    writes by blocks or the device keeps the pool row-major (XLA's
+    scatter writes that layout in place), and of extend where it writes
+    by blocks: its window gather still slices a cache layer out, so
+    there only the pool's own size counts."""
     print(f"  decode kv_path: {engine.kv_path}", flush=True)
+    print(f"  prefill kv_write: {engine.kv_write['prefill']}", flush=True)
+    print(f"  extend kv_write: {engine.kv_write['extend']}", flush=True)
     if engine.kv_path != "paged":
         return
     cc, slots = engine.cache_cfg, engine.max_slots
     row = cc.n_heads * cc.head_dim
     rows = cc.num_blocks * cc.block_size
-    sizes = {cc.n_layers * rows * row, rows * row,
-             slots * engine.window * row}
+    whole, layer = cc.n_layers * rows * row, rows * row
     i32 = jnp.zeros((slots,), jnp.int32)
+    one = jnp.ones((1,), jnp.int32)
     table = jnp.zeros((slots, engine.window // cc.block_size), jnp.int32)
     wide = jnp.zeros((1, engine.max_seq_len), jnp.int32)
-    programs = {"decode": (engine._decode, (i32, i32, i32, i32, table),
-                           sizes)}
-    if engine._kv_layout == "rows":
-        # prefill gathers no window (and its K and V stacks of a whole
-        # prompt may well count what a few slots' windows count)
-        programs["prefill"] = (
-            engine._prefill, (wide, jnp.ones((1,), jnp.int32), wide),
-            {cc.n_layers * rows * row, rows * row})
     pool_bytes = engine.pool["k"].nbytes
-    for name, (program, args, sizes) in programs.items():
+    # name: program, arguments after params and pool, the element counts
+    # no output may have, whether the temporaries are held under one
+    # pool array (a 1024-wide forward's own activations are not, beside
+    # this file's small pool)
+    programs = {"decode": (engine._decode, (i32, i32, i32, i32, table),
+                           {whole, layer, slots * engine.window * row},
+                           True)}
+    by_blocks = engine.kv_write["prefill"] == "paged"
+    if engine._kv_layout == "rows" or by_blocks:
+        # prefill gathers no window (and its K and V stacks of a whole
+        # prompt may well count what a few slots' windows count); its
+        # own attention matrix is not the pool's either, whatever it
+        # counts (at this file's serving shapes, one cache layer)
+        programs["prefill"] = (
+            engine._prefill, (wide, one, wide),
+            {whole, layer} - {cc.n_heads * engine.max_seq_len ** 2},
+            not by_blocks)
+    if engine.kv_write["extend"] == "paged":
+        span = jnp.zeros((1, min(64, engine.max_seq_len)), jnp.int32)
+        programs["extend"] = (
+            engine._extend_prefill,
+            (span, span, one, span, jnp.zeros((1, engine.window), jnp.int32)),
+            {whole}, False)
+    for name, (program, args, sizes, small) in programs.items():
         compiled = program.lower(engine.params, engine.pool,
                                  *args).compile()
         found = pool_sized_ops(compiled.as_text(), sizes)
@@ -347,12 +366,14 @@ def program_check(engine: InferenceEngine) -> None:
               f"pool's, one layer's or every slot's window's size "
               f"({sorted(sizes)} elements); found {len(found)}")
         memory = compiled.memory_analysis()
-        check(memory.temp_size_in_bytes < pool_bytes
-              and memory.alias_size_in_bytes >= 2 * pool_bytes,
-              f"the {name} program's temporaries "
-              f"({memory.temp_size_in_bytes} B) are smaller than one "
-              f"pool array ({pool_bytes} B) and its pool is updated in "
-              f"place ({memory.alias_size_in_bytes} B aliased)")
+        check(memory.alias_size_in_bytes >= 2 * pool_bytes,
+              f"the {name} program's pool is updated in place "
+              f"({memory.alias_size_in_bytes} B aliased)")
+        if small:
+            check(memory.temp_size_in_bytes < pool_bytes,
+                  f"the {name} program's temporaries "
+                  f"({memory.temp_size_in_bytes} B) are smaller than one "
+                  f"pool array ({pool_bytes} B)")
 
 
 def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
@@ -383,6 +404,10 @@ def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
     if device.platform == "tpu":
         check(engine.kv_path == "paged",
               "on a TPU the engine's decode program takes the paged path")
+        if engine._kv_layout == "lanes":
+            check(engine.kv_write["prefill"] == "paged",
+                  "on a TPU prefill writes a pool that lies with its rows "
+                  "on the lanes by blocks, in place")
     program_check(engine)
 
     cold = seeded_requests(seed, shapes.n_requests, cfg.vocab_size,

@@ -51,9 +51,15 @@ The token being decoded never comes out of the pool: its K and V are in
 registers when the step runs, so :func:`paged_attention_decode` merges
 that one key into the softmax after the kernel (the query sees its own
 position whatever the order of read and write), and the pool is written
-once per step, after the last layer, by :func:`write_rows`: a second
-kernel that takes the pool in the same view, aliased to its output, and
-rewrites only the groups that hold a written row.
+once per step, after the last layer, by :func:`write_rows`.
+
+**One kernel writes the ``"lanes"`` pool** (``paged_kv_write``,
+:func:`write_blocks`), for the decode step's one row a slot and for the
+admission programs' runs of whole blocks alike: it takes the pool in the
+same view, aliased to its output, reads a group, sets the lanes of the
+written rows that fall in it (a block is 16 neighbouring lanes) and
+writes the group back, so that no other group is touched and nothing of
+the pool's size is produced.
 """
 
 from __future__ import annotations
@@ -463,34 +469,6 @@ def paged_attention_decode(q, k_new, v_new, k_pool, v_pool, layer, plan,
     return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
 
 
-def _write_kernel(rows_ref, order_ref, start_ref, group_ref,  # prefetch
-                  kn_ref, vn_ref, k_ref, v_ref,               # inputs
-                  ko_ref, vo_ref,                             # outputs
-                  *, n_heads: int):
-    """One 128-row group of one layer, K and V: every slot whose row
-    lies in it puts its ``(H, hd)`` column into the row's lane."""
-    del group_ref                                # the index maps read it
-    s = pl.program_id(1)
-    hd = k_ref.shape[1]
-    ko_ref[...] = k_ref[...]
-    vo_ref[...] = v_ref[...]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hd, GROUP_ROWS), 1)
-
-    def one(t, carry):
-        b = order_ref[t]
-        here = lane == rows_ref[b] % GROUP_ROWS
-        for new_ref, out_ref in ((kn_ref, ko_ref), (vn_ref, vo_ref)):
-            new = new_ref[b].astype(jnp.float32)             # (hd, H)
-            for h in range(n_heads):
-                col = jnp.broadcast_to(new[:, h:h + 1], (hd, GROUP_ROWS))
-                out_ref[h] = jnp.where(
-                    here, col, out_ref[h].astype(jnp.float32)
-                ).astype(out_ref.dtype)
-        return carry
-
-    jax.lax.fori_loop(start_ref[s], start_ref[s + 1], one, 0)
-
-
 def write_rows(k_pool, v_pool, k_new, v_new, rows, active, *,
                interpret: bool = False, layout: str = "lanes"):
     """Both pools (L, R, H, hd) with row ``rows[b]`` of every layer set
@@ -502,53 +480,105 @@ def write_rows(k_pool, v_pool, k_new, v_new, rows, active, *,
     writes that layout where it lies (an inactive slot's index is out of
     range and dropped; active slots name distinct rows). In the
     ``"lanes"`` layout a row is one lane of 64 tiles, and a DMA moves
-    whole tiles, so the kernel reads a 128-row group, sets the lanes and
-    writes the group back (``input_output_aliases``: no other group is
-    touched). One grid step per layer and per DISTINCT group, the slots
-    sorted by group, so no two steps in flight ever hold the same rows:
-    the pipeline may fetch ahead and write behind. There, slots that
-    name one row are applied in slot order."""
-    L, R, H, hd = k_pool.shape
-    B = rows.shape[0]
-    rows = rows.astype(jnp.int32)
+    whole tiles: :func:`write_blocks` with every slot's row a piece of
+    its own. There, slots that name one row are applied in slot order."""
     if layout == "rows":
-        at = jnp.where(active, rows, R)
+        at = jnp.where(active, rows.astype(jnp.int32), k_pool.shape[1])
         return tuple(pool.at[:, at].set(new.astype(pool.dtype), mode="drop")
                      for pool, new in ((k_pool, k_new), (v_pool, v_new)))
-    # inactive slots sort behind every group and open no step
-    group = jnp.where(active, rows // GROUP_ROWS, R)
-    order = jnp.argsort(group, stable=True).astype(jnp.int32)
-    sorted_group = group[order]
-    opens = jnp.concatenate([jnp.ones((1,), bool),
-                             sorted_group[1:] != sorted_group[:-1]])
-    opens &= sorted_group < R
-    step = jnp.cumsum(opens, dtype=jnp.int32) - 1                  # (B,)
-    n_steps = jnp.sum(opens, dtype=jnp.int32)
-    dropped = B + 1
-    step_group = jnp.zeros((B,), jnp.int32).at[
-        jnp.where(opens, step, dropped)].set(sorted_group, mode="drop")
-    n_active = jnp.sum(active, dtype=jnp.int32)
-    start = jnp.full((B + 1,), n_active, jnp.int32).at[
-        jnp.where(opens, step, dropped)].set(
-            jnp.arange(B, dtype=jnp.int32), mode="drop")
+    return write_blocks(k_pool, v_pool, k_new, v_new,
+                        write_plan(rows, active), interpret=interpret)
 
-    def pool_map(l, s, rows_r, order_r, start_r, group_r):
-        return (l, 0, 0, group_r[s])
 
-    def new_map(l, s, *_):
-        return (l, 0, 0, 0)
+def write_plan(rows, active) -> dict:
+    """The piece list of :func:`write_blocks`, shared by every layer.
 
+    ``rows`` (N,) int32 pool rows and ``active`` (N,) which of them are
+    written (entries that name one row are applied in their order: the
+    sort is stable). A *piece* is a maximal stretch
+    of consecutive active entries whose rows are consecutive too, that
+    stays inside one 128-entry tile of the new rows and one 128-row group
+    of the pool: the kernel moves it with one lane rotation. A prompt's
+    block is one piece, and blocks the allocator handed out in a row are
+    one. Returns int32 arrays, per piece (``N`` long, sorted by group, so
+    that the pieces of one group are neighbours): ``src`` its first
+    entry, ``dst`` its first row, ``n`` its length; and ``n_pieces``
+    ``(1,)``, the grid's size."""
+    N = rows.shape[0]
+    rows = rows.astype(jnp.int32)
+    i = jnp.arange(N, dtype=jnp.int32)
+    follows = jnp.concatenate([jnp.zeros((1,), bool),
+                               active[:-1] & (rows[1:] == rows[:-1] + 1)])
+    opens = active & ~(follows & (i % GROUP_ROWS != 0)
+                       & (rows % GROUP_ROWS != 0))
+    piece = jnp.cumsum(opens, dtype=jnp.int32) - 1                 # (N,)
+    n_pieces = jnp.sum(opens, dtype=jnp.int32)
+    zeros = jnp.zeros((N,), jnp.int32)
+    at = jnp.where(opens, piece, N)                                # N: dropped
+    src = zeros.at[at].set(i, mode="drop")
+    dst = zeros.at[at].set(rows, mode="drop")
+    n = zeros.at[jnp.where(active, piece, N)].add(1, mode="drop")
+    # unused entries sort behind every group
+    order = jnp.argsort(jnp.where(i < n_pieces, dst // GROUP_ROWS,
+                                  jnp.iinfo(jnp.int32).max), stable=True)
+    return {"src": src[order], "dst": dst[order], "n": n[order],
+            "n_pieces": n_pieces[None]}
+
+
+def _write_blocks_kernel(layer_ref, src_ref, dst_ref, n_ref,  # prefetch
+                         kn_ref, vn_ref, k_ref, v_ref,        # inputs
+                         ko_ref, vo_ref):                     # outputs
+    """One piece: the 128-entry tile of new rows that holds it, rotated
+    so that its lanes lie over its rows' lanes in their 128-row group.
+    The group stays in VMEM while the pieces that follow name it."""
+    del layer_ref                                # the index maps read it
+    s = pl.program_id(1)
+    dst = dst_ref[s]
+    before = dst_ref[jnp.maximum(s - 1, 0)]
+
+    @pl.when((s == 0) | (dst // GROUP_ROWS != before // GROUP_ROWS))
+    def _():
+        ko_ref[...] = k_ref[...]
+        vo_ref[...] = v_ref[...]
+
+    lo = dst % GROUP_ROWS
+    shift = (lo - src_ref[s] % GROUP_ROWS + GROUP_ROWS) % GROUP_ROWS
+    # Mosaic rotates 32-bit lanes only: narrower rows go as the words
+    # their sublanes pack into (a lane's rotation moves whole sublanes)
+    dtype = ko_ref.dtype
+    words = jnp.uint32 if dtype.itemsize < 4 else dtype
+    H, hd, _ = ko_ref.shape
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (H, hd * dtype.itemsize // 4, GROUP_ROWS), 2)
+    here = (lane >= lo) & (lane < lo + n_ref[s])
+    for new_ref, out_ref in ((kn_ref, ko_ref), (vn_ref, vo_ref)):
+        moved = pltpu.roll(pltpu.bitcast(new_ref[...], words), shift, 2)
+        out_ref[...] = pltpu.bitcast(
+            jnp.where(here, moved, pltpu.bitcast(out_ref[...], words)),
+            dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _write_pieces(kt, vt, kn, vn, layer, plan, *, interpret: bool):
+    """The kernel call of :func:`write_blocks` on the pools' views ``(L,
+    H, hd, R)`` and the new rows' ``(Ln, H, hd, N)``, ``N`` a multiple of
+    128. Jitted so that a program which calls it once per layer lowers
+    the kernel once, as :func:`_pool_attention`."""
+    _, H, hd, _ = kt.shape
+
+    def new_map(l, s, layer_r, src_r, *_):
+        return (l, 0, 0, src_r[s] // GROUP_ROWS)
+
+    def pool_map(l, s, layer_r, src_r, dst_r, *_):
+        return (layer_r[0] + l, 0, 0, dst_r[s] // GROUP_ROWS)
+
+    new_spec = pl.BlockSpec((None, H, hd, GROUP_ROWS), new_map)
     pool_spec = pl.BlockSpec((None, H, hd, GROUP_ROWS), pool_map)
-    new_spec = pl.BlockSpec((None, B, hd, H), new_map)
-    view = (0, 2, 3, 1)            # (L, R, H, hd) -> (L, H, hd, R): a bitcast
-    kt, vt = (jnp.transpose(a, view) for a in (k_pool, v_pool))
-    kn, vn = (jnp.transpose(a.astype(k_pool.dtype), (0, 1, 3, 2))
-              for a in (k_new, v_new))                      # (L, B, hd, H)
-    kt, vt = pl.pallas_call(
-        functools.partial(_write_kernel, n_heads=H),
+    return pl.pallas_call(
+        _write_blocks_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(L, n_steps),
+            grid=(kn.shape[0], plan["n_pieces"][0]),
             in_specs=[new_spec, new_spec, pool_spec, pool_spec],
             out_specs=[pool_spec, pool_spec],
         ),
@@ -559,6 +589,34 @@ def write_rows(k_pool, v_pool, k_new, v_new, rows, active, *,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_kv_write",
-    )(rows, order, start, step_group, kn, vn, kt, vt)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan["src"], plan["dst"],
+      plan["n"], kn, vn, kt, vt)
+
+
+def write_blocks(k_pool, v_pool, k_new, v_new, plan, layer=0, *,
+                 interpret: bool = False):
+    """Both ``"lanes"`` pools (L, R, H, hd) with the rows ``plan`` =
+    :func:`write_plan` ``(rows, active)`` names set, in the cache layers
+    ``layer .. layer + Ln - 1``, to ``k_new[:, i]`` / ``v_new[:, i]``
+    (``(Ln, N, H, hd)``, cast to the pool's type and nothing else), in
+    place when the pools are donated; every other row stays bit for bit.
+
+    A row is one lane of 64 tiles and a DMA moves whole tiles, so the
+    kernel reads a 128-row group, sets the lanes and writes the group
+    back (``input_output_aliases``: no other group is touched). The
+    admission programs write whole blocks, 16 neighbouring lanes of a
+    group each; the decode step one row a slot. One grid step per layer
+    and piece, the pieces sorted by group: the first of a group reads
+    it, the last leaves it to be written back, so no two steps in flight
+    hold the same rows and the pipeline may fetch ahead and write
+    behind."""
+    N = k_new.shape[1]
+    pad = -N % GROUP_ROWS
+    view = (0, 2, 3, 1)     # rows on the lanes; of the pools a bitcast
+    kt, vt = (jnp.transpose(a, view) for a in (k_pool, v_pool))
+    kn, vn = (jnp.pad(jnp.transpose(a.astype(k_pool.dtype), view),
+                      ((0, 0), (0, 0), (0, 0), (0, pad)))
+              for a in (k_new, v_new))
+    kt, vt = _write_pieces(kt, vt, kn, vn, layer, plan, interpret=interpret)
     back = (0, 3, 1, 2)
     return jnp.transpose(kt, back), jnp.transpose(vt, back)

@@ -9,7 +9,11 @@ tree (stacked-layers layout) and the block-allocated KV pool
   ``models/transformer.TransformerLM``, masked by the factored
   ``ops.attention.length_valid_mask`` rule), writing every position's
   rotary-embedded K and V into the sequences' cache blocks and
-  returning each prompt's last-position logits.
+  returning each prompt's last-position logits. The write is a scatter
+  (plain jnp) or, where the device keeps the pool with its rows on the
+  lanes, one in-place kernel call over the written blocks
+  (``paged_attention.write_blocks``): a scatter into that layout copies
+  the whole pool twice.
 - :func:`make_decode_fn` — ONE token per running slot: project q/k/v
   for the new token, put k/v into the slot's current block, and attend
   the single query against the slot's keys. Two paths, one contract:
@@ -40,11 +44,13 @@ quantize on the way in, gathers dequantize on the way out, so the whole
 quantisation story lives in :func:`_pool_write` / :func:`_pool_window`
 and the attention math never sees anything but the compute dtype.
 
-Everything but the paged decode path is plain jnp (no Pallas custom
-calls), so on a serving mesh GSPMD partitions the programs directly:
-slots over ``dp``, heads/mlp/vocab over ``tp`` (:func:`param_shardings`),
-the pool laid out by ``kv_cache.pool_shardings``. GSPMD cannot partition
-a ``pallas_call``, so an engine on a mesh asks for the window path.
+Everything but the paged decode path and the block write of prefill
+and extend is plain jnp (no Pallas custom calls), so on a serving mesh
+GSPMD partitions the programs directly: slots over ``dp``,
+heads/mlp/vocab over ``tp`` (:func:`param_shardings`), the pool laid out
+by ``kv_cache.pool_shardings``. GSPMD cannot partition a
+``pallas_call``, so an engine on a mesh asks for the plain paths
+(``"window"``, ``"scatter"``).
 """
 
 from __future__ import annotations
@@ -379,16 +385,80 @@ def model_forward(cfg: TransformerConfig, params, tokens, lengths=None,
     return logits
 
 
-def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None):
+def _kernel_path(implementation, plain: str, cache_cfg):
+    """``(implementation, layout)``: ``implementation`` checked against
+    the pool (``plain``, the jnp path's name, or "paged" / "interpret":
+    the kernels, compiled or interpreted; ``None`` is "paged" where a
+    kernel reads the pool and the backend is a TPU, else ``plain``) and
+    the layout the kernels of ``ops/paged_attention.py`` read this pool
+    in (``"lanes"`` / ``"rows"``), or a false value."""
+    layout = cache_cfg is not None and paged_attention.supported(
+        cache_cfg.num_blocks * cache_cfg.block_size, cache_cfg.block_size,
+        cache_cfg.head_dim, cache_cfg.dtype, cache_cfg.n_heads)
+    if implementation is None:
+        implementation = ("paged" if layout
+                          and jax.default_backend() == "tpu" else plain)
+    if implementation not in (plain, "paged", "interpret"):
+        raise ValueError(f"implementation={implementation!r}; expected "
+                         f"{plain!r}, 'paged', 'interpret' or None")
+    if implementation != plain and not layout:
+        raise ValueError(f"no kernel reads this pool ({cache_cfg}): see "
+                         f"ops.paged_attention.supported")
+    return implementation, layout
+
+
+def _block_writer(implementation, cache_cfg):
+    """``write(pool, k, v, plan, layer=0)`` for the admission programs,
+    or None where they scatter: the pool with the rows ``plan`` names
+    (``paged_attention.write_plan``) of cache layers ``layer ..`` set to
+    ``k`` / ``v`` (``(Ln, N, H, hd)``), in place and with no array of
+    the pool's size beside it. Only the ``"lanes"`` layout needs it (a
+    scatter relayouts that whole pool on the way in and out, PERF.md
+    section 6, PR 27); XLA's scatter writes a row-major pool where it
+    lies."""
+    implementation, layout = _kernel_path(implementation, "scatter",
+                                          cache_cfg)
+    if implementation == "scatter" or layout != "lanes":
+        return None
+
+    def write(pool, k, v, plan, layer=0):
+        pool = dict(pool)
+        with jax.named_scope("kv.write"):
+            pool["k"], pool["v"] = paged_attention.write_blocks(
+                pool["k"], pool["v"], k, v, plan, layer,
+                interpret=implementation == "interpret")
+        return pool
+
+    return write
+
+
+def _write_plan(rows, cache_cfg):
+    """The block writer's plan for an admission's flat write ``rows``:
+    every row but the trash block's, where padded positions point."""
+    with jax.named_scope("kv.write"):
+        return paged_attention.write_plan(rows, rows >= cache_cfg.block_size)
+
+
+def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None, *,
+                    implementation: str | None = None):
     """``prefill(params, pool, tokens, lengths, write_rows)``
-    → ``(last_logits, pool)``.
+    → ``(last_logits, pool)``; ``prefill.kv_write`` says how the rows
+    reach the pool.
 
     ``tokens`` (B, S) right-padded prompts, ``lengths`` (B,) true
     lengths, ``write_rows`` (B, S) flat pool rows per position (padded
     positions point at the trash block). ``last_logits`` (B, vocab) are
     the logits at each prompt's final REAL position — the first
-    generated token's distribution."""
+    generated token's distribution.
+
+    implementation: "scatter" | "paged" | "interpret" | None (auto, as
+    :func:`make_decode_fn` decides; an engine on a mesh asks for
+    "scatter"). ``kv_write == "paged"``: one in-place kernel call after
+    the forward writes every cache layer's rows by blocks
+    (``paged_attention.write_blocks``); the trash block is not written.
+    ``"scatter"``: ``_pool_write``, also where the pool is row-major."""
     quantized = cache_cfg.quantized if cache_cfg is not None else False
+    write = _block_writer(implementation, cache_cfg)
 
     def prefill(params, pool, tokens, lengths, write_rows):
         B, S = tokens.shape
@@ -398,7 +468,9 @@ def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None):
         rows = write_rows.reshape(-1)                       # (B*S,)
         flat_k = ks.transpose(0, 1, 3, 2, 4).reshape(L, B * S, H, hd)
         flat_v = vs.transpose(0, 1, 3, 2, 4).reshape(L, B * S, H, hd)
-        if cfg.passes == 1:
+        if write is not None:
+            pool = write(pool, flat_k, flat_v, _write_plan(rows, cache_cfg))
+        elif cfg.passes == 1:
             for l in range(L):
                 pool = _pool_write(pool, l, rows, flat_k[l], flat_v[l],
                                    quantized)
@@ -410,6 +482,7 @@ def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None):
         last = logits[jnp.arange(B), jnp.maximum(lengths, 1) - 1]
         return last, pool
 
+    prefill.kv_write = "scatter" if write is None else "paged"
     prefill.passes = cfg.passes
     return prefill
 
@@ -448,19 +521,9 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None, *,
                          "serve bidirectional (BERT) configs through the "
                          "prefill/scoring path")
     quantized = cache_cfg.quantized if cache_cfg is not None else False
-    layout = cache_cfg is not None and paged_attention.supported(
-        cache_cfg.num_blocks * cache_cfg.block_size, cache_cfg.block_size,
-        cache_cfg.head_dim, cache_cfg.dtype, cache_cfg.n_heads)
-    if implementation is None:
-        implementation = ("paged" if layout
-                          and jax.default_backend() == "tpu" else "window")
-    if implementation not in ("window", "paged", "interpret"):
-        raise ValueError(f"implementation={implementation!r}; expected "
-                         f"'window', 'paged', 'interpret' or None")
+    implementation, layout = _kernel_path(implementation, "window",
+                                          cache_cfg)
     paged = implementation != "window"
-    if paged and not layout:
-        raise ValueError(f"the paged decode path cannot read this pool "
-                         f"({cache_cfg}): see ops.paged_attention.supported")
     interpret = implementation == "interpret"
 
     def decode(params, pool, tokens, positions, lengths, write_rows, table):
@@ -581,10 +644,13 @@ def make_multi_decode_fn(step, steps: int):
     return decode
 
 
-def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
+def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
+                   implementation: str | None = None):
     """``extend(params, pool, tokens, positions, lengths, write_rows,
     window_rows)`` → ``(logits, pool)`` — E tokens per slot in one
-    cache-aware forward.
+    cache-aware forward; ``extend.kv_write`` and ``implementation`` as
+    :func:`make_prefill_fn`'s (the write of every layer goes that way;
+    the read is the window gather on either).
 
     ``tokens`` (B, E) the new tokens (right-padded), ``positions``
     (B, E) their ABSOLUTE cache positions (padded entries must point at
@@ -609,6 +675,7 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
                          "bidirectional (BERT) configs through the "
                          "prefill/scoring path")
     quantized = cache_cfg.quantized if cache_cfg is not None else False
+    write = _block_writer(implementation, cache_cfg)
 
     def extend(params, pool, tokens, positions, lengths, write_rows,
                window_rows):
@@ -616,6 +683,8 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
         B, E = tokens.shape
         x = params["embed"].astype(dt)[tokens]          # (B, E, D)
         rows = write_rows.reshape(-1)                   # (B*E,)
+        if write is not None:
+            plan = _write_plan(rows, cache_cfg)         # every layer's
 
         def layer(x, pool, p, cl):
             h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
@@ -630,7 +699,11 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
                                                      k.shape[3])
             flat_v = v.transpose(0, 2, 1, 3).reshape(B * E, v.shape[1],
                                                      v.shape[3])
-            pool = _pool_write(pool, cl, rows, flat_k, flat_v, quantized)
+            if write is not None:
+                pool = write(pool, flat_k[None], flat_v[None], plan, cl)
+            else:
+                pool = _pool_write(pool, cl, rows, flat_k, flat_v,
+                                   quantized)
             kw, vw = _pool_window(pool, cl, window_rows, dt, quantized)
             with jax.named_scope("attn"):
                 o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
@@ -642,6 +715,7 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None):
         x, pool, _ = _run_stack(cfg, params, x, pool, layer)
         return _logits(cfg, params, x), pool
 
+    extend.kv_write = "scatter" if write is None else "paged"
     return extend
 
 

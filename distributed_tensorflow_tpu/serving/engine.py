@@ -283,9 +283,11 @@ class InferenceEngine:
         self._pending_swap = None
         self.pool = init_pool(cache_cfg, mesh)
 
-        prefill = decode_lib.make_prefill_fn(cfg, cache_cfg)
-        # GSPMD cannot partition the paged path's pallas_call: a mesh
-        # keeps the window path; otherwise make_decode_fn chooses
+        # GSPMD cannot partition a pallas_call: a mesh keeps the plain
+        # paths (scatter, window); otherwise the builders choose
+        admit_impl = "scatter" if mesh is not None else None
+        prefill = decode_lib.make_prefill_fn(cfg, cache_cfg,
+                                             implementation=admit_impl)
         decode = (decode_lib.make_decode_fn(
             cfg, cache_cfg,
             implementation="window" if mesh is not None else None)
@@ -302,8 +304,14 @@ class InferenceEngine:
         self.decode_passes = decode.passes if decode is not None else None
         #: the layout the paged kernel reads the pool in (what a run is)
         self._kv_layout = decode.kv_layout if decode is not None else None
-        extend = (decode_lib.make_extend_fn(cfg, cache_cfg)
+        extend = (decode_lib.make_extend_fn(cfg, cache_cfg,
+                                            implementation=admit_impl)
                   if cfg.causal else None)
+        #: how each admission program's rows reach the pool ("paged":
+        #: by blocks, in place / "scatter"), by ``serve.prefill``'s
+        #: ``program``
+        self.kv_write = {"prefill": prefill.kv_write,
+                         "extend": extend.kv_write if extend else None}
         copy_fn = decode_lib.make_copy_fn()
 
         def gather_fn(pool, rows):
@@ -794,6 +802,8 @@ class InferenceEngine:
                       if submit_mono is not None else None)
         C = seq.cached_tokens
         S = seq.prompt_len - C                      # suffix to compute
+        bs = self.cache_cfg.block_size
+        program = "extend" if C else "prefill"
         E = (min(self.max_seq_len, 1 << max(3, (S - 1).bit_length()))
              if C else self.max_seq_len)            # program's width
         with telemetry.span(
@@ -804,7 +814,9 @@ class InferenceEngine:
                 queue_wait_s=(round(queue_wait, 6)
                               if queue_wait is not None else None),
                 replayed=len(seq.request.generated_prefix) or None,
-                program="extend" if C else "prefill",
+                program=program, kv_write=self.kv_write[program],
+                blocks_written=((seq.prompt_len - 1) // bs - C // bs + 1
+                                if C else len(seq.table.blocks)),
                 passes=self.prefill_passes):
             lengths = np.asarray([seq.prompt_len], np.int32)
             if C:

@@ -1,5 +1,7 @@
-"""The paged decode path: ``ops/paged_attention.py`` and the decode
-program ``serving/decode.make_decode_fn`` builds from it.
+"""The paged paths: ``ops/paged_attention.py``, the decode program
+``serving/decode.make_decode_fn`` builds from it, and the admission
+programs (``make_prefill_fn``, ``make_extend_fn``) that write the pool
+by blocks.
 
 On the CPU the kernels run in interpret mode. The contract is the window
 path's: the same keys attended with the same float32 arithmetic
@@ -29,6 +31,9 @@ L, NB, BS, H, HD = 2, 32, 16, 4, 16          # pool: 512 rows, 4 groups
 MAX_BLOCKS = 8                                # window: 128 positions
 TOL = {jnp.float32: dict(rtol=1e-5, atol=2e-6),
        jnp.bfloat16: dict(rtol=2 ** -7, atol=1e-6)}   # one bf16 ulp
+#: this file builds engines and compiles whole serving programs: what
+#: they leave on the heap slows a later file's garbage collection
+pytestmark = pytest.mark.usefixtures("leave_no_programs_behind")
 
 
 def _pool(rng, dtype):
@@ -198,6 +203,91 @@ def test_write_applies_one_row_in_slot_order():
     np.testing.assert_array_equal(k[:, 77], new[:, 1])
 
 
+def _position_rows(tables, n):
+    """``BlockTable.rows(arange(n))`` for each table: positions past a
+    table's blocks point into the trash block."""
+    rows = np.zeros((len(tables), n), np.int32)
+    for b, blocks in enumerate(tables):
+        blocks = list(blocks) + [TRASH_BLOCK] * (-(-n // BS) - len(blocks))
+        rows[b] = [blocks[p // BS] * BS + p % BS for p in range(n)]
+    return rows
+
+
+#: what an admission hands the block writer: (rows (B, n), first cache
+#: layer, cache layers written). Four groups of eight blocks; block 0 is
+#: the trash block.
+BLOCK_WRITES = {
+    "table_order": (_position_rows([[8, 9, 10, 11]], 64), 0, L),
+    "reversed": (_position_rows([[11, 10, 9, 8]], 64), 0, L),
+    "over_groups": (_position_rows([[3, 20, 12, 29]], 64), 0, L),
+    # two sequences of a batch whose blocks interleave in two groups
+    "two_share_groups": (_position_rows([[8, 17, 10], [9, 16, 11]], 48),
+                         0, L),
+    # one and two blocks of a 64-wide prefill: the padded positions name
+    # the trash block, which lies in group 0 beside blocks 2, 5 and 6
+    "padded_beside_trash": (_position_rows([[2], [5, 6]], 64), 0, L),
+    # extend: spans that start inside a block and run on into the next
+    # (neighbouring or not), padded with the trash ROW, one layer a call
+    "mid_block_one_layer": (np.array(
+        [[8 * BS + 5 + i for i in range(11)]
+         + [20 * BS + i for i in range(9)] + [0] * 4,
+         [9 * BS + 12 + i for i in range(22)] + [0] * 2], np.int32), 1, 1),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(BLOCK_WRITES))
+def test_block_write_touches_its_rows_and_no_other(case, dtype):
+    """``write_blocks`` sets every named row outside the trash block, in
+    the layers it is given, to the new row cast to the pool's type;
+    every other row of every layer (the trash block and blocks 1-7 of
+    its group among them) stays bit for bit."""
+    rows, layer, n_layers = BLOCK_WRITES[case]
+    rng = np.random.default_rng(5)
+    pool = _pool(rng, dtype)
+    flat = rows.reshape(-1)
+    active = flat >= BS
+    k_new, v_new = (jnp.asarray(rng.standard_normal(
+        (n_layers, flat.size, H, HD)), jnp.float32) for _ in range(2))
+    plan = pa.write_plan(jnp.asarray(flat), jnp.asarray(active))
+    # a piece never crosses a group of the pool or a tile of the new rows
+    n = int(plan["n_pieces"][0])
+    src, dst, count = (np.asarray(plan[x])[:n] for x in ("src", "dst", "n"))
+    assert count.sum() == active.sum() and np.all(count > 0)
+    assert np.all(dst // 128 == (dst + count - 1) // 128)
+    assert np.all(src // 128 == (src + count - 1) // 128)
+    assert np.all(np.diff(dst // 128) >= 0)
+    k, v = pa.write_blocks(pool["k"], pool["v"], k_new, v_new, plan, layer,
+                           interpret=True)
+    for got, old, new in ((k, pool["k"], k_new), (v, pool["v"], v_new)):
+        assert got.dtype == old.dtype
+        want = np.array(old, np.float32)
+        want[layer:layer + n_layers, flat[active]] = np.asarray(
+            new.astype(dtype), np.float32)[:, active]
+        np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+        assert np.any(want != np.asarray(old, np.float32))
+        # a write of float32 rows is a cast and nothing else: the bits
+        np.testing.assert_array_equal(
+            np.asarray(got[layer:layer + n_layers, flat[active]]),
+            np.asarray(new.astype(dtype)[:, active]))
+
+
+def test_block_write_with_nothing_to_write():
+    rng = np.random.default_rng(6)
+    pool = _pool(rng, jnp.bfloat16)
+    rows = jnp.zeros((32,), jnp.int32)
+    new = jnp.ones((L, 32, H, HD), jnp.bfloat16)
+    plan = pa.write_plan(rows, rows >= BS)
+    assert int(plan["n_pieces"][0]) == 0
+    k, v = pa.write_blocks(pool["k"], pool["v"], new, new, plan,
+                           interpret=True)
+    np.testing.assert_array_equal(np.asarray(k, np.float32),
+                                  np.asarray(pool["k"], np.float32))
+    np.testing.assert_array_equal(np.asarray(v, np.float32),
+                                  np.asarray(pool["v"], np.float32))
+
+
 @pytest.fixture(scope="module")
 def tiny():
     cfg = TransformerConfig.tiny(max_seq_len=64)
@@ -272,13 +362,87 @@ def test_decode_program_matches_window_path(tiny, kv_dtype):
         np.testing.assert_array_equal(w[:, kept[8:]], start[n][:, kept[8:]])
 
 
-def _engine(cfg, params, impl, monkeypatch, **kw):
-    """An engine whose decode program is built with ``implementation``:
-    the engine itself passes none, so the test steers the builder."""
-    real = decode_lib.make_decode_fn
-    monkeypatch.setattr(
-        engine_lib.decode_lib, "make_decode_fn",
-        lambda c, cc, implementation=None: real(c, cc, implementation=impl))
+def _admissions(cc):
+    """Two prompts that share the pool's first group, as a (2, 32)
+    prefill batch, and a span each to extend them by (one starts inside
+    a block). Returns both programs' arguments after params and pool."""
+    prompts = [[5, 9, 2, 7, 1, 3, 8, 4, 6, 2, 9], list(range(3, 23))]
+    spans = [[7, 11, 13, 2, 5], [4] * 8]
+    alloc = BlockAllocator(cc.num_blocks)
+    tables = [BlockTable(cc, max_blocks=8) for _ in prompts]
+    for t, p, s in zip(tables, prompts, spans):
+        t.ensure_room(len(p) + len(s) + 1, alloc)
+    S, E = 32, 8
+    toks = np.zeros((2, S), np.int32)
+    ext = np.zeros((2, E), np.int32)
+    pos = np.full((2, E), 64, np.int32)               # pad -> masked query
+    rows = np.zeros((2, E), np.int32)                 # pad -> trash row
+    for b, (t, p, s) in enumerate(zip(tables, prompts, spans)):
+        toks[b, :len(p)] = p
+        ext[b, :len(s)] = s
+        pos[b, :len(s)] = np.arange(len(p), len(p) + len(s))
+        rows[b, :len(s)] = t.rows(pos[b, :len(s)])
+    lengths = np.array([len(p) for p in prompts], np.int32)
+    prefill_args = (jnp.asarray(toks), jnp.asarray(lengths), jnp.asarray(
+        np.stack([t.rows(np.arange(S)) for t in tables])))
+    extend_args = (jnp.asarray(ext), jnp.asarray(pos),
+                   jnp.asarray(lengths + [len(s) for s in spans]),
+                   jnp.asarray(rows),
+                   jnp.asarray(np.stack([t.window_rows() for t in tables])))
+    return prefill_args, extend_args
+
+
+@pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
+def test_admission_programs_match_the_scatter_path(tiny, kv_dtype):
+    """``make_prefill_fn`` and ``make_extend_fn`` both ways over one
+    pool: the same logits, and the same pool on every row outside the
+    trash block, bit for bit (the rows are a cast of the same K and V;
+    only what lands in the trash block differs: the kernel skips it)."""
+    cfg, params = tiny
+    params = decode_lib.canonical_params(cfg, params)
+    cc = CacheConfig.for_model(cfg, num_blocks=32, block_size=8,
+                               kv_dtype=kv_dtype)
+    prefill_args, extend_args = _admissions(cc)
+    start = {n: a + 1 for n, a in init_pool(cc).items()}
+    outs = {}
+    for impl in ("scatter", "interpret"):
+        prefill = decode_lib.make_prefill_fn(cfg, cc, implementation=impl)
+        extend = decode_lib.make_extend_fn(cfg, cc, implementation=impl)
+        want = "scatter" if impl == "scatter" else "paged"
+        assert (prefill.kv_write, extend.kv_write) == (want, want)
+        last, pool = jax.jit(prefill)(params, dict(start), *prefill_args)
+        logits, after = jax.jit(extend)(params, pool, *extend_args)
+        outs[impl] = (np.asarray(last), np.asarray(logits), pool, after)
+    plain, paged = outs["scatter"], outs["interpret"]
+    np.testing.assert_array_equal(paged[0], plain[0])
+    np.testing.assert_array_equal(paged[1][:, :5], plain[1][:, :5])
+    live = cc.block_size                           # rows past the trash block
+    for a, b in ((paged[2], plain[2]), (paged[3], plain[3])):
+        for n in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(a[n], np.float32)[:, live:],
+                np.asarray(b[n], np.float32)[:, live:])
+            assert np.any(np.asarray(a[n], np.float32)[:, live:]
+                          != np.asarray(start[n], np.float32)[:, live:])
+    # the kernel leaves the trash block as it was
+    np.testing.assert_array_equal(
+        np.asarray(paged[3]["k"], np.float32)[:, :live],
+        np.asarray(start["k"], np.float32)[:, :live])
+
+
+def _engine(cfg, params, impl, monkeypatch, admit=None, **kw):
+    """An engine whose decode program is built with ``implementation``
+    ``impl``, and its prefill and extend programs with ``admit`` if
+    given: the engine itself passes none, so the test steers the
+    builders."""
+    steer = {"make_decode_fn": impl}
+    if admit is not None:
+        steer.update(make_prefill_fn=admit, make_extend_fn=admit)
+    for name, how in steer.items():
+        monkeypatch.setattr(
+            engine_lib.decode_lib, name,
+            lambda c, cc, implementation=None, how=how,
+            real=getattr(decode_lib, name): real(c, cc, implementation=how))
     engine = InferenceEngine(cfg, params, **kw)
     monkeypatch.undo()
     return engine
@@ -321,11 +485,63 @@ def test_engine_tokens_equal_on_both_paths(tiny, scenario, monkeypatch):
         assert engines["interpret"].stats()["prefix_cache"]["hit_tokens"] > 0
 
 
+@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+def test_engine_tokens_equal_on_both_write_paths(tiny, scenario,
+                                                 monkeypatch):
+    """A cold prompt, a prefix hit (extend) and a preempted replay
+    through admission programs that scatter and that write by blocks:
+    the rows are the same bits, so the tokens are the same."""
+    cfg, params = tiny
+    spec = SCENARIOS[scenario]
+    outs, engines = {}, {}
+    for admit in ("scatter", "interpret"):
+        e = engines[admit] = _engine(cfg, params, "window", monkeypatch,
+                                     admit=admit, **spec["engine"])
+        outs[admit] = e.generate(spec["prompts"], max_new_tokens=32)
+    assert engines["scatter"].kv_write == {"prefill": "scatter",
+                                           "extend": "scatter"}
+    assert engines["interpret"].kv_write == {"prefill": "paged",
+                                             "extend": "paged"}
+    assert outs["interpret"] == outs["scatter"]
+    e = engines["interpret"]
+    acct = e.block_accounting()
+    assert acct["conserved"] and acct["leaked_refs"] == 0
+    if scenario == "preemption":
+        assert e.scheduler.preemptions > 0
+    if scenario == "prefix_hit":
+        assert e.stats()["prefix_cache"]["hit_tokens"] > 0
+
+
 def test_engine_on_the_cpu_takes_the_window_path(tiny):
     cfg, params = tiny
     e = InferenceEngine(cfg, params, num_blocks=32, block_size=8,
                         max_slots=2)
     assert e.kv_path == "window"
+
+
+@pytest.mark.parametrize("where,paths", [
+    ("cpu", ("window", "scatter")),
+    ("tpu", ("paged", "paged")),
+    ("tpu, int8 pool", ("window", "scatter")),
+    ("tpu, mesh", ("window", "scatter")),
+])
+def test_engine_chooses_its_paths_by_what_it_sees(tiny, where, paths,
+                                                  monkeypatch, request):
+    """The kernels' paths only where the backend is a TPU (the builders
+    ask ``jax.default_backend``; answered for them here), the pool is
+    one a kernel reads, and no mesh would have to partition a
+    ``pallas_call``. Built, not run."""
+    cfg, params = tiny
+    kw = dict(num_blocks=32, block_size=8, max_slots=4)
+    if "tpu" in where:
+        monkeypatch.setattr(decode_lib.jax, "default_backend", lambda: "tpu")
+    if "int8" in where:
+        kw["kv_dtype"] = "int8"
+    if "mesh" in where:
+        kw["mesh"] = request.getfixturevalue("mesh2d")
+    e = InferenceEngine(cfg, params, **kw)
+    assert (e.kv_path, e.kv_write["prefill"], e.kv_write["extend"]) == (
+        paths[0], paths[1], paths[1])
 
 
 @pytest.mark.parametrize("why,cache,impl", [
@@ -342,6 +558,10 @@ def test_implementation_argument_is_checked(tiny, why, cache, impl):
     cc = CacheConfig.for_model(cfg, **cache)
     with pytest.raises(ValueError):
         decode_lib.make_decode_fn(cfg, cc, implementation=impl)
+    for make in (decode_lib.make_prefill_fn, decode_lib.make_extend_fn):
+        with pytest.raises(ValueError):
+            make(cfg, cc, implementation=impl)
+        assert make(cfg, cc).kv_write == "scatter"
     # and left alone, such a pool quietly takes the window path
     assert decode_lib.make_decode_fn(cfg, cc).kv_path == "window"
 
@@ -376,25 +596,18 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("impl,clean", [("paged", True), ("window", False)])
-def test_compiled_decode_holds_nothing_of_the_pools_size(
-        one_chip, no_compile_cache, impl, clean):
-    """transformer-big at the benchmark's serving shapes, compiled for
-    one v5e chip: the paged program produces no array of the pool's, a
-    layer's or the 64 x 1024-row window's size (``chip_smoke``'s check,
-    which the window program fails 200-fold), and the Mosaic kernels
-    compile at these widths."""
+def _tbig_serving_specs(one_chip):
+    """transformer-big at the benchmark's serving shapes, described and
+    not allocated: ``(cfg, cache config, spec(shape, dtype=int32),
+    params, pool)`` with every array on ``one_chip``."""
     import dataclasses
-
-    import chip_smoke
 
     cfg = TransformerConfig.transformer_big(max_seq_len=1024,
                                             scan_layers=False)
     cc = CacheConfig.for_model(cfg, num_blocks=4096, block_size=16,
                                dtype=jnp.bfloat16)
-    slots, window = 64, 1024
 
-    def spec(shape, dtype):
+    def spec(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     model = TransformerLM(dataclasses.replace(cfg, scan_layers=True))
@@ -403,13 +616,28 @@ def test_compiled_decode_holds_nothing_of_the_pools_size(
         jax.random.PRNGKey(0))
     params = jax.tree_util.tree_map(
         lambda a: spec(a.shape, a.dtype), decode_lib._plain(shapes["params"]))
+    pool = {n: spec((cc.n_layers, cc.num_blocks * cc.block_size, cc.n_heads,
+                     cc.head_dim), cc.dtype) for n in ("k", "v")}
+    return cfg, cc, spec, params, pool
+
+
+@pytest.mark.parametrize("impl,clean", [("paged", True), ("window", False)])
+def test_compiled_decode_holds_nothing_of_the_pools_size(
+        one_chip, no_compile_cache, impl, clean):
+    """transformer-big at the benchmark's serving shapes, compiled for
+    one v5e chip: the paged program produces no array of the pool's, a
+    layer's or the 64 x 1024-row window's size (``chip_smoke``'s check,
+    which the window program fails 200-fold), and the Mosaic kernels
+    compile at these widths."""
+    import chip_smoke
+
+    cfg, cc, spec, params, pool = _tbig_serving_specs(one_chip)
+    slots, window = 64, 1024
     rows = cc.num_blocks * cc.block_size
     row = cc.n_heads * cc.head_dim
-    pool = {n: spec((cc.n_layers, rows, cc.n_heads, cc.head_dim), cc.dtype)
-            for n in ("k", "v")}
-    vec = spec((slots,), jnp.int32)
+    vec = spec((slots,))
     table = spec((slots, window // cc.block_size if impl == "paged"
-                  else window), jnp.int32)
+                  else window))
     fn = decode_lib.make_decode_fn(cfg, cc, implementation=impl)
     hlo = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, vec, vec, vec, vec, table).compile().as_text()
@@ -417,6 +645,46 @@ def test_compiled_decode_holds_nothing_of_the_pools_size(
         hlo, {cc.n_layers * rows * row, rows * row, slots * window * row})
     assert (not found) == clean, found[:5]
     assert ("paged_attn_decode" in hlo) == clean
+
+
+@pytest.mark.parametrize("program,impl,clean", [
+    ("prefill", "paged", True), ("prefill", "scatter", False),
+    ("extend", "paged", True), ("extend", "scatter", False)])
+def test_compiled_admission_holds_nothing_of_the_pools_size(
+        one_chip, no_compile_cache, program, impl, clean):
+    """transformer-big's admission programs at the benchmark's shapes
+    (a 1024-wide cold prefill; a 64-wide extend over a 1024-row window),
+    compiled for one v5e chip. Written by blocks, prefill produces no
+    array of the pool's or a layer's size and extend none of the pool's
+    (its window gather still slices a layer out, PERF.md section 7);
+    both hold under a gigabyte of temporaries beside a pool that is
+    updated where it lies. The scatter costs either two copies of each
+    pool."""
+    import chip_smoke
+
+    cfg, cc, spec, params, pool = _tbig_serving_specs(one_chip)
+    rows = cc.num_blocks * cc.block_size
+    row = cc.n_heads * cc.head_dim
+    sizes = {cc.n_layers * rows * row}
+    if program == "prefill":
+        fn = decode_lib.make_prefill_fn(cfg, cc, implementation=impl)
+        args = (spec((1, 1024)), spec((1,)), spec((1, 1024)))
+        sizes.add(rows * row)
+    else:
+        fn = decode_lib.make_extend_fn(cfg, cc, implementation=impl)
+        args = (spec((1, 64)), spec((1, 64)), spec((1,)), spec((1, 64)),
+                spec((1, 1024)))
+    assert fn.kv_write == impl
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    hlo = compiled.as_text()
+    found = chip_smoke.pool_sized_ops(hlo, sizes)
+    assert (not found) == clean, found[:5]
+    assert ("paged_kv_write" in hlo) == clean
+    memory = compiled.memory_analysis()
+    pool_bytes = cc.n_layers * rows * row * 2
+    assert (memory.temp_size_in_bytes < 1 << 30) == clean
+    assert memory.alias_size_in_bytes >= 2 * pool_bytes
 
 
 @pytest.mark.parametrize("program", ["decode", "decode_steps", "prefill"])
@@ -466,7 +734,8 @@ def test_compiled_looped_programs_hold_nothing_of_the_pools_size(
             args = (vec, vec, vec, spec((slots, 8), jnp.int32), table, vec)
         assert (fn.kv_path, fn.kv_layout, fn.passes) == ("paged", "rows", 4)
     else:
-        fn = decode_lib.make_prefill_fn(cfg, cc)
+        fn = decode_lib.make_prefill_fn(cfg, cc, implementation="paged")
+        assert fn.kv_write == "scatter"         # row-major: XLA's, in place
         args = (wide, spec((1,), jnp.int32), wide)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, *args).compile()

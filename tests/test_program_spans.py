@@ -201,6 +201,11 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
     assert (a["program"], a["cached_tokens"], a["prompt_tokens"],
             a["span_id"]) == ("extend", 16, 18, "req/a")
     assert b["program"] == "prefill" and "cached_tokens" not in b
+    # how the rows reached the pool (the CPU scatters) and how many
+    # blocks took them: positions 16-17 lie in one, nine tokens and the
+    # next one's room in two
+    assert (a["kv_write"], a["blocks_written"]) == ("scatter", 1)
+    assert (b["kv_write"], b["blocks_written"]) == ("scatter", 2)
     assert steps[0][3]["cached_tokens"] == admitted[0] == 16
     for p in prefills:                  # build, launch, wait under each
         sub = [s[0] for s in first if s is not p
