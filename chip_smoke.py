@@ -7,10 +7,10 @@ process (a chip belongs to one process: nothing here starts a child):
 
 1. train, one chip — ``bootstrap.initialize`` → ``make_mesh`` →
    ``make_sharded_train_step`` → a handful of steps on one repeated
-   ``synthetic_tokens`` batch, in the configuration the single-chip
-   bench row runs (bench.py ``main``);
-2. serve, same chip — ``InferenceEngine`` at bench.py's TPU serving
-   shapes, ``submit`` → ``run_until_idle`` over seeded requests, then a
+   ``synthetic_tokens`` batch, in the configuration of the benchmark's
+   ``tbig_train`` (``benchmark/configs/tbig_train.json``);
+2. serve, same chip — ``InferenceEngine`` over ``tbig_serve``'s model,
+   ``submit`` → ``run_until_idle`` over seeded requests, then a
    shared-prefix batch so the extend program and the copy-on-write pool
    copy run too, then the same requests again through the warm engine;
 3. train, four chips (only when JAX reports at least four) — the same
@@ -70,7 +70,7 @@ def check(ok: bool, what: str) -> None:
 
 
 def train_config() -> TransformerConfig:
-    """The single-chip bench configuration (bench.py ``main``)."""
+    """The model keys of ``benchmark/configs/tbig_train.json``."""
     return TransformerConfig.transformer_big(
         max_seq_len=1024, remat=False, scan_layers=False,
         loss_impl="kernel", loss_chunks=8,
@@ -79,7 +79,7 @@ def train_config() -> TransformerConfig:
 
 
 def serve_config() -> TransformerConfig:
-    """bench.py ``--serving``'s TPU model."""
+    """The model keys of ``benchmark/configs/tbig_serve.json``."""
     return TransformerConfig.transformer_big(max_seq_len=1024,
                                              scan_layers=False)
 
@@ -103,8 +103,7 @@ def looped_config(n_layers: int = 3) -> TransformerConfig:
 
 @dataclasses.dataclass(frozen=True)
 class ServeShapes:
-    """Engine and workload shapes (defaults: bench.py ``--serving``'s
-    TPU branch)."""
+    """Engine and workload shapes of the smoke run's serve phase."""
     num_blocks: int = 1024
     block_size: int = 16
     max_slots: int = 16

@@ -216,7 +216,7 @@ class CoordinationServiceAgent:
         self._inc_hint: dict[str, int] = {}
         #: per-agent KV/barrier op counts ({op_name: n}) — the raw
         #: material of the fleet-scale control-plane cost curves
-        #: (bench.py --fleet). Incremented without a lock: each agent
+        #: (testing/fleet_sim.py). Incremented without a lock: each agent
         #: belongs to one worker (exact there); the process-wide
         #: singleton's counts are approximate under thread races, which
         #: is fine for a cost profile.

@@ -87,9 +87,9 @@ class TransformerConfig:
     remat_policy: str = "nothing"  # "nothing" | "dots" (save matmul outputs)
     scan_layers: bool = True
     attention_impl: str | None = None   # None = auto (pallas on TPU)
-    # Pallas kernel tile sizes; the 512/1024 defaults are from the v5e
-    # block sweep (tools/perf_sweep.py) — grid overhead dominates below
-    # 512 and VMEM pressure wins above 1024 at head_dim 64.
+    # Pallas kernel tile sizes; the 512/1024 defaults are from a v5e
+    # block sweep — grid overhead dominates below 512 and VMEM pressure
+    # wins above 1024 at head_dim 64.
     attn_block_q: int = 512
     attn_block_k: int = 1024
     learning_rate: float = 3e-4
@@ -116,15 +116,6 @@ class TransformerConfig:
     # in HBM (the memory wall that capped global batch at 8 on v5e).
     # 0 = classic full-logits path.
     loss_chunks: int = 0
-    # Backward policy for the chunk scan: "recompute" re-derives each
-    # chunk's logits in the backward (minimum memory); "save" keeps the
-    # bf16 chunk logits (B·S·V·2 bytes — half the fp32 full-logits peak)
-    # so the backward skips the vocab-projection recompute. Interleaved
-    # A/B on v5e single chip measured "save" NEUTRAL-to-slightly-slower
-    # (the extra HBM traffic for the saved logits cancels the skipped
-    # matmul); kept as a knob for shapes where the recompute dominates
-    # (bigger vocab, shorter chunks, bandwidth-rich parts).
-    loss_chunk_policy: str = "recompute"
     # Fused-CE implementation: "scan" = the lax.scan chunk path above;
     # "kernel" = the Pallas vocab-tiled online-logsumexp kernels
     # (ops/fused_ce.py) — logits tiles never leave VMEM. On sharded
@@ -144,14 +135,6 @@ class TransformerConfig:
     # adamw first-moment dtype: bfloat16 halves the mu read+write HBM
     # traffic of the (bandwidth-bound) optimizer update; None = fp32.
     adam_mu_dtype: Any = None
-    # Fused optimizer update: one Pallas pass per parameter leaf with
-    # outputs aliased onto inputs (ops/fused_adamw.py) instead of the
-    # optax update→apply chain. Elementwise, so it runs per-shard under
-    # shard_map on sharded meshes (param_specs threaded in by
-    # make_sharded_train_step). optimizer_impl: "pallas" | "interpret" |
-    # "reference" | None (auto: pallas on TPU).
-    fused_optimizer: bool = False
-    optimizer_impl: str | None = None
     # The shape of a looped ("universal") stack: the ``n_layers`` blocks
     # run ``passes`` times with the same weights, the final norm after
     # every pass (its output feeds the next pass), and every pass of
@@ -533,8 +516,7 @@ def next_token_loss(logits, tokens):
 
 
 def fused_next_token_loss(hidden, embed, tokens, *, num_chunks,
-                          compute_dtype=jnp.bfloat16,
-                          chunk_policy: str = "recompute"):
+                          compute_dtype=jnp.bfloat16):
     """Chunked next-token CE over the tied embedding — the fused loss.
 
     Equivalent to ``next_token_loss(einsum(hidden, embed), tokens)`` but
@@ -566,22 +548,13 @@ def fused_next_token_loss(hidden, embed, tokens, *, num_chunks,
     def chunk_body(carry, xtm):
         xc, tc, mc = xtm
         logits = jnp.einsum("bcd,vd->bcv", xc.astype(compute_dtype), emb)
-        # Named BEFORE the fp32 cast: the "save" policy keeps the bf16
-        # form (half the bandwidth/footprint of saving fp32).
-        from jax.ad_checkpoint import checkpoint_name
-        logits = checkpoint_name(logits, "ce_logits").astype(jnp.float32)
+        logits = logits.astype(jnp.float32)
         ls = optax.softmax_cross_entropy_with_integer_labels(logits, tc)
         return carry + jnp.sum(ls * mc), None
 
-    if chunk_policy == "save":
-        policy = jax.checkpoint_policies.save_only_these_names("ce_logits")
-    elif chunk_policy == "recompute":
-        policy = jax.checkpoint_policies.nothing_saveable
-    else:
-        raise ValueError(f"chunk_policy={chunk_policy!r}; expected "
-                         f"'recompute' or 'save'")
     total, _ = jax.lax.scan(
-        jax.checkpoint(chunk_body, policy=policy),
+        jax.checkpoint(chunk_body,
+                       policy=jax.checkpoint_policies.nothing_saveable),
         jnp.zeros((), jnp.float32), xs)
     return total / (B * (S - 1))
 
@@ -637,17 +610,6 @@ def make_optimizer(cfg: TransformerConfig):
                        mu_dtype=cfg.adam_mu_dtype)
 
 
-def _find_adam_state(opt_state):
-    """Index of the ScaleByAdamState (count/mu/nu) in an optax chain
-    state tuple; raises if the transform isn't adam-shaped."""
-    for i, s in enumerate(opt_state):
-        if hasattr(s, "mu") and hasattr(s, "nu") and hasattr(s, "count"):
-            return i
-    raise ValueError(
-        "fused_optimizer=True needs an optax.adamw-style chain state "
-        f"(ScaleByAdamState not found in {type(opt_state)})")
-
-
 def make_loss_fn(cfg: TransformerConfig, model: TransformerLM):
     """loss_fn(params, tokens) -> scalar for ``cfg``/``model`` — the
     objective shared by the GSPMD step, the bucketed data-parallel step,
@@ -697,8 +659,7 @@ def make_loss_fn(cfg: TransformerConfig, model: TransformerLM):
         if fused:
             return fused_next_token_loss(
                 out, params["embed"], tokens,
-                num_chunks=cfg.loss_chunks, compute_dtype=cfg.dtype,
-                chunk_policy=cfg.loss_chunk_policy)
+                num_chunks=cfg.loss_chunks, compute_dtype=cfg.dtype)
         return next_token_loss(out, tokens)
 
     def loss_fn(params, tokens):
@@ -714,59 +675,18 @@ def make_loss_fn(cfg: TransformerConfig, model: TransformerLM):
     return loss_fn
 
 
-def make_train_step(cfg: TransformerConfig, model: TransformerLM, tx,
-                    param_specs=None):
+def make_train_step(cfg: TransformerConfig, model: TransformerLM, tx):
     """Functional (state, batch) -> (state, metrics) SPMD step built on
-    :func:`make_loss_fn`. ``param_specs`` (a pytree of PartitionSpecs
-    matching params) lets the fused optimizer run per-shard on sharded
-    meshes."""
+    :func:`make_loss_fn`."""
     loss_fn = make_loss_fn(cfg, model)
-
-    # The fused update needs per-shard execution on sharded meshes; with
-    # no param_specs on a >1 mesh the pallas call would run replicated
-    # (GSPMD can't partition it) — keep the optax path there.
-    use_fused_opt = cfg.fused_optimizer and (
-        cfg.mesh is None or cfg.mesh.size == 1 or param_specs is not None)
-
-    def fused_opt_step(state, grads):
-        from distributed_tensorflow_tpu.ops.fused_adamw import (
-            fused_adamw_update)
-        opt_state = state["opt_state"]
-        # The fused kernel REPLACES the whole optax chain with AdamW on
-        # cfg.learning_rate/weight_decay — a tx with extra stateful
-        # transforms would be silently skipped. Require the state
-        # structure to match make_optimizer(cfg) exactly so a custom tx
-        # (clipping, schedules, different chain) fails loudly here.
-        expected = jax.eval_shape(
-            lambda p: make_optimizer(cfg).init(p), state["params"])
-        if (jax.tree_util.tree_structure(expected)
-                != jax.tree_util.tree_structure(opt_state)):
-            raise ValueError(
-                "fused_optimizer=True supports exactly the "
-                "make_optimizer(cfg) adamw chain; the provided "
-                "optimizer's state structure differs — set "
-                "fused_optimizer=False or use make_optimizer(cfg)")
-        idx = _find_adam_state(opt_state)
-        adam = opt_state[idx]
-        params, mu, nu, count = fused_adamw_update(
-            state["params"], grads, adam.mu, adam.nu, adam.count,
-            lr=cfg.learning_rate, weight_decay=cfg.weight_decay,
-            implementation=cfg.optimizer_impl, mesh=cfg.mesh,
-            param_specs=param_specs)
-        new_adam = adam._replace(count=count, mu=mu, nu=nu)
-        return params, tuple(new_adam if i == idx else s
-                             for i, s in enumerate(opt_state))
 
     def train_step(state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(state["params"],
                                                   batch["tokens"])
         with jax.named_scope("optimizer"):
-            if use_fused_opt:
-                params, opt_state = fused_opt_step(state, grads)
-            else:
-                updates, opt_state = tx.update(grads, state["opt_state"],
-                                               state["params"])
-                params = optax.apply_updates(state["params"], updates)
+            updates, opt_state = tx.update(grads, state["opt_state"],
+                                           state["params"])
+            params = optax.apply_updates(state["params"], updates)
         return ({"params": params, "opt_state": opt_state,
                  "step": state["step"] + 1},
                 {"loss": loss})
@@ -855,12 +775,6 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh,
     - ``"gspmd"`` — one compiler-scheduled sync (the pre-ISSUE-6 path).
     - ``"auto"`` (default) — "bucketed" on >1-device pure-dp meshes
       (no MoE, default step), "gspmd" otherwise.
-    - ``"none"`` — MEASUREMENT ONLY: the bucketed step with the gradient
-      sync deleted (each shard applies its LOCAL grads — replicas
-      diverge, so never train with this). Timing full vs "none" isolates
-      the step's exposed collective time; bench.py's phase-breakdown
-      rows (``compute_frac``/``collective_frac``/``overlap_eff``) are
-      the full/none/collective-only delta.
 
     ``step_factory(cfg, model, tx)`` lets variants (BERT MLM) swap the
     per-step loss while reusing all sharding/jit wiring.
@@ -878,9 +792,9 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh,
         data_axes as mesh_data_axes
     pure_dp = (set(mesh.shape) <= {"dcn", "dp"} and mesh.size > 1
                and cfg.moe_experts == 0 and step_factory is None)
-    if grad_sync not in ("auto", "bucketed", "gspmd", "none"):
-        raise ValueError(f"grad_sync={grad_sync!r}; expected auto/"
-                         f"bucketed/gspmd/none")
+    if grad_sync not in ("auto", "bucketed", "gspmd"):
+        raise ValueError(f"grad_sync={grad_sync!r}; expected 'auto', "
+                         f"'bucketed' or 'gspmd'")
     if zero not in (0, 1, 2):
         raise ValueError(f"zero={zero!r}; expected 0, 1, or 2")
     if zero:
@@ -888,9 +802,6 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh,
             raise ValueError("zero= is not supported with step_factory")
         if cfg.moe_experts > 0:
             raise NotImplementedError("zero= with MoE is not supported")
-        if cfg.fused_optimizer:
-            raise ValueError("zero= replaces the optimizer update; set "
-                             "fused_optimizer=False")
         if grad_sync != "auto":
             raise ValueError("zero= owns the gradient sync schedule; "
                              "leave grad_sync='auto'")
@@ -899,14 +810,13 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh,
                                             seed, level=zero)
         return _make_zero_gspmd_train_step(cfg, mesh, global_batch,
                                            seed, level=zero)
-    if grad_sync in ("bucketed", "none") and not pure_dp:
+    if grad_sync == "bucketed" and not pure_dp:
         raise ValueError(
-            f"grad_sync={grad_sync!r} needs a pure data-parallel mesh "
+            f"grad_sync='bucketed' needs a pure data-parallel mesh "
             f"(axes ⊆ {{dcn, dp}}, >1 device, no MoE); got "
             f"{dict(mesh.shape)}")
-    if pure_dp and grad_sync in ("auto", "bucketed", "none"):
-        return _make_bucketed_dp_train_step(cfg, mesh, global_batch, seed,
-                                            sync=grad_sync != "none")
+    if pure_dp and grad_sync in ("auto", "bucketed"):
+        return _make_bucketed_dp_train_step(cfg, mesh, global_batch, seed)
     if cfg.mesh is None:
         cfg = dataclasses.replace(cfg, mesh=mesh)
     model = TransformerLM(cfg)
@@ -928,13 +838,7 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh,
         mesh, P(data_axes if data_axes else None, seq_axis))}
 
     rules = mesh_axis_rules(mesh)
-    factory = step_factory or make_train_step
-    factory_kwargs = {}
-    import inspect
-    if "param_specs" in inspect.signature(factory).parameters:
-        factory_kwargs["param_specs"] = jax.tree_util.tree_map(
-            lambda ns: ns.spec, state_shardings["params"])
-    step = factory(cfg, model, tx, **factory_kwargs)
+    step = (step_factory or make_train_step)(cfg, model, tx)
     with mesh, nn_partitioning.axis_rules(rules):
         state = jax.jit(init_fn, out_shardings=state_shardings)(rng)
         step_jit = jax.jit(
@@ -951,19 +855,14 @@ def make_sharded_train_step(cfg: TransformerConfig, mesh: Mesh,
 
 
 def _make_bucketed_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
-                                 global_batch: int, seed: int = 0,
-                                 *, sync: bool = True):
+                                 global_batch: int, seed: int = 0):
     """Pure data-parallel train step with explicit comm/compute overlap:
     the whole step runs under shard_map, per-device grads are reduced by
     collectives.GradientBucketer in reverse layer order (last-layer
     buckets launch while earlier layers still differentiate), and the
     replicated optimizer applies locally. Parameters are replicated on a
     pure-dp mesh, so state/step signatures match the GSPMD path
-    (state replicated, batch sharded over dcn×dp).
-
-    ``sync=False`` deletes the gradient collectives (grad_sync="none"):
-    the identical program minus the reduction, for isolating exposed
-    collective time in phase-breakdown measurements."""
+    (state replicated, batch sharded over dcn×dp)."""
     from distributed_tensorflow_tpu.cluster.topology import \
         data_axes as mesh_data_axes
     from distributed_tensorflow_tpu.parallel.collectives import (
@@ -1009,9 +908,8 @@ def _make_bucketed_dp_train_step(cfg: TransformerConfig, mesh: Mesh,
         # so grads sync as a bucketed MEAN allreduce
         loss, grads = jax.value_and_grad(loss_fn)(state["params"],
                                                   batch["tokens"])
-        if sync:
-            grads = bucketer.all_reduce(grads, op=ReduceOp.MEAN)
-            loss = collectives_all_reduce(loss, data_axes, ReduceOp.MEAN)
+        grads = bucketer.all_reduce(grads, op=ReduceOp.MEAN)
+        loss = collectives_all_reduce(loss, data_axes, ReduceOp.MEAN)
         with jax.named_scope("optimizer"):
             updates, opt_state = tx.update(grads, state["opt_state"],
                                            state["params"])

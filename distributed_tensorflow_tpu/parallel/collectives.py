@@ -459,9 +459,8 @@ def simulate_overlap(ready_s: Sequence[float], dur_s: Sequence[float],
          "finish_s":   per-bucket finish times}
 
     This is the hand-checkable counterpart of the *measured* overlap
-    efficiency (bench.py times the full / sync-free / collective-only
-    steps); tests pin this model against a hand-computed 2-bucket
-    schedule.
+    efficiency (the device trace's collective time); tests pin this
+    model against a hand-computed 2-bucket schedule.
     """
     if len(ready_s) != len(dur_s):
         raise ValueError(f"{len(ready_s)} ready times vs "

@@ -251,9 +251,8 @@ def zero_state_bytes(n_params: int, n_shards: int, level: int,
     """Analytic persistent+transient training-state bytes per device.
 
     Replicated (level 0): P*(param + grad + slot); ZeRO-1 shards the
-    slots; ZeRO-2 shards the gradient buffer too. The measured curve in
-    ``bench.py --scaling`` uses real shard shapes — this closed form is
-    the sanity line printed next to it.
+    slots; ZeRO-2 shards the gradient buffer too. A closed form:
+    ``tests/test_zero.py`` holds it against real shard shapes.
     """
     if level not in (0, 1, 2):
         raise ValueError(f"level must be 0, 1, or 2, got {level}")

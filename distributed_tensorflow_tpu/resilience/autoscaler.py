@@ -39,9 +39,8 @@ makes it *act*. Three layers, bottom up:
 Verified the way this repo always does: ``tools/chaos_sweep.py
 --spike`` drives seeded traffic spikes through a real shared fleet
 (examples/shared_fleet.py) and gates scale-up firing, SLO recovery,
-the ledger identity (±1%) and capacity return; ``bench.py
---autoscale`` captures the measured spike table (AUTOSCALE_r*.json,
-regression-gated inverted by tools/bench_trend.py).
+the ledger identity (±1%) and capacity return
+(``tests/test_autoscaler.py`` holds the counts).
 """
 
 from __future__ import annotations

@@ -52,8 +52,8 @@ Instrumented sites:
                           supervisor's staleness budget, ``signal``
                           partitions it (KV ops and heartbeats
                           suppressed for a window); the seeded
-                          fault plans of bench.py --fleet and
-                          tools/fleet_sweep.py are rules on this site
+                          fault plans of tests/test_fleet_sim.py
+                          are rules on this site
 ``data.dispatch``         input/data_service.DataServiceDispatcher.tick
                           (tag=job) — a ``raise`` fails one dispatch
                           round; the background loop must absorb it

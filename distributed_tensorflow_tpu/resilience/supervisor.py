@@ -232,7 +232,7 @@ class RecoverySupervisor:
           ``ShardedKVHeartbeats`` swaps in per-shard summary keys over
           the coordination KV so the watch loop polls O(N/shard)
           keys instead of O(N) files — the fleet-scale detect path
-          (bench.py --fleet measures detect latency vs N through it).
+          (tests/test_fleet_sim.py drives it).
         - ``runner_factory`` / ``cluster_spec_fn`` — how generations
           are spawned: default the real spawn-process
           ``MultiProcessRunner`` + fresh-port cluster specs; the

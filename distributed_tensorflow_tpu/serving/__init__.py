@@ -63,8 +63,9 @@ Quick start::
         for done in engine.step():
             print(done["id"], done["tokens"])
 
-Bench: ``python bench.py --serving`` (p50/p99 latency + tokens/s at a
-target QPS); chaos: ``python tools/chaos_sweep.py --serve``.
+Bench: ``python3 benchmark/run.py --workload tbig_serve.batch_chat``
+(cells in ``BENCHMARK.json``; chip only); chaos: ``python
+tools/chaos_sweep.py --serve``.
 """
 
 from distributed_tensorflow_tpu.serving.engine import InferenceEngine

@@ -744,8 +744,7 @@ def kv_quantization_probe(cfg: TransformerConfig, params, prompt,
     pools (f32 and ``kv_dtype``), feeding the f32 path's tokens to both
     so the trajectories stay aligned, and track the worst absolute
     logit difference and whether any argmax flipped. This is the
-    number the README's KV-dtype table documents and ``bench.py
-    --serving --kv-dtype int8`` stamps into its row."""
+    number the README's KV-dtype table documents."""
     from distributed_tensorflow_tpu.serving.kv_cache import (
         BlockAllocator, BlockTable, CacheConfig, init_pool)
 

@@ -37,7 +37,7 @@ double-serving) — only death re-routes them.
 
 Transport is pluggable: the elastic example uses per-replica
 line-buffered inbox files a :func:`~distributed_tensorflow_tpu.serving.
-replica.routed_replica` tails; ``bench.py --serving --router`` wires
+replica.routed_replica` tails; ``tests/test_router.py`` wires
 ``submit_fn`` straight into in-process engines.
 """
 

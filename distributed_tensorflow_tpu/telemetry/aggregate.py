@@ -177,8 +177,8 @@ def collect_rollup(agent=None, worker_ids=None) -> dict:
 # sizes; see README "Fleet scale".)
 #
 # Freshness: a value reaches the root after every level between has
-# republished — rollup latency is O(depth × publish interval), which
-# bench.py --fleet measures as snapshot age at collect time.
+# republished — rollup latency is O(depth × publish interval), the
+# snapshot age at collect time.
 #
 # Legacy discipline unchanged: partials are JSON strings, written in
 # place, read with enumerated point reads; a dead reducer's partial

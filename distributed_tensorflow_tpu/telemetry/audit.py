@@ -34,8 +34,7 @@ spends partition the total: they sum exactly to each SLO's
 
 Consumed by ``tools/day_report.py`` (render + ``--check`` gates),
 ``tools/obs_report.py`` / ``tools/health_report.py`` (per-cause budget
-table, day-phase breakdown), ``chaos_sweep.py --day`` and
-``bench.py --day``.
+table, day-phase breakdown) and ``chaos_sweep.py --day``.
 """
 
 from __future__ import annotations

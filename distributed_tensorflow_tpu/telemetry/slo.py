@@ -22,8 +22,7 @@ Two consumption modes share the math:
   record; :meth:`SLOMonitor.evaluate` is exported on the health scrape.
 - :func:`evaluate_records` / :func:`records_from_events` — post-hoc over
   a run's ``serve.request`` events; ``tools/health_report.py --check``
-  gates ``--slo-budget`` on it and ``bench.py --serving`` stamps the
-  verdict into its row.
+  gates ``--slo-budget`` on it.
 
 Production window presets live in :data:`DEFAULT_BURN_WINDOWS`; bench
 and test runs last seconds, not hours, so :func:`windows_for_span`

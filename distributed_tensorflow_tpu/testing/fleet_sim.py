@@ -562,7 +562,7 @@ def seeded_domain_kill_plan(seed: int, topology: DomainTopology, *,
 
 @dataclasses.dataclass
 class FleetReport:
-    """What one FleetSim.run measured (bench.py --fleet's raw rows)."""
+    """What one FleetSim.run measured."""
 
     num_workers: int
     steps: int
@@ -1012,8 +1012,8 @@ def seeded_data_kill_schedule(seed: int, num_workers: int, *,
 
 @dataclasses.dataclass
 class DataFleetReport:
-    """What one DataServiceSim.run measured (bench.py --data-service's
-    raw rows + the chaos/property-test observables)."""
+    """What one DataServiceSim.run measured (raw rows + the
+    chaos/property-test observables)."""
 
     num_workers: int
     num_splits: int

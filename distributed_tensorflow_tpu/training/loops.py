@@ -74,7 +74,7 @@ def stack_batches(batches: Iterable):
 #: Step-phase names StepTelemetry accepts (seconds within one step):
 #: host compute proper is whatever remains after the others.
 #: - ``compute``    time in the compiled step's compute (measured or
-#:   calibrated — see bench.py's phase breakdown)
+#:   calibrated)
 #: - ``collective`` EXPOSED gradient-collective time (host-timed sync
 #:   like the elastic worker's allgather, or calibrated residual)
 #: - ``host``       host-side callback/bookkeeping time (Model.fit

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
-One rule for every entry point (``chip_smoke.py``, ``bench.py``, the
-examples, ``tests/conftest.py``): the cache is wherever
+One rule for every entry point (``chip_smoke.py``, the examples,
+``tests/conftest.py``): the cache is wherever
 ``JAX_COMPILATION_CACHE_DIR`` says, and otherwise at one fixed path
 under the checkout. The path must not move between runs — a cache in a
 tempdir or under a pid never hits — so it is derived from this file's

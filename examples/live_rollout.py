@@ -25,9 +25,8 @@ Modes the sweeps drive:
 - ``--restart-mode`` — the pre-hot-swap baseline: a reassigned replica
   ABORTS and lets the supervisor respawn it; the next incarnation
   pin-restores the target (``from_checkpoint(at_step=)``, a
-  ``mode="restart"`` swap event). Same traffic, same events — the
-  swap-vs-restart freshness comparison in ``bench.py --rollout`` is
-  this flag and nothing else;
+  ``mode="restart"`` swap event). Same traffic, same events — a
+  swap-vs-restart freshness comparison is this flag and nothing else;
 - ``--kills N`` — seeded SIGKILLs through the supervisor mid-rollout
   (``chaos_sweep.py --rollout``): completions must still cover the
   workload, and every completion's tokens must equal the PURE output

@@ -2,8 +2,7 @@
 """Serve the flagship transformer: continuous batching + KV-cache decode.
 
 Default mode runs ONE in-process engine against a seeded request load
-and prints the latency/throughput summary (the bench.py --serving loop,
-human-sized). ``--elastic`` instead runs N *serving replicas* under the
+and prints the latency/throughput summary. ``--elastic`` instead runs N *serving replicas* under the
 recovery supervisor (resilience/supervisor.py) — each replica statically
 owns a shard of the workload, heartbeats per engine step, and appends
 completed requests to ``served-<task>.jsonl``. Kill one mid-load (try

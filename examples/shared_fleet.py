@@ -27,8 +27,7 @@ then read the run::
 
 ``tools/chaos_sweep.py --spike`` sweeps seeds through this script and
 gates scale-up firing, SLO recovery, the ledger identity and capacity
-return; ``bench.py --autoscale`` captures AUTOSCALE_r*.json from the
-same summary.
+return.
 """
 
 import argparse

@@ -40,15 +40,15 @@ def main():
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--tiny", action="store_true",
-                    help="CI-sized model (default on CPU)")
+                    help="CI-sized model (without it: BERT-base, "
+                         "whatever the backend)")
     args = ap.parse_args()
 
     enable_compile_cache()
     bootstrap.initialize()
     mesh = make_mesh(parse_axes(args.axes))
-    tiny = args.tiny or jax.default_backend() == "cpu"
     cfg = (bert.tiny_bert_config(max_seq_len=args.seq)
-           if tiny else bert.bert_config(max_seq_len=args.seq))
+           if args.tiny else bert.bert_config(max_seq_len=args.seq))
 
     state, step_fn = bert.make_sharded_train_step(
         cfg, mesh, args.global_batch)

@@ -350,9 +350,8 @@ def elastic_worker(ckpt_dir, total_steps, save_every, global_batch, lr,
         # Per-step phase attribution (the obs_report/trace_report phase
         # table): compute = local fwd/bwd + optimizer apply, collective
         # = the cross-process gradient allgather (host-driven here, so
-        # it is ENTIRELY exposed — overlap_eff 0 by construction; the
-        # compiled bucketed path in bench.py measures the overlapped
-        # counterpart), ckpt_block = step-loop time blocked on
+        # it is ENTIRELY exposed — overlap_eff 0 by construction),
+        # ckpt_block = step-loop time blocked on
         # checkpoint capture/commit/snapshot.
         t0 = _time.perf_counter()
         start = (step * global_batch + pid * per_batch) % _POOL

@@ -1,7 +1,8 @@
 """Flash attention kernel vs unfused reference (CPU, interpret mode).
 
 On the CPU test mesh both paths are exact fp32, so tolerances are tight —
-the TPU bf16-MXU run is covered by bench.py on hardware.
+the TPU bf16-MXU run is the benchmark's (``tbig_train.steady``) and
+``chip_smoke.py``'s on hardware.
 """
 
 import jax

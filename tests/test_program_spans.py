@@ -277,7 +277,7 @@ def test_ttft_runs_from_submit_and_queue_wait_says_how_much(tiny,
 KERNEL_NAMES = {
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "fused_ce_fwd",
     "fused_ce_bwd_dh", "fused_ce_bwd_de", "fused_ce_bwd_dhde_acc_dh",
-    "fused_ce_bwd_dhde_acc_de", "fused_adamw"}
+    "fused_ce_bwd_dhde_acc_de"}
 
 
 def _pallas_names(jaxpr) -> list[str]:
@@ -298,12 +298,12 @@ def _pallas_names(jaxpr) -> list[str]:
 def test_every_kernel_of_a_train_step_carries_a_stable_name():
     """The jaxpr of a tiny train step on the kernel paths (traced, never
     lowered, so the CPU will do): flash attention forward and both
-    backward kernels, fused CE forward and backward, fused adamw."""
+    backward kernels, fused CE forward and backward."""
     cfg = TransformerConfig.tiny(
         max_seq_len=128, scan_layers=False, remat=False,
         attention_impl="pallas", attn_block_q=64, attn_block_k=64,
         loss_impl="kernel", loss_kernel_impl="pallas", loss_block_n=32,
-        loss_block_v=64, fused_optimizer=True, optimizer_impl="pallas")
+        loss_block_v=64)
     model = TransformerLM(cfg)
     tx = make_optimizer(cfg)
     tokens = jnp.zeros((2, 128), jnp.int32)
@@ -316,8 +316,8 @@ def test_every_kernel_of_a_train_step_carries_a_stable_name():
     names = _pallas_names(
         jax.make_jaxpr(step)(state, {"tokens": tokens}).jaxpr)
     assert set(names) <= KERNEL_NAMES, set(names) - KERNEL_NAMES
-    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "fused_ce_fwd",
-            "fused_adamw"} <= set(names)
+    assert {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+            "fused_ce_fwd"} <= set(names)
     assert any(n.startswith("fused_ce_bwd_") for n in names)
 
 

@@ -75,8 +75,7 @@ def test_ring_attention_grads(qkv4, causal, devices):
 def test_sp_flash_matches_full_attention(qkv4, impl, causal, devices):
     """The Pallas-kernel SP paths (interpret mode on CPU): forward parity
     with full attention — the fast path the chip runs. (sp=4 for CI
-    compile time; the real Mosaic kernels also run under shard_map on
-    the chip every bench run — bench.py sp_kernel_smoke.)"""
+    compile time; no cell runs the Mosaic kernels under sp yet.)"""
     q, k, v = qkv4
     mesh = make_mesh({"sp": 4}, devices=jax.devices()[:4])
     fn = make_ring_attention(mesh, causal=causal, impl=impl,

@@ -6,12 +6,10 @@ real dispatch machinery (dispatch.send -> worker.execute ->
 dispatch.result linked by one span_id), overlap-efficiency parity
 against a hand-computed 2-bucket schedule, the bottleneck classifier on
 synthetic input-bound/comm-bound runs, obs_report's phase table +
-bottleneck CI gates, trace_report's CLI + completeness check, and
-bench_trend's regression gate.
+bottleneck CI gates, and trace_report's CLI + completeness check.
 """
 
 import json
-import os
 import threading
 import time
 
@@ -386,95 +384,6 @@ def test_trace_report_check_fails_on_generation_hole(tmp_path, capsys):
                  "generation": g}) + "\n")
     assert tr.main([str(tmp_path), "--check"]) == 1
     assert "INCOMPLETE" in capsys.readouterr().err
-
-
-# ---------------------------------------------------------------------------
-# bench_trend
-# ---------------------------------------------------------------------------
-
-def _write_round(repo, n, value, rc=0):
-    payload = {"n": n, "cmd": "bench", "rc": rc, "tail": "",
-               "parsed": {"metric": "m", "value": value, "unit": "x/s",
-                          "extra": {"mfu": 0.5}}}
-    if rc != 0:
-        payload.pop("parsed")
-    with open(os.path.join(repo, f"BENCH_r{n:02d}.json"), "w") as f:
-        json.dump(payload, f)
-
-
-def test_bench_trend_regression_gate(tmp_path, capsys):
-    import tools.bench_trend as bt
-    repo = str(tmp_path)
-    _write_round(repo, 1, 100.0)
-    _write_round(repo, 2, 150.0)
-    _write_round(repo, 3, 140.0)            # -6.7% vs best: ok
-    assert bt.main(["--repo", repo, "--check"]) == 0
-    out = capsys.readouterr().out
-    assert "r02=150" in out and "no regression" in out
-    _write_round(repo, 4, 120.0)            # -20% vs best 150: fail
-    assert bt.main(["--repo", repo, "--check"]) == 1
-    assert "REGRESSION" in capsys.readouterr().err
-    # a failed capture round is skipped, not treated as a zero
-    _write_round(repo, 5, 0.0, rc=1)
-    os.remove(os.path.join(repo, "BENCH_r04.json"))
-    assert bt.main(["--repo", repo, "--check"]) == 0
-    assert "skipped round r05" in capsys.readouterr().out
-
-
-def _write_scaling_round(repo, n, rows, era=None):
-    payload = {"bench": "scaling", "rows": rows}
-    if era is not None:
-        payload["timing_era"] = era
-    with open(os.path.join(repo, f"SCALING_r{n:02d}.json"), "w") as f:
-        json.dump(payload, f)
-
-
-def test_bench_trend_scaling_eras_and_memfrontier_floor(tmp_path,
-                                                        capsys):
-    """ISSUE 18 trend semantics: raw scaling throughput only gates
-    within one host-speed ``timing_era`` (PR 14's no-cross-host rule
-    applied across rounds), while the memfrontier max-trainable-params
-    FLOOR and the inverted step-time-tax series gate across all
-    rounds — a shrinking frontier or a growing tax fails regardless of
-    which box measured it."""
-    import tools.bench_trend as bt
-    repo = str(tmp_path)
-
-    def tput(v):
-        return {"workload": "transformer", "metric": "tokens_per_sec",
-                "devices": 8, "throughput": v, "efficiency_pct": 100.0}
-
-    def mf(params, mult):
-        return {"workload": "memfrontier",
-                "metric": "max_trainable_params", "devices": 8,
-                "technique": "zero2", "max_trainable_params": params,
-                "step_time_mult": mult, "steps_ok": True}
-
-    # era-less fast box, then a slower era: -60% throughput passes
-    # because the rounds are not comparable bases for each other
-    _write_scaling_round(repo, 1, [tput(1000.0), mf(100, 1.0)])
-    _write_scaling_round(repo, 2, [tput(400.0), mf(100, 1.0)],
-                         era="slowbox")
-    assert bt.main(["--repo", repo, "--check"]) == 0
-    capsys.readouterr()
-    # same era: -50% throughput now fails
-    _write_scaling_round(repo, 3, [tput(200.0), mf(100, 1.0)],
-                         era="slowbox")
-    assert bt.main(["--repo", repo, "--check"]) == 1
-    assert "transformer" in capsys.readouterr().err
-    # the param floor is era-free: a cross-era shrink still fails ...
-    _write_scaling_round(repo, 3, [tput(400.0), mf(60, 1.0)],
-                         era="otherbox")
-    assert bt.main(["--repo", repo, "--check"]) == 1
-    assert "memfrontier" in capsys.readouterr().err
-    # ... and so does a growing step-time tax (inverted series)
-    _write_scaling_round(repo, 3, [tput(400.0), mf(100, 2.0)],
-                         era="otherbox")
-    assert bt.main(["--repo", repo, "--check"]) == 1
-    assert "memfrontier_mult" in capsys.readouterr().err
-    _write_scaling_round(repo, 3, [tput(390.0), mf(110, 0.95)],
-                         era="slowbox")
-    assert bt.main(["--repo", repo, "--check"]) == 0
 
 
 # ---------------------------------------------------------------------------

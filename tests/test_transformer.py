@@ -206,29 +206,104 @@ def test_unrolled_layers_match_scan(devices):
                                rtol=1e-5, atol=1e-5)
 
 
-def test_fused_loss_chunk_policies_agree(devices):
-    """'save' (keep bf16 chunk logits) and 'recompute' are the same
-    math — gradients included."""
+def test_fused_loss_matches_full_logits_loss(devices):
+    """The chunk scan is the full-logits cross-entropy — gradients
+    included, to float32 rounding (the two sum the sequence in another
+    order: a few units of 1.2e-7 on 30 terms) — and its backward
+    recomputes each chunk's logits: nothing of the logits' size is kept
+    from the forward."""
     from distributed_tensorflow_tpu.models.transformer import (
-        fused_next_token_loss)
+        fused_next_token_loss, next_token_loss)
     rng = jax.random.PRNGKey(5)
     k1, k2, k3 = jax.random.split(rng, 3)
     B, S, D, V = 2, 16, 8, 32
     hidden = jax.random.normal(k1, (B, S, D), jnp.float32)
     embed = jax.random.normal(k2, (V, D), jnp.float32)
     tokens = jax.random.randint(k3, (B, S), 0, V)
-    outs = {}
-    for pol in ("recompute", "save"):
-        loss, grads = jax.value_and_grad(
-            lambda h, e: fused_next_token_loss(
-                h, e, tokens, num_chunks=4, compute_dtype=jnp.float32,
-                chunk_policy=pol), argnums=(0, 1))(hidden, embed)
-        outs[pol] = (float(loss), grads)
-    np.testing.assert_allclose(outs["recompute"][0], outs["save"][0],
+    def scan(h, e):
+        return fused_next_token_loss(h, e, tokens, num_chunks=4,
+                                     compute_dtype=jnp.float32)
+
+    def full(h, e):
+        return next_token_loss(jnp.einsum("bsd,vd->bsv", h, e), tokens)
+
+    scan_loss, scan_grads = jax.value_and_grad(scan, argnums=(0, 1))(
+        hidden, embed)
+    full_loss, full_grads = jax.value_and_grad(full, argnums=(0, 1))(
+        hidden, embed)
+    np.testing.assert_allclose(float(scan_loss), float(full_loss),
                                rtol=1e-6)
-    for a, b in zip(outs["recompute"][1], outs["save"][1]):
+    for a, b in zip(scan_grads, full_grads):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6)
-    with pytest.raises(ValueError, match="chunk_policy"):
-        fused_next_token_loss(hidden, embed, tokens, num_chunks=4,
-                              chunk_policy="bogus")
+                                   rtol=1e-5, atol=1e-6)
+    _, vjp = jax.vjp(scan, hidden, embed)
+    kept = [x.size for x in jax.tree_util.tree_leaves(vjp)
+            if hasattr(x, "size")]
+    assert kept and max(kept) < B * S * V, kept
+
+
+@pytest.mark.parametrize("mu_dtype", [None, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_train_step_updates_by_adamw_written_out(mu_dtype, devices):
+    """``make_optimizer(cfg)`` inside ``make_train_step``, three steps on
+    seeded weights, against AdamW written out in numpy: bias-corrected
+    moments, eps outside the root, decoupled weight decay, the first
+    moment kept in ``adam_mu_dtype`` between steps. The chain holds no
+    clipping and no schedule, so neither does the reference. The
+    bfloat16 case keeps the tolerances of the kernel-parity case it
+    replaces (5e-2 on the moment, 1e-4 on a parameter)."""
+    from distributed_tensorflow_tpu.models.transformer import make_loss_fn
+    cfg = TransformerConfig.tiny(adam_mu_dtype=mu_dtype)
+    model, tx = TransformerLM(cfg), make_optimizer(cfg)
+    batches = [synthetic_tokens(2, cfg.max_seq_len, cfg.vocab_size, seed=i)
+               for i in range(3)]
+    params = model.init(jax.random.PRNGKey(0), batches[0])["params"]
+    state = {"params": params, "opt_state": tx.init(params),
+             "step": jnp.zeros((), jnp.int32)}
+    step = jax.jit(make_train_step(cfg, model, tx))
+    grad_fn = jax.jit(jax.grad(make_loss_fn(cfg, model)))
+
+    b1, b2, eps = 0.9, 0.999, 1e-8
+    lr, wd = cfg.learning_rate, cfg.weight_decay
+    leaves, treedef = jax.tree_util.tree_flatten(params)
+    p = [np.asarray(x, np.float32) for x in leaves]
+    mu = [np.zeros_like(x) for x in p]
+    nu = [np.zeros_like(x) for x in p]
+    for t, tokens in enumerate(batches, start=1):
+        grads = jax.tree_util.tree_leaves(grad_fn(
+            jax.tree_util.tree_unflatten(treedef, p), tokens))
+        for i, g in enumerate(np.asarray(g, np.float32) for g in grads):
+            m = b1 * mu[i] + (1 - b1) * g
+            nu[i] = b2 * nu[i] + (1 - b2) * g * g
+            update = ((m / (1 - b1 ** t))
+                      / (np.sqrt(nu[i] / (1 - b2 ** t)) + eps)
+                      + wd * p[i])
+            p[i] = p[i] - lr * update
+            mu[i] = (m if mu_dtype is None
+                     else np.asarray(m.astype(mu_dtype), np.float32))
+        state, _ = step(state, {"tokens": tokens})
+
+    adam = state["opt_state"][0]
+    assert int(adam.count) == int(state["step"]) == 3
+    tol_p, tol_mu = (1e-6, 1e-6) if mu_dtype is None else (1e-4, 5e-2)
+    for got, want, tol in (
+            (state["params"], p, tol_p), (adam.mu, mu, tol_mu),
+            (adam.nu, nu, 1e-6)):
+        for x, y in zip(jax.tree_util.tree_leaves(got), want):
+            np.testing.assert_allclose(np.asarray(x, np.float32), y,
+                                       atol=tol)
+    assert all(x.dtype == (mu_dtype or jnp.float32)
+               for x in jax.tree_util.tree_leaves(adam.mu))
+
+
+@pytest.mark.parametrize("value", ["none", "off"])
+def test_grad_sync_takes_three_values_and_no_other(value, devices):
+    """The measurement-only ``"none"`` is gone with the tool that set
+    it: the bucketed step always reduces."""
+    mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
+    with pytest.raises(ValueError) as err:
+        make_sharded_train_step(TransformerConfig.tiny(), mesh, 4,
+                                grad_sync=value)
+    for name in ("'auto'", "'bucketed'", "'gspmd'"):
+        assert name in str(err.value)
+    assert value in str(err.value)

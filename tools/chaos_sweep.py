@@ -133,10 +133,8 @@ rack-loss restore came from a WARM tier — ``host`` or ``peer``, never
 outside the dead rack.
 
 The simulated-fleet axis of this family lives in
-``tools/fleet_sweep.py``: seed-derived crash/stall/partition schedules
-through hundreds of in-process workers (testing/fleet_sim.py) plus the
-FLEET_r*.json control-plane scaling-curve gates — run it alongside the
-sweeps here.
+``tests/test_fleet_sim.py``: seed-derived crash/stall/partition
+schedules through in-process workers (testing/fleet_sim.py).
 
 Usage::
 

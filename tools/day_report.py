@@ -2,8 +2,7 @@
 """Production-day scorecard: goodput identity, cause-itemized SLO
 budget spend, phase breakdown, rack-loss recovery tier.
 
-The retrospective surface over a ``bench.py --day`` /
-``testing/day_sim.DaySim`` run (or any telemetry run directory with a
+The retrospective surface over a ``testing/day_sim.DaySim`` run (or any telemetry run directory with a
 day driver's ``day.*`` markers): everything is recomputed purely from
 the event logs by ``telemetry/audit.audit_day`` — no in-process state.
 
