@@ -50,12 +50,15 @@ one pool row — dequantize-on-gather; 2-3.8x the slots depending on
 from __future__ import annotations
 
 import dataclasses
+import heapq
 import math
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from distributed_tensorflow_tpu import telemetry
 
 #: Physical block every allocator reserves: padded/inactive positions
 #: scatter here and masked attention never reads it.
@@ -173,7 +176,13 @@ class BlockAllocator:
     sequence sharing it, the prefix cache — holds one reference:
     :meth:`free` decrefs and only the LAST owner's free returns the
     block to the pool. Freeing a block nobody owns is still a
-    programming error and raises."""
+    programming error and raises.
+
+    References are dropped from many places (a finished, preempted or
+    re-queued sequence, a match handed back, copy-on-write, migration),
+    and all of them pass :meth:`free`. Whoever has to know installs
+    :attr:`observer`: the :class:`PrefixCache` does, to learn that a
+    block it indexes has lost its last foreign owner."""
 
     def __init__(self, num_blocks: int):
         if num_blocks < 2:
@@ -181,6 +190,10 @@ class BlockAllocator:
         self.num_blocks = num_blocks
         self._free = list(range(num_blocks - 1, TRASH_BLOCK, -1))
         self._refs: dict[int, int] = {}
+        #: optional ``callable(block, refs_left)``: :meth:`free` calls
+        #: it for every reference it drops from a block that stays
+        #: allocated (``refs_left >= 1``).
+        self.observer = None
 
     @property
     def num_free(self) -> int:
@@ -236,9 +249,14 @@ class BlockAllocator:
             if b not in self._refs:
                 raise ValueError(f"double free of block {b}")
         released = []
+        observer = self.observer
         for b in blocks:
-            self._refs[b] -= 1
-            if self._refs[b] == 0:
+            left = self._refs[b] - 1
+            if left:
+                self._refs[b] = left
+                if observer is not None:
+                    observer(b, left)
+            else:
                 del self._refs[b]
                 released.append(b)
         if released:
@@ -443,6 +461,28 @@ class PrefixCache:
     sequence shares — or one a cached longer chain still hangs off —
     is never reclaimed out from under its users.
 
+    **How the order is kept.** ``evict`` does not look for its victim
+    among all entries: the evictable ones are filed in a min-heap on
+    ``last_used`` as they BECOME evictable — the last foreign reference
+    on the block is dropped (the allocator tells the cache through
+    :attr:`BlockAllocator.observer`; the cache is handed no call at the
+    many places that release), the last cached child is evicted, a
+    spilled block is re-adopted — and filed again whenever ``last_used``
+    is written on an entry that stays evictable (:meth:`_touch`, the
+    one place that writes it). Nothing is taken out when an entry stops
+    being evictable (a match shares it, a child hangs off it): an item
+    is checked when it is popped (the entry still indexed, under that
+    ``last_used``, a leaf, refcount 1) and dropped if stale, and the
+    heap is rebuilt from the entries before stale items outnumber them
+    several times. So ``evict(n)`` costs ``n`` pops and a few stale
+    ones, whatever the cache holds. The victim is the one a scan of all
+    entries would choose, call for call: the eligible entry of least
+    ``last_used``. Ties need no rule: ``_clock`` advances once per
+    :meth:`match` / :meth:`register` call, a call touches entries of
+    one root path, and of those only the deepest cached one can be a
+    leaf, so among equal ``last_used`` at most one entry is eligible at
+    a time.
+
     At most ``len(prompt) - 1`` tokens ever match: prefill must compute
     at least the final prompt position to produce the first generated
     token's logits."""
@@ -452,12 +492,19 @@ class PrefixCache:
         self.block_size = block_size
         self._entries: dict[tuple, _CacheEntry] = {}
         self._children: dict[object, set] = {}
+        self._by_block: dict[int, _CacheEntry] = {}
+        #: lazy min-heap of ``(last_used, push number, entry)``; the
+        #: push number keeps a comparison from ever reaching the entry
+        self._lru: list[tuple] = []
+        self._pushes = 0
+        allocator.observer = self._on_free
         self._clock = 0
         self.hit_tokens = 0
         self.lookup_tokens = 0
         self.hit_requests = 0
         self.lookups = 0
         self.evictions = 0
+        self.evict_examined = 0
         self._spill: HostTier | None = None
         self._spill_extract = None
         self._spill_insert = None
@@ -509,8 +556,8 @@ class PrefixCache:
                 e = self._readopt(k, key)
             if e is None:
                 break
-            e.last_used = self._clock
             self._alloc.incref(e.block)
+            self._touch(e)
             blocks.append(e.block)
             key = k
             n += bs
@@ -523,8 +570,8 @@ class PrefixCache:
                         best is None or e.last_used > best.last_used):
                     best = e
             if best is not None:
-                best.last_used = self._clock
                 self._alloc.incref(best.block)
+                self._touch(best)
                 blocks.append(best.block)
                 n += len(rest)
         return n, blocks
@@ -559,13 +606,52 @@ class PrefixCache:
             if e is None:
                 self._alloc.incref(blocks[i])
                 e = _CacheEntry(k, key, blocks[i], btoks, self._clock)
-                self._entries[k] = e
-                self._children.setdefault(key, set()).add(k)
+                self._index(e)
                 added += 1
             else:
-                e.last_used = self._clock
+                # the prompt may sit in OTHER blocks than the entry's
+                # (an ask that ran cold beside its document's first):
+                # then nobody holds e.block and e stays evictable
+                self._touch(e)
             key = k
         return added
+
+    def _index(self, e: _CacheEntry) -> None:
+        self._entries[e.key] = e
+        self._children.setdefault(e.parent, set()).add(e.key)
+        self._by_block[e.block] = e
+
+    def _evictable(self, e: _CacheEntry) -> bool:
+        return (not self._children.get(e.key)
+                and self._alloc.refcount(e.block) == 1)
+
+    def _file(self, e: _CacheEntry) -> None:
+        """Put ``e`` in the eviction order under its present
+        ``last_used``. Stale items are bounded: past a few times the
+        number of entries the heap is rebuilt from the entries."""
+        self._pushes += 1
+        heapq.heappush(self._lru, (e.last_used, self._pushes, e))
+        if len(self._lru) > 4 * len(self._entries) + 64:
+            self._lru = [(x.last_used, i, x) for i, x in enumerate(
+                x for x in self._entries.values() if self._evictable(x))]
+            heapq.heapify(self._lru)
+            self._pushes = len(self._lru)
+
+    def _touch(self, e: _CacheEntry) -> None:
+        """Every write of ``last_used``. An entry that is evictable now
+        sees no other event before it is wanted, so it is filed again
+        here; the item under its old ``last_used`` has gone stale."""
+        e.last_used = self._clock
+        if self._evictable(e):
+            self._file(e)
+
+    def _on_free(self, block: int, refs_left: int) -> None:
+        """The allocator's observer: the last foreign reference on an
+        indexed leaf's block has gone."""
+        if refs_left == 1:
+            e = self._by_block.get(block)
+            if e is not None and not self._children.get(e.key):
+                self._file(e)
 
     def evict(self, n_blocks: int) -> int:
         """Free up to ``n_blocks`` pool blocks by dropping
@@ -573,35 +659,42 @@ class PrefixCache:
         refcount 1 — only the cache's own reference — and no cached
         children). Entries referenced by running sequences are never
         evicted. Returns how many blocks actually went back to the
-        pool."""
-        freed = 0
-        while freed < n_blocks:
-            victim = None
-            for e in self._entries.values():
-                if self._children.get(e.key):
-                    continue                 # interior of a cached chain
-                if self._alloc.refcount(e.block) != 1:
-                    continue                 # a sequence still shares it
-                if victim is None or e.last_used < victim.last_used:
-                    victim = e
-            if victim is None:
-                break
-            if self._spill is not None:
-                # Victim selection above already guarantees refcount 1
-                # (the cache's own ref): a block any sequence shares is
-                # never spilled, only truly cold cache-private blocks.
-                self._spill.put(victim.key, victim.parent, victim.tokens,
-                                self._spill_extract(victim.block),
-                                self._spill_epoch)
-            del self._entries[victim.key]
-            kids = self._children.get(victim.parent)
-            if kids is not None:
+        pool. The ``kv.evict`` span counts them (``blocks``) beside the
+        candidates popped to find them (``examined``)."""
+        if n_blocks <= 0:
+            return 0
+        with telemetry.span("kv.evict") as sp:
+            freed = examined = 0
+            while freed < n_blocks and self._lru:
+                last_used, _, victim = heapq.heappop(self._lru)
+                examined += 1
+                if (self._entries.get(victim.key) is not victim
+                        or victim.last_used != last_used
+                        or not self._evictable(victim)):
+                    continue                 # stale: see the class docstring
+                if self._spill is not None:
+                    # Only truly cold cache-private blocks are spilled:
+                    # the victim's refcount is 1 (the cache's own ref),
+                    # so no sequence shares it.
+                    self._spill.put(victim.key, victim.parent,
+                                    victim.tokens,
+                                    self._spill_extract(victim.block),
+                                    self._spill_epoch)
+                del self._entries[victim.key]
+                del self._by_block[victim.block]
+                kids = self._children[victim.parent]
                 kids.discard(victim.key)
                 if not kids:
                     del self._children[victim.parent]
-            self._alloc.free([victim.block])
-            self.evictions += 1
-            freed += 1
+                    parent = self._entries.get(victim.parent)
+                    if parent is not None and self._evictable(parent):
+                        self._file(parent)   # its last child went
+                self._alloc.free([victim.block])
+                self.evictions += 1
+                freed += 1
+            self.evict_examined += examined
+            sp["blocks"] = freed
+            sp["examined"] = examined
         return freed
 
     def fence(self, epoch) -> int:
@@ -617,10 +710,12 @@ class PrefixCache:
         lookup, exactly like a stale entry from a dead engine
         incarnation. Returns the number of device entries dropped."""
         dropped = len(self._entries)
-        for e in self._entries.values():
-            self._alloc.free([e.block])
+        blocks = [e.block for e in self._entries.values()]
         self._entries.clear()
         self._children.clear()
+        self._by_block.clear()
+        self._lru.clear()
+        self._alloc.free(blocks)
         self._spill_epoch = epoch
         self.fences += 1
         self.fence_dropped += dropped
@@ -649,8 +744,8 @@ class PrefixCache:
         self._spill.readopted += 1
         self.spill_hits += 1
         e = _CacheEntry(key, chain_key, block, se.tokens, self._clock)
-        self._entries[key] = e
-        self._children.setdefault(chain_key, set()).add(key)
+        self._index(e)
+        self._file(e)              # a leaf nobody else holds
         return e
 
     def stats(self) -> dict:
@@ -663,6 +758,7 @@ class PrefixCache:
             "hit_rate": (self.hit_tokens / self.lookup_tokens
                          if self.lookup_tokens else 0.0),
             "evictions": self.evictions,
+            "evict_examined": self.evict_examined,
             "spill_hits": self.spill_hits,
             "spill_rejects": self.spill_rejects,
             "fences": self.fences,

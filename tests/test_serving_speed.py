@@ -11,17 +11,21 @@ pure speed: correctness never depends on cache state, draft quality,
 or storage dtype.
 """
 
+import random
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_tensorflow_tpu import telemetry
 from distributed_tensorflow_tpu.models.transformer import (
     TransformerConfig, TransformerLM)
 from distributed_tensorflow_tpu.serving import (
     BlockAllocator, CacheConfig, InferenceEngine, PrefixCache, Request,
     kv_quantization_probe, truncated_draft)
-from distributed_tensorflow_tpu.serving.kv_cache import init_pool
+from distributed_tensorflow_tpu.serving.kv_cache import (
+    BlockTable, HostTier, OutOfBlocksError, init_pool)
 
 #: Documented int8 KV logit-error bound for the CI-sized config (the
 #: probe measures ~0.004 on this box; README's KV-dtype table cites
@@ -142,6 +146,275 @@ class TestPrefixCacheUnit:
 
 
 # ---------------------------------------------------------------------------
+# prefix cache: the eviction order, against a scan of every entry
+# ---------------------------------------------------------------------------
+
+def _scan_victims(cache, n_blocks):
+    """The oracle: ``PrefixCache.evict`` as it was before the evictable
+    leaves were kept in order — for each block it frees it walks every
+    entry for the unreferenced leaf of least ``last_used``. Evicts from
+    ``cache`` and returns the freed block ids in order."""
+    freed = []
+    while len(freed) < n_blocks:
+        victim = None
+        for e in cache._entries.values():
+            if cache._children.get(e.key):
+                continue                 # interior of a cached chain
+            if cache._alloc.refcount(e.block) != 1:
+                continue                 # a sequence still shares it
+            if victim is None or e.last_used < victim.last_used:
+                victim = e
+        if victim is None:
+            break
+        if cache._spill is not None:
+            cache._spill.put(victim.key, victim.parent, victim.tokens,
+                             cache._spill_extract(victim.block),
+                             cache._spill_epoch)
+        del cache._entries[victim.key]
+        cache._by_block.pop(victim.block, None)
+        kids = cache._children.get(victim.parent)
+        if kids is not None:
+            kids.discard(victim.key)
+            if not kids:
+                del cache._children[victim.parent]
+        cache._alloc.free([victim.block])
+        cache.evictions += 1
+        freed.append(victim.block)
+    return freed
+
+
+def _evict_logged(cache, n_blocks):
+    """``cache.evict(n_blocks)``; the block ids it freed, in order."""
+    freed = []
+    free = cache._alloc.free
+    cache._alloc.free = lambda blocks: (freed.extend(blocks),
+                                        free(blocks))[1]
+    try:
+        assert cache.evict(n_blocks) == len(freed)
+    finally:
+        del cache._alloc.free            # the method shows again
+    return freed
+
+
+class _World:
+    """An allocator, a prefix cache and the tables of a few live
+    sequences, driven as the scheduler drives them. Two worlds take the
+    same operations: one evicts with ``PrefixCache.evict``, the other
+    with the scan."""
+
+    def __init__(self, bs, spill, scan, num_blocks=48):
+        self.cfg = CacheConfig(n_layers=1, n_heads=1, head_dim=1,
+                               num_blocks=num_blocks, block_size=bs)
+        self.alloc = BlockAllocator(num_blocks)
+        self.cache = PrefixCache(self.alloc, bs)
+        self.scan = scan
+        self.tables = []
+        self.log = []                     # what every evict left behind
+        self.readopted = []               # (block, the block it was)
+        self.tier = None
+        if spill:
+            self.tier = HostTier(capacity_blocks=6)
+            self.cache.attach_spill(
+                self.tier, epoch=0,
+                extract=lambda b: {"was": np.asarray([b])},
+                insert=lambda b, arrays: self.readopted.append(
+                    (b, int(arrays["was"][0]))))
+
+    def evict(self, n):
+        freed = (_scan_victims(self.cache, n) if self.scan
+                 else _evict_logged(self.cache, n))
+        self.log.append((tuple(freed), tuple(self.cache._entries),
+                         tuple(self.alloc._free),
+                         tuple(self.tier._entries) if self.tier else ()))
+        return len(freed)
+
+    def _room(self, table, n_tokens):
+        """``Scheduler._ensure_room``: evict for what is missing."""
+        need = self.cfg.blocks_for(table.length + n_tokens)
+        grow = need - len(table.blocks)
+        if grow > self.alloc.num_free:
+            self.evict(grow - self.alloc.num_free)
+        try:
+            table.ensure_room(n_tokens, self.alloc)
+        except OutOfBlocksError:
+            return False
+        return True
+
+    def admit(self, tokens, keep, cold):
+        """Match (unless the ask runs ``cold``: the trap, a prompt
+        registered again under other blocks), hand the match back or
+        build the table, copy a shared tail on write, register."""
+        n, blocks = (0, []) if cold else self.cache.match(tokens)
+        out = (n, tuple(blocks))
+        table = BlockTable(self.cfg, 64)
+        table.blocks = list(blocks)
+        if not keep or not self._room(table, len(tokens) + 1):
+            table.release(self.alloc)
+            return out + ("back",)
+        if self.alloc.num_free < 1:
+            self.evict(1)
+        try:
+            copies = table.ensure_writable(n, len(tokens), self.alloc)
+        except OutOfBlocksError:
+            table.release(self.alloc)
+            return out + ("no room to copy",)
+        table.length = len(tokens)
+        self.cache.register(tokens, table.blocks)
+        self.tables.append(table)
+        return out + (tuple(copies), tuple(table.blocks))
+
+    def grow(self, i, n):
+        table = self.tables[i]
+        if self._room(table, n):
+            table.length += n
+        return tuple(table.blocks)
+
+    def release(self, i):
+        self.tables.pop(i).release(self.alloc)
+
+    def fence(self, epoch):
+        return self.cache.fence(epoch)
+
+    def state(self):
+        c = self.cache
+        return (tuple(c._entries), tuple(self.alloc._free),
+                tuple(sorted(self.alloc._refs.items())),
+                tuple(e.last_used for e in c._entries.values()),
+                c.evictions, c.spill_hits, c.spill_rejects,
+                tuple(self.readopted),
+                self.tier.stats() if self.tier else None,
+                tuple(self.tier._entries) if self.tier else ())
+
+
+def _draw_prompt(rng, bs, docs):
+    """A document's prefix and a short tail over three tokens: chains
+    share blocks, fork inside one, and end on and off a block's edge."""
+    doc = rng.choice(docs)
+    cut = rng.choice([len(doc), len(doc), rng.randrange(1, len(doc) + 1),
+                      (rng.randrange(len(doc)) // bs + 1) * bs])
+    tail = [rng.randrange(3) for _ in range(rng.choice([0, 0, 1, bs,
+                                                        bs + 1]))]
+    return tuple(doc[:cut]) + tuple(tail)
+
+
+@pytest.mark.parametrize("spill", [False, True], ids=["drop", "spill"])
+@pytest.mark.parametrize("bs", [1, 4, 16])
+def test_evict_frees_what_the_scan_frees(bs, spill):
+    """Thousands of the scheduler's own operations on two caches in
+    lock step: after every ``evict`` the same block ids were freed in
+    the same order, and the same entries, free list and spill tier are
+    left. ``last_used`` ties between eligible entries never arise, so
+    the scan's pick is the heap's."""
+    rng = random.Random(1000 * bs + spill)
+    docs = [[rng.randrange(3) for _ in range(rng.randrange(bs, 5 * bs + 2))]
+            for _ in range(6)]
+    new, old = (_World(bs, spill, scan=False), _World(bs, spill, scan=True))
+    evicts = 0
+    for step in range(3000):
+        r = rng.random()
+        if r < 0.45 or not new.tables:
+            op = ("admit", _draw_prompt(rng, bs, docs), rng.random() < 0.8,
+                  rng.random() < 0.15)
+        elif r < 0.60:
+            op = ("grow", rng.randrange(len(new.tables)),
+                  rng.randrange(1, 2 * bs + 1))
+        elif r < 0.90:
+            op = ("release", rng.randrange(len(new.tables)))
+        elif r < 0.995:
+            op = ("evict", rng.randrange(1, 6))
+        else:
+            op = ("fence", step)
+        got = [getattr(w, op[0])(*op[1:]) for w in (new, old)]
+        assert got[0] == got[1], (step, op)
+        assert new.log == old.log, (step, op)
+        evicts += len(new.log)
+        new.log.clear()
+        old.log.clear()
+        assert new.state() == old.state(), (step, op)
+        assert len(new.cache._lru) <= 4 * len(new.cache) + 65
+    assert evicts > 300 and new.cache.evictions > 400
+    if spill:
+        assert new.cache.spill_hits > 0 and new.tier.dropped > 0
+    # every reference is a table's or the cache's
+    for w in (new, old):
+        for t in list(w.tables):
+            t.release(w.alloc)
+        assert w.alloc.total_refs == len(w.cache)
+        w.evict(10 ** 6)
+        assert len(w.cache) == 0 and w.alloc.num_allocated == 0
+
+
+def test_evict_cost_is_by_blocks_freed_not_by_entries():
+    """By the counter, not the clock: 4,096 entries, 64 sequences'
+    blocks still referenced, the evictable ones older and newer than
+    those; freeing 50 looks at about 50 candidates (a scan would look
+    at 200,000 and more)."""
+    bs = 2
+    alloc = BlockAllocator(4200)
+    cache = PrefixCache(alloc, bs)
+    cfg = CacheConfig(n_layers=1, n_heads=1, head_dim=1, num_blocks=4200,
+                      block_size=bs)
+    held = []
+    for i in range(512):                     # 512 prompts of 8 blocks
+        toks = [i // 256, i % 256] + [i % 7] * 14
+        table = BlockTable(cfg, 16)
+        table.ensure_room(len(toks), alloc)
+        cache.register(toks, table.blocks)
+        if i % 8 == 3:
+            held.append(table)               # a running sequence
+        else:
+            table.release(alloc)
+    assert len(cache) == 4096 and len(held) == 64
+    for i in (5, 6, 7):                      # matched and handed back:
+        n, got = cache.match([i // 256, i % 256] + [i % 7] * 14)
+        alloc.free(got)                      # newer than the sequences
+    before = cache.stats()["evict_examined"]
+    assert cache.evict(50) == 50
+    examined = cache.stats()["evict_examined"] - before
+    assert 50 <= examined <= 150, examined
+    assert all(alloc.refcount(b) == 2 for t in held for b in t.blocks)
+    # the oldest went first: prompt 0's leaf, then up its chain
+    assert cache.match([0, 0] + [0] * 14)[0] == 0
+
+
+def test_stale_items_stay_bounded_without_eviction():
+    """10,000 match / hand-back cycles and no eviction: every cycle
+    files the chain's leaf again, and the structure stays within a
+    fixed multiple of the cache."""
+    alloc = BlockAllocator(64)
+    cache = PrefixCache(alloc, 4)
+    prompts = [[p] * 4 + [q] * 5 for p in range(3) for q in range(3)]
+    for toks in prompts:
+        blocks = alloc.alloc(2)
+        cache.register(toks, blocks)
+        alloc.free(blocks)
+    for i in range(10000):
+        n, got = cache.match(prompts[i % len(prompts)])
+        assert n == 8
+        alloc.free(got)
+        assert len(cache._lru) <= 4 * len(cache) + 65
+    assert cache.evictions == 0 and len(cache) == 12
+    assert cache.evict(100) == 12            # and all of it is still found
+
+
+def test_readopted_block_nobody_matched_is_evictable():
+    """``_readopt`` leaves a leaf only the cache holds: it is in the
+    eviction order from then on."""
+    alloc = BlockAllocator(8)
+    cache = PrefixCache(alloc, 2)
+    tier = HostTier()
+    cache.attach_spill(tier, epoch=0, extract=lambda b: {},
+                       insert=lambda b, arrays: None)
+    blocks = alloc.alloc(1)
+    cache.register([5, 6], blocks)
+    alloc.free(blocks)
+    assert cache.evict(1) == 1 and len(tier) == 1
+    (key,) = tier._entries
+    assert cache._readopt(key, None) is not None and len(cache) == 1
+    assert cache.evict(1) == 1 and alloc.num_allocated == 0
+
+
+# ---------------------------------------------------------------------------
 # prefix cache: engine level (the byte-parity contract)
 # ---------------------------------------------------------------------------
 
@@ -229,6 +502,48 @@ class TestPrefixCacheEngine:
             assert o == reference_greedy(cfg, params, p, 8)
         assert e.stats()["prefix_cache"]["hit_tokens"] > 0
         _assert_blocks_conserved(e)
+
+    def test_every_step_evicts_and_matches_the_scan(self, tiny, tmp_path,
+                                                    monkeypatch):
+        """A pool so small that nearly every step evicts, a closed loop
+        of a few dozen requests: tokens, evictions, preemptions and the
+        allocator's audit are those of the same run with the scan in
+        ``evict``'s place, and the ``kv.evict`` spans count every
+        evicted block."""
+        cfg, params = tiny
+        rng = random.Random(7)
+        prompts = [[rng.randrange(1, 12)
+                    for _ in range(rng.randrange(5, 17))]
+                   for _ in range(30)]
+        runs = {}
+        for scan in (False, True):
+            if scan:
+                monkeypatch.setattr(
+                    PrefixCache, "evict",
+                    lambda self, n: len(_scan_victims(self, n)))
+            e = _engine(cfg, params, num_blocks=14, block_size=4,
+                        max_slots=3, prefix_caching=True)
+            telemetry.configure(str(tmp_path / f"scan{scan}"),
+                                process_id=0)
+            try:
+                outs = e.generate(prompts, max_new_tokens=6)
+            finally:
+                telemetry.shutdown()
+            _assert_blocks_conserved(e)
+            stats = e.stats()["prefix_cache"]
+            runs[scan] = (outs, stats["evictions"],
+                          e.scheduler.preemptions, e.block_accounting())
+        assert runs[False] == runs[True]
+        outs, evictions, _, audit = runs[False]
+        assert evictions > len(prompts)
+        assert audit["leaked_refs"] == 0 and audit["conserved"]
+        assert outs[:3] == [reference_greedy(cfg, params, p, 6)
+                            for p in prompts[:3]]
+        spans = [ev for ev in telemetry.read_events(
+            str(tmp_path / "scanFalse" / "events-0.jsonl"))
+            if ev["ev"] == "kv.evict"]
+        assert sum(ev["blocks"] for ev in spans) == evictions
+        assert all(ev["examined"] >= ev["blocks"] for ev in spans)
 
 
 # ---------------------------------------------------------------------------
