@@ -355,7 +355,7 @@ def program_check(engine: InferenceEngine) -> None:
             (span, span, one, span, jnp.zeros((1, engine.window), jnp.int32)),
             {whole}, False)
     for name, (program, args, sizes, small) in programs.items():
-        compiled = program.lower(engine.params, engine.pool,
+        compiled = program.lower(engine.served_params, engine.pool,
                                  *args).compile()
         found = pool_sized_ops(compiled.as_text(), sizes)
         for line in found[:8]:
@@ -396,7 +396,9 @@ def serve_phase(cfg: TransformerConfig, shapes: ServeShapes, device, *,
         cfg, params, num_blocks=shapes.num_blocks,
         block_size=shapes.block_size, max_slots=shapes.max_slots,
         max_prompt_len=shapes.max_prompt_len, prefix_caching=True)
-    for name, tree in (("params", engine.params), ("pool", engine.pool)):
+    for name, tree in (("params", engine.params),
+                       ("served params", engine.served_params),
+                       ("pool", engine.pool)):
         on = {d for leaf in jax.tree_util.tree_leaves(tree)
               for d in leaf.devices()}
         check(on == {device}, f"engine {name} live on {device}")

@@ -107,10 +107,9 @@ def resident_params(cfg: TransformerConfig, params):
     temporaries in each program). With ``D`` minor the compiled decode
     and extend programs hold no temporaries to speak of (compiled for a
     described v5e; ``(L, D, H * hd)`` still cost two of the copies). The
-    programs read either form (:func:`_heads`); the engine keeps this
-    one where the weights arrive in a 16-bit compute type and there is
-    no mesh (float32 weights are converted in every run anyway, and a
-    mesh shards the head axis)."""
+    programs read either form (:func:`_heads`); the engine serves this
+    one where :func:`wants_resident` says the device would keep the
+    model's own form the costly way."""
     params = dict(canonical_params(cfg, params))
     layers = dict(params["layers"])
     attn = dict(layers["attn"])
@@ -121,6 +120,48 @@ def resident_params(cfg: TransformerConfig, params):
     layers["attn"] = attn
     params["layers"] = layers
     return params
+
+
+def wants_resident(cfg: TransformerConfig) -> bool:
+    """Whether 16-bit projection kernels of this model are worth keeping
+    as :func:`resident_params` lays them out, on one device. It is the
+    head's width that decides, as it decides how the KV pool lies
+    (``paged_attention.supported``): a minor dimension under the 128
+    lanes would pad, so the device itself keeps ``(L, D, H, hd)`` with
+    the largest dimension, ``D``, on the lanes, which is the resident
+    form already, and the programs compile to better fusions from the
+    model's own shape (transformer-big, ``hd`` 64, on a v5e: a full
+    decode step ran 7.66 ms handed the matrix form and 7.45 handed the
+    model's, a nearly empty one 1.64 and 1.45: PERF.md section 6,
+    PR 35). From 128 up it keeps ``(H, hd)`` tiles, the case
+    ``resident_params`` describes."""
+    return (jnp.dtype(cfg.dtype).itemsize == 2
+            and cfg.head_dim >= paged_attention.GROUP_ROWS)
+
+
+def compute_params(cfg: TransformerConfig, params, *,
+                   resident: bool = False):
+    """The canonical tree as a program run needs it: every matrix the
+    programs contract with (the embedding, an untied head, the attention
+    and feed-forward kernels: the leaves they ``.astype(cfg.dtype)``) in
+    the compute type, with ``resident`` in :func:`resident_params`'
+    layout. The norm scales (and an exit gate) stay as they arrive:
+    :func:`_rms_norm` multiplies them into a float32 stream. Rounding a
+    matrix here and rounding it at the head of a run give the matmuls
+    the same bits; done once, when the engine takes the weights, the
+    programs' own casts are no-ops (for transformer-big 1.41 GB of
+    traffic, 1.7 ms of every run on a v5e: PERF.md section 6, PR 35).
+    Pure, so that the engine runs it as one program."""
+    dt = cfg.dtype
+    params = dict(params)
+    for name in ("embed", "lm_head"):
+        if name in params:
+            params[name] = params[name].astype(dt)
+    layers = dict(params["layers"])
+    for group in ("attn", "mlp"):
+        layers[group] = {n: w.astype(dt) for n, w in layers[group].items()}
+    params["layers"] = layers
+    return resident_params(cfg, params) if resident else params
 
 
 def _heads(h, w, n_heads: int):
@@ -352,14 +393,20 @@ def _logits(cfg: TransformerConfig, params, x):
 
 
 def model_forward(cfg: TransformerConfig, params, tokens, lengths=None,
-                  *, return_kv: bool = False):
+                  *, return_kv: bool = False, logits_at=None):
     """Full-sequence forward over the canonical parameter tree — the
     serving-side twin of ``TransformerLM.__call__`` (same einsums, same
     order, no sharding-constraint machinery; GSPMD lays it out from the
     caller's in_shardings). ``lengths`` masks a right-padded batch via
     the factored rule. ``return_kv`` additionally returns the per-cache-
     layer post-RoPE K and V stacks ``(L, B, H, S, hd)`` — exactly what
-    prefill writes into the cache blocks."""
+    prefill writes into the cache blocks. ``logits_at`` ``(B,)``: the
+    one position of each sequence whose logits the caller wants,
+    ``(B, vocab)``: the head then runs on those rows alone. A prompt's
+    first token needs one row; ``(S, vocab)`` logits of a 1024-wide
+    prefill are 69 GFLOP and 67 MB that nobody reads, and on a v5e that
+    array took the fast memory the attention's score matrix lives in
+    (PERF.md section 6, PR 35)."""
     dt = cfg.dtype
     x = params["embed"].astype(dt)[tokens]             # (B, S, D)
 
@@ -379,6 +426,8 @@ def model_forward(cfg: TransformerConfig, params, tokens, lengths=None,
         return x, carry, ((k, v) if return_kv else None)
 
     x, _, kv = _run_stack(cfg, params, x, None, layer)
+    if logits_at is not None:
+        x = x[jnp.arange(x.shape[0]), logits_at]
     logits = _logits(cfg, params, x)
     if return_kv:
         return logits, _stacked(kv)
@@ -462,8 +511,9 @@ def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None, *,
 
     def prefill(params, pool, tokens, lengths, write_rows):
         B, S = tokens.shape
-        logits, (ks, vs) = model_forward(cfg, params, tokens,
-                                         lengths=lengths, return_kv=True)
+        last, (ks, vs) = model_forward(
+            cfg, params, tokens, lengths=lengths, return_kv=True,
+            logits_at=jnp.maximum(lengths, 1) - 1)
         L, _, H, _, hd = ks.shape
         rows = write_rows.reshape(-1)                       # (B*S,)
         flat_k = ks.transpose(0, 1, 3, 2, 4).reshape(L, B * S, H, hd)
@@ -479,7 +529,6 @@ def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None, *,
             # many to unroll one scatter each
             pool = _pool_write(pool, slice(None), rows, flat_k, flat_v,
                                quantized)
-        last = logits[jnp.arange(B), jnp.maximum(lengths, 1) - 1]
         return last, pool
 
     prefill.kv_write = "scatter" if write is None else "paged"
@@ -727,9 +776,8 @@ def make_draft_fn(cfg: TransformerConfig):
     preemption or restart)."""
 
     def draft(params, tokens, lengths):
-        logits = model_forward(cfg, params, tokens, lengths=lengths)
-        last = logits[jnp.arange(tokens.shape[0]),
-                      jnp.maximum(lengths, 1) - 1]
+        last = model_forward(cfg, params, tokens, lengths=lengths,
+                             logits_at=jnp.maximum(lengths, 1) - 1)
         return jnp.argmax(last, axis=-1).astype(jnp.int32)
 
     return jax.jit(draft)

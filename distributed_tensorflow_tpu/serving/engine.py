@@ -72,6 +72,7 @@ tests/test_serving_speed.py pins):
 
 from __future__ import annotations
 
+import functools
 import itertools
 import os
 import threading
@@ -96,6 +97,11 @@ from distributed_tensorflow_tpu.serving.scheduler import (
     Request, Sequence)
 
 _pool_epochs = itertools.count()
+
+#: one program for the whole tree (not one a leaf), compiled once a
+#: configuration: a hot-swap's new weights run it again
+_compute_params = jax.jit(decode_lib.compute_params, static_argnums=(0,),
+                          static_argnames=("resident",))
 
 
 def request_span_id(request_id: str) -> str:
@@ -250,21 +256,17 @@ class InferenceEngine:
             elif draft_cfg is None:
                 raise ValueError("draft_params requires draft_cfg")
             self._draft_cfg = draft_cfg
-            self._draft_params = jax.tree_util.tree_map(
-                jnp.asarray,
-                dict(decode_lib.canonical_params(draft_cfg,
-                                                 draft_params)))
+            self._draft_params = self._serving_tree(draft_cfg,
+                                                    draft_params)[1]
             self._draft = decode_lib.make_draft_fn(draft_cfg)
 
-        params = self._serving_tree(params)
-        if mesh is not None:
-            shardings = decode_lib.param_shardings(cfg, mesh)
-            params = jax.tree_util.tree_map(
-                lambda a, s: jax.device_put(jnp.asarray(a), s),
-                dict(params), shardings)
-        else:
-            params = jax.tree_util.tree_map(jnp.asarray, dict(params))
-        self.params = params
+        #: the weights as they were given, in the programs' tree: what
+        #: the digest, a hot-swap's tree test and a check against the
+        #: masters read. ``served_params`` is what the compiled programs
+        #: take: the same tree wherever the weights arrive in the
+        #: compute type, a copy rounded to it once otherwise
+        self.params, self.served_params = self._serving_tree(
+            cfg, params, mesh)
         #: model-version identity: snapshot step (0 = direct params, no
         #: checkpoint provenance) + content digest. Stamped on every
         #: serve.prefill/serve.request event and rotated by
@@ -324,6 +326,8 @@ class InferenceEngine:
             # inputs arrive host-side and get sharded by in_shardings
             from jax.sharding import NamedSharding, PartitionSpec as P
             dp = "dp" if "dp" in mesh.shape else None
+            shardings = jax.tree_util.tree_map(lambda a: a.sharding,
+                                               self.served_params)
             pool_sh = pool_shardings(mesh, cache_cfg)
             rep = NamedSharding(mesh, P())
             slotv = NamedSharding(mesh, P(dp))
@@ -469,18 +473,42 @@ class InferenceEngine:
             self.spill_tier = tier
 
     # -- weights -----------------------------------------------------------
-    def _serving_tree(self, params):
-        """``params`` in the layout the programs index: stacked layers,
-        and where the weights arrive in a 16-bit compute type on one
-        device, the projection kernels as plain matrices
-        (``decode.resident_params``: such weights are not converted in a
-        program run, so what is left to save is their relayout)."""
-        dtype = jnp.dtype(self.cfg.dtype)
-        leaf = jax.tree_util.tree_leaves(params)[0]
-        if (self.mesh is None and dtype.itemsize == 2
-                and jnp.dtype(leaf.dtype) == dtype):
-            return decode_lib.resident_params(self.cfg, params)
-        return decode_lib.canonical_params(self.cfg, params)
+    @staticmethod
+    def _serving_tree(cfg, params, mesh=None):
+        """``(params, served)``, the one way weights reach a program.
+        ``params`` is what arrived, on the device in the layout the
+        programs index (stacked layers, under ``param_shardings`` on a
+        mesh); ``served`` is what the programs are handed: every matrix
+        in the compute type (``decode.compute_params``, one program run
+        when the weights are taken and not a cast in every run of every
+        program), and on one device, where ``decode.wants_resident``,
+        the projection kernels as plain matrices
+        (``decode.resident_params``: no relayout in a run either; a mesh
+        shards the head axis and keeps the model's form). Weights that
+        arrive in the compute type are not copied: ``served is params``,
+        in the served layout."""
+        resident = mesh is None and decode_lib.wants_resident(cfg)
+        params = decode_lib.canonical_params(cfg, params)
+        want = jax.eval_shape(
+            functools.partial(decode_lib.compute_params, cfg), params)
+        cast = any(a.dtype != b.dtype for a, b in zip(
+            jax.tree_util.tree_leaves(params),
+            jax.tree_util.tree_leaves(want)))
+        if resident and not cast:
+            params = decode_lib.resident_params(cfg, params)
+        if mesh is not None:
+            shardings = decode_lib.param_shardings(cfg, mesh)
+            params = jax.tree_util.tree_map(
+                lambda a, s: jax.device_put(jnp.asarray(a), s),
+                dict(params), shardings)
+        else:
+            params = jax.tree_util.tree_map(jnp.asarray, dict(params))
+        if not cast:
+            return params, params
+        served = _compute_params(cfg, params, resident=resident)
+        if mesh is not None:
+            served = jax.device_put(served, shardings)
+        return params, served
 
     @property
     def weights_version(self) -> str:
@@ -567,25 +595,20 @@ class InferenceEngine:
         released and its PRISTINE request re-queued at the front
         (tokens generated under the old weights are discarded, so no
         completed output ever mixes versions — the preemption-replay
-        path is sanitized too); (2) the params pointer flips (and the
-        default truncated-target draft is re-derived when speculative
-        decoding uses it); (3) the prefix cache is fenced by the new
-        ``weights_version`` — device entries dropped, host-tier spills
-        epoch-fenced; (4) a ``serve.swap`` event is emitted and the
+        path is sanitized too); (2) the params pointer flips, the tree
+        the programs take with it, made as the constructor makes it
+        (``_serving_tree``: float32 weights are rounded to the compute
+        type again, once), and the default truncated-target draft is
+        re-derived when speculative decoding uses it; (3) the prefix
+        cache is fenced by the new ``weights_version`` — device entries
+        dropped, host-tier spills epoch-fenced; (4) a ``serve.swap`` event is emitted and the
         whole transition is priced into the ``rollout`` badput bucket.
         Zero requests are dropped: the latency clock keys on request
         id and survives the requeue, so SLO burn stays honest."""
         t0 = started_mono if started_mono is not None \
             else time.monotonic()
         raw = params
-        params = self._serving_tree(params)
-        if self.mesh is not None:
-            shardings = decode_lib.param_shardings(self.cfg, self.mesh)
-            params = jax.tree_util.tree_map(
-                lambda a, s: jax.device_put(jnp.asarray(a), s),
-                dict(params), shardings)
-        else:
-            params = jax.tree_util.tree_map(jnp.asarray, dict(params))
+        params, served = self._serving_tree(self.cfg, params, self.mesh)
         old_l, old_t = jax.tree_util.tree_flatten(self.params)
         new_l, new_t = jax.tree_util.tree_flatten(params)
         if old_t != new_t or any(
@@ -598,13 +621,12 @@ class InferenceEngine:
                 "architecture change")
         previous = self.weights_version
         requeued = self.scheduler.requeue_running()
-        self.params = params
+        self.params, self.served_params = params, served
         if self.spec_k and self._draft_default:
-            dcfg, dparams = decode_lib.truncated_draft(self.cfg, raw)
-            self._draft_cfg = dcfg
-            self._draft_params = jax.tree_util.tree_map(
-                jnp.asarray,
-                dict(decode_lib.canonical_params(dcfg, dparams)))
+            self._draft_cfg, draft = decode_lib.truncated_draft(self.cfg,
+                                                                raw)
+            self._draft_params = self._serving_tree(self._draft_cfg,
+                                                    draft)[1]
         self.weights_step = (int(step) if step is not None
                              else self.weights_step + 1)
         self.weights_digest = params_digest(self.params)
@@ -836,7 +858,7 @@ class InferenceEngine:
                     win = seq.table.window_rows()[None]
                 with telemetry.span("serve.prefill.launch"):
                     logits, self.pool = self._extend_prefill(
-                        self.params, self.pool, jnp.asarray(toks),
+                        self.served_params, self.pool, jnp.asarray(toks),
                         jnp.asarray(pos), jnp.asarray(lengths),
                         jnp.asarray(rows), jnp.asarray(win))
                     last = logits[0, S - 1]
@@ -847,7 +869,7 @@ class InferenceEngine:
                     rows = seq.table.rows(np.arange(E))[None]   # (1, E)
                 with telemetry.span("serve.prefill.launch"):
                     last, self.pool = self._prefill(
-                        self.params, self.pool, jnp.asarray(toks),
+                        self.served_params, self.pool, jnp.asarray(toks),
                         jnp.asarray(lengths), jnp.asarray(rows))
                     last = last[0]
             self.scheduler.commit_prefill(seq)
@@ -916,7 +938,7 @@ class InferenceEngine:
                     table[s] = seq.table.window_rows()
         with telemetry.span("serve.decode.launch"):
             logits, self.pool = self._decode(
-                self.params, self.pool,
+                self.served_params, self.pool,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(lengths), jnp.asarray(write_rows),
                 jnp.asarray(table))
@@ -988,7 +1010,7 @@ class InferenceEngine:
                     table[s] = seq.table.window_rows()
         with telemetry.span("serve.decode.launch"):
             chosen, self.pool = self._decode(
-                self.params, self.pool,
+                self.served_params, self.pool,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(lengths), jnp.asarray(write_rows),
                 jnp.asarray(table), jnp.asarray(budget))
@@ -1085,7 +1107,7 @@ class InferenceEngine:
                 window_rows[s] = seq.table.window_rows()
         with telemetry.span("serve.decode.launch"):
             logits, self.pool = self._extend_spec(
-                self.params, self.pool, jnp.asarray(tokens),
+                self.served_params, self.pool, jnp.asarray(tokens),
                 jnp.asarray(positions), jnp.asarray(lengths),
                 jnp.asarray(write_rows), jnp.asarray(window_rows))
         with telemetry.span("serve.decode.wait"):

@@ -7,8 +7,10 @@ window path, on the interpreted paged path of a pool that lies
 rows-on-lanes (``head_dim`` 16) and of one that lies row-major
 (``head_dim`` 128)."""
 
+import functools
 import glob
 import os
+import re
 import sys
 
 import jax
@@ -26,7 +28,8 @@ from distributed_tensorflow_tpu.models.transformer import (  # noqa: E402
     TransformerConfig)
 from distributed_tensorflow_tpu.ops import paged_attention  # noqa: E402
 from distributed_tensorflow_tpu.serving import decode as decode_lib  # noqa: E402
-from distributed_tensorflow_tpu.serving.engine import InferenceEngine  # noqa: E402
+from distributed_tensorflow_tpu.serving.engine import (  # noqa: E402
+    InferenceEngine, params_digest)
 from distributed_tensorflow_tpu.serving.kv_cache import (  # noqa: E402
     TRASH_BLOCK, BlockAllocator, BlockTable, CacheConfig, init_pool)
 from distributed_tensorflow_tpu.serving.scheduler import Request  # noqa: E402
@@ -46,13 +49,17 @@ SHAPES = {
 }
 
 
+def _noise(cfg):
+    """``seeded_params``' noise: less at a width of 1024 (see there)."""
+    return 0.1 if cfg.d_model == 64 else 0.01
+
+
 @pytest.fixture(scope="module", params=list(SHAPES))
 def served(request):
     """``(cfg, params, implementation, layout)`` per path."""
     shape, impl, layout = SHAPES[request.param]
     cfg = TransformerConfig.tiny(**{**LOOPED, **shape})
-    noise = 0.1 if cfg.d_model == 64 else 0.01
-    return cfg, seeded_params(cfg, seed=3, noise=noise), impl, layout
+    return cfg, seeded_params(cfg, seed=3, noise=_noise(cfg)), impl, layout
 
 
 def reference_logits(cfg, params, tokens):
@@ -326,24 +333,212 @@ def test_resident_kernels_give_the_programs_the_same_logits(served):
 
 
 def test_engine_keeps_bfloat16_weights_resident():
-    """Weights that arrive in a 16-bit compute type stay in it on the
-    device, projection kernels as matrices; float32 weights keep the
-    model's tree."""
+    """Weights that arrive in the compute type stay as they are on the
+    device and the engine holds ONE tree of them: what the programs take
+    is ``engine.params``. Where that type is 16 bits wide and a head 128
+    or more, the projection kernels lie as matrices; under that the
+    device keeps the model's own form with ``D`` on the lanes, and so
+    does the engine."""
     import dataclasses
     cfg = TransformerConfig.tiny(**LOOPED)
     half = dataclasses.replace(cfg, dtype=jnp.bfloat16,
                                param_dtype=jnp.bfloat16)
-    for c, want in ((cfg, (2, 64, 4, 16)), (half, (2, 64, 64))):
+    wide = dataclasses.replace(half, d_model=1024, n_heads=8)
+    for c, want in ((cfg, (2, 64, 4, 16)), (half, (2, 64, 4, 16)),
+                    (wide, (2, 1024, 1024))):
         params = jax.tree_util.tree_map(
-            lambda a: a.astype(c.param_dtype), seeded_params(cfg, seed=3))
+            lambda a: a.astype(c.param_dtype),
+            seeded_params(dataclasses.replace(c, param_dtype=jnp.float32),
+                          seed=3, noise=0.01))
         engine = InferenceEngine(c, params, num_blocks=BLOCKS,
                                  block_size=BLOCK, max_slots=SLOTS)
+        assert engine.served_params is engine.params
         assert engine.params["layers"]["attn"]["value"].shape == want
         assert {leaf.dtype for leaf in jax.tree_util.tree_leaves(
             engine.params)} == {jnp.dtype(c.param_dtype)}
         engine.submit(Request(id="r", tokens=tuple(TOKENS[:9]),
                               max_new_tokens=3))
         assert len(engine.run_until_idle()["r"]["tokens"]) == 3
+
+
+# -- float32 weights, a 16-bit compute type: rounded once, when taken -------
+
+#: the block designs the engine serves, computing in bfloat16 from
+#: float32 weights as ``tbig_serve`` does: one pass over unstacked
+#: layers with a tied head; the looped stack with its own head, post
+#: norms and an exit gate, with heads of 16 (served in the model's
+#: shapes) and of 128 (projection kernels served as matrices)
+ROUNDED = {
+    "one-pass": dict(vocab_size=128, max_seq_len=64, scan_layers=False),
+    "looped": LOOPED,
+    "looped-head-128": {**LOOPED, "d_model": 1024, "n_heads": 8},
+}
+#: the leaves the programs multiply by in the compute type; every other
+#: leaf (norm scales, the exit gate) they use as it arrives
+MATRICES = ("embed", "lm_head", "attn", "mlp")
+
+
+@pytest.fixture(scope="module", params=list(ROUNDED))
+def rounded(request):
+    """``(cfg, float32 params)``, ``cfg.dtype`` bfloat16."""
+    cfg = TransformerConfig.tiny(**{**ROUNDED[request.param],
+                                    "dtype": jnp.bfloat16})
+    return cfg, seeded_params(cfg, seed=3, noise=_noise(cfg))
+
+
+def _engine(cfg, params):
+    return InferenceEngine(cfg, params, num_blocks=BLOCKS, block_size=BLOCK,
+                           max_slots=SLOTS, max_prompt_len=32,
+                           prefix_caching=True)
+
+
+def _is_matrix(path):
+    return any(getattr(k, "key", None) in MATRICES for k in path)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _assert_rounded_once(cfg, served, masters):
+    """Every matrix of ``served`` is ``astype(cfg.dtype)`` of its master
+    bit for bit, once the resident relayout of the three projection
+    stacks is undone (heads of 128: ``decode.wants_resident``); every
+    other leaf is the master's, in float32."""
+    masters = decode_lib.canonical_params(cfg, masters)
+    flat, treedef = jax.tree_util.tree_flatten_with_path(served)
+    want, want_def = jax.tree_util.tree_flatten_with_path(masters)
+    assert treedef == want_def
+    seen = set()
+    for (path, got), (_, master) in zip(flat, want):
+        assert master.dtype == jnp.float32, path
+        if not _is_matrix(path):
+            assert got.dtype == jnp.float32, path
+            np.testing.assert_array_equal(_bits(got), _bits(master))
+            continue
+        seen.add(path[-1].key)
+        assert got.dtype == jnp.dtype(cfg.dtype), path
+        if (path[-1].key in ("query", "key", "value")
+                and decode_lib.wants_resident(cfg)):
+            assert got.shape == (cfg.n_layers, cfg.d_model, cfg.d_model)
+            got = got.transpose(0, 2, 1).reshape(master.shape)
+        np.testing.assert_array_equal(
+            _bits(got), _bits(master.astype(cfg.dtype)), err_msg=str(path))
+    assert seen == {"embed", "query", "key", "value", "out", "wi", "wo"} | (
+        set() if cfg.tie_embeddings else {"lm_head"})
+
+
+def _serve_a_mixed_batch(engine, tag=""):
+    """A cold prompt (prefill), then one that shares its first two
+    blocks (extend, a prefix hit) beside an unrelated one, the two
+    decoding together: ``{id: tokens}``."""
+    hits = engine.stats()["prefix_cache"]["hit_requests"]
+    engine.submit(Request(id=tag + "a", tokens=tuple(TOKENS[:19]),
+                          max_new_tokens=5))
+    done = dict(engine.run_until_idle())
+    engine.submit(Request(id=tag + "b",
+                          tokens=tuple(TOKENS[:16] + TOKENS[20:25]),
+                          max_new_tokens=6))
+    engine.submit(Request(id=tag + "c", tokens=tuple(TOKENS[7:18]),
+                          max_new_tokens=4))
+    done.update(engine.run_until_idle())
+    assert engine.stats()["prefix_cache"]["hit_requests"] == hits + 1
+    assert engine.block_accounting()["conserved"]
+    return {rid[len(tag):]: list(r["tokens"]) for rid, r in done.items()}
+
+
+def test_float32_weights_are_rounded_once_and_the_masters_kept(rounded):
+    """An engine that computes in bfloat16 from float32 weights hands its
+    programs a second tree, each matrix rounded once; ``engine.params``
+    stays the float32 canonical tree (what the benchmark's reference
+    reads, what ``weights_digest`` is of). It serves the tokens of an
+    engine given the same weights already rounded, which holds one tree."""
+    cfg, params = rounded
+    engine = _engine(cfg, params)
+    canonical = jax.tree_util.tree_map(
+        jnp.asarray, decode_lib.canonical_params(cfg, params))
+    assert engine.served_params is not engine.params
+    got, want = (jax.tree_util.tree_flatten_with_path(t)
+                 for t in (engine.params, canonical))
+    assert got[1] == want[1]
+    for (path, a), (_, b) in zip(got[0], want[0]):
+        assert (a.shape, a.dtype) == (b.shape, jnp.float32), path
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+    assert engine.weights_digest == params_digest(canonical)
+    _assert_rounded_once(cfg, engine.served_params, params)
+
+    already = _engine(cfg, decode_lib.compute_params(cfg, canonical))
+    assert already.served_params is already.params
+    tokens = _serve_a_mixed_batch(engine)
+    assert tokens == _serve_a_mixed_batch(already)
+    assert sorted(map(len, tokens.values())) == [4, 5, 6]
+
+
+def test_a_hot_swap_of_float32_weights_compiles_nothing(rounded):
+    """``install_version`` rounds the new float32 tree the same way, so
+    the programs see the types and shapes they were compiled for: no
+    jitted function gains an entry, and what is served is the new
+    weights' (the tokens of an engine built on them)."""
+    cfg, params = rounded
+    engine = _engine(cfg, params)
+    before = _serve_a_mixed_batch(engine)
+    programs = (engine._prefill, engine._decode, engine._extend_prefill)
+    sizes = [p._cache_size() for p in programs]
+    assert all(sizes)
+    digest = engine.weights_digest
+    new = seeded_params(cfg, seed=11, noise=_noise(cfg))
+    engine.install_version(new)
+    assert engine.weights_digest != digest
+    _assert_rounded_once(cfg, engine.served_params, new)
+    swapped = _serve_a_mixed_batch(engine, tag="s-")
+    assert [p._cache_size() for p in programs] == sizes
+    assert swapped == _serve_a_mixed_batch(_engine(cfg, new))
+    assert swapped != before
+
+
+def _weight_converts(engine, params):
+    """The float-to-compute-type ``convert``s of whole weights or of one
+    layer's slice of them in the decode, prefill and extend programs as
+    lowered for ``params``, by program."""
+    cfg, B, W = engine.cfg, engine.max_slots, engine.window
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    args = {"decode": (engine._decode,
+                       (i32(B), i32(B), i32(B), i32(B), i32((B, W)))),
+            "prefill": (engine._prefill, (i32((1, 32)), i32(1),
+                                          i32((1, 32)))),
+            "extend": (engine._extend_prefill,
+                       (i32((1, 8)), i32((1, 8)), i32(1), i32((1, 8)),
+                        i32((1, W))))}
+    shapes = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        if _is_matrix(path):
+            shapes |= {leaf.shape, leaf.shape[1:]}
+    sizes = {"x".join(map(str, s)) for s in shapes}
+    found = {}
+    for name, (program, rest) in args.items():
+        text = program.lower(params, engine.pool, *rest).as_text()
+        assert "stablehlo.convert" in text        # the dialect read below
+        found[name] = [
+            m.group(0) for m in re.finditer(
+                r"stablehlo\.convert [^\n]*tensor<([0-9x]+)xf32>\) -> "
+                r"tensor<[0-9x]+xbf16>", text) if m.group(1) in sizes]
+    return found
+
+
+def test_the_lowered_programs_convert_no_weight(rounded):
+    """The guard that the casts do not come back: lowered for the served
+    tree, no program of the bfloat16 engine converts an array of a
+    weight's (or one layer of a stacked weight's) shape from float32;
+    lowered for the float32 masters, which is what every run did before,
+    each of the three converts every matrix."""
+    cfg, params = rounded
+    engine = _engine(cfg, params)
+    assert _weight_converts(engine, engine.served_params) == {
+        "decode": [], "prefill": [], "extend": []}
+    before = _weight_converts(engine, engine.params)
+    matrices = 7 if cfg.tie_embeddings else 8
+    assert all(len(found) >= matrices for found in before.values()), before
 
 
 # -- several decode steps in one launch (``decode_steps``) -------------------

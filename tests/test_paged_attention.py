@@ -12,6 +12,8 @@ program takes. What the TPU's compiler makes of the program (nothing of
 the pool's size) is checked on a described v5e, no chip needed.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -614,11 +616,34 @@ def _tbig_serving_specs(one_chip):
     shapes = jax.eval_shape(
         lambda r: model.init(r, jnp.zeros((1, 8), jnp.int32)),
         jax.random.PRNGKey(0))
+    # the tree as the engine serves float32 weights: every matrix in
+    # bfloat16, in the model's own shapes (heads of 64: the device keeps
+    # D on the lanes by itself)
+    assert not decode_lib.wants_resident(cfg)
+    shapes = jax.eval_shape(
+        lambda p: decode_lib.compute_params(cfg, p),
+        decode_lib._plain(shapes["params"]))
     params = jax.tree_util.tree_map(
-        lambda a: spec(a.shape, a.dtype), decode_lib._plain(shapes["params"]))
+        lambda a: spec(a.shape, a.dtype), shapes)
     pool = {n: spec((cc.n_layers, cc.num_blocks * cc.block_size, cc.n_heads,
                      cc.head_dim), cc.dtype) for n in ("k", "v")}
     return cfg, cc, spec, params, pool
+
+
+def _weight_shaped_work(hlo: str, params) -> list:
+    """The entry computation's converts, copies and fusions that produce
+    an array of a weight matrix's shape: what a program run pays where
+    its weights arrive in another type or layout than it computes with.
+    (A prefetch into the chip's near memory is ``copy-start`` /
+    ``copy-done`` / ``slice-start`` and is not matched: it moves a
+    weight without rewriting it, beside the compute.)"""
+    shapes = {",".join(map(str, leaf.shape))
+              for leaf in jax.tree_util.tree_leaves(params) if leaf.ndim > 1}
+    entry = hlo[hlo.index("\nENTRY "):]
+    return [line.strip()[:160] for line in entry.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([0-9,]+)\]\S* "
+                              r"(convert|copy|fusion)\(", line))
+            and m.group(1) in shapes]
 
 
 @pytest.mark.parametrize("impl,clean", [("paged", True), ("window", False)])
@@ -645,6 +670,9 @@ def test_compiled_decode_holds_nothing_of_the_pools_size(
         hlo, {cc.n_layers * rows * row, rows * row, slots * window * row})
     assert (not found) == clean, found[:5]
     assert ("paged_attn_decode" in hlo) == clean
+    # the weights arrive as the program computes with them: no convert,
+    # no relayout of a matrix at the head of a run
+    assert not _weight_shaped_work(hlo, params)
 
 
 @pytest.mark.parametrize("program,impl,clean", [
@@ -681,6 +709,16 @@ def test_compiled_admission_holds_nothing_of_the_pools_size(
     found = chip_smoke.pool_sized_ops(hlo, sizes)
     assert (not found) == clean, found[:5]
     assert ("paged_kv_write" in hlo) == clean
+    assert not _weight_shaped_work(hlo, params)
+    if program == "prefill":
+        # the head runs on the one row a prompt's first token needs: a
+        # (1024, vocab) array of logits is work nobody reads, and it took
+        # the fast memory that the twelve layers' (heads, 1024, 1024)
+        # score matrices then lost (PERF.md section 6, PR 35)
+        entry = hlo[hlo.index("\nENTRY "):]
+        assert f"[1024,{cfg.vocab_size}]" not in entry
+        scores = re.findall(r"f32\[16,1024,1024\]\{[^}]*\}", entry)
+        assert scores and all("S(1)" in s for s in scores)
     memory = compiled.memory_analysis()
     pool_bytes = cc.n_layers * rows * row * 2
     assert (memory.temp_size_in_bytes < 1 << 30) == clean
