@@ -1,9 +1,9 @@
 """Operations the algorithm needs, computed from shapes.
 
-``train_step_flops`` is a copy of ``bench.step_flops`` (the original is
-listed in PERF.md's open questions for deletion): 6*N per token for the
-forward and backward matmuls plus the attention term, halved under a
-causal mask. Recomputed operations do not count.
+``train_step_flops`` is 6*N per token for the forward and backward
+matmuls plus the attention term, halved under a causal mask (the
+second yardstick it was copied from went with PR 31: this is the only
+one). Recomputed operations do not count.
 """
 
 

@@ -54,6 +54,18 @@ def whole_step_rate(step_ends_s, step_counts, open_s: float,
     return float(count), end - start
 
 
+def quarter_means(series) -> tuple[float, float]:
+    """Means over the first and over the last quarter of ``series``. Of
+    the requests left waiting after each step of a window they say
+    whether the queue grew: the knee of an open loop is the highest
+    rate at which the last quarter's is no more than half a request
+    over the first's (bursts come and go, so single steps say
+    nothing)."""
+    quarter = max(1, len(series) // 4)
+    return (sum(series[:quarter]) / quarter,
+            sum(series[-quarter:]) / quarter)
+
+
 def _product(record: dict, names) -> float | None:
     if isinstance(names, str):
         names = [names]

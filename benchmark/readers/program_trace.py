@@ -258,30 +258,6 @@ def gap_ms_per_span(spans, ops, name: str) -> float | None:
     return gaps.get(name, 0.0) / len(named) * 1e3
 
 
-def gap_shares(ops, spans, min_gap_s: float = trace_reduce.MIN_GAP_S
-               ) -> dict[str, float]:
-    """Idle seconds between busy intervals, each gap cut where a span
-    starts or ends and every piece given to the innermost span that
-    covers it (``gap_attribution`` gives a whole gap to the span at its
-    middle, which is what a metric wants; this says what the host was
-    doing all through it, which is what a reader of the table wants)."""
-    union = trace_reduce.busy_union(_plain(ops))
-    out: dict[str, float] = defaultdict(float)
-    for (_, lo), (hi, _) in zip(union, union[1:]):
-        if hi - lo < min_gap_s:
-            continue
-        near = [s for s in spans if s[1] < hi and s[1] + s[2] > lo]
-        cuts = sorted({lo, hi} | {t for s in near
-                                  for t in (s[1], s[1] + s[2])
-                                  if lo < t < hi})
-        for a, b in zip(cuts, cuts[1:]):
-            mid = (a + b) / 2
-            over = [(s[2], s[0]) for s in near
-                    if s[1] <= mid <= s[1] + s[2]]
-            out[min(over)[1] if over else trace_reduce.NO_SPAN] += b - a
-    return dict(out)
-
-
 def scope_ms_per_span(spans, ops, match, per: str | None,
                       main_runs: int) -> float | None:
     """The first ``per`` span of a trace opens before the chip's first
@@ -431,7 +407,7 @@ def table(path: str) -> None:
           f"gaps over {trace_reduce.MIN_GAP_S * 1e6:.0f} us by innermost "
           f"program span (ms: each gap split over the spans it crosses, "
           f"and whole to the span at its middle)")
-    split = gap_shares(ops, spans)
+    split = trace_reduce.gap_shares(_plain(ops), spans)
     whole = trace_reduce.gap_attribution(_plain(ops), _plain(spans))
     for name, s in trace_reduce.top(split, 20):
         print(f"  {name:26s} {s * 1e3:11.3f} "

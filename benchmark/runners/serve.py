@@ -10,9 +10,11 @@ same code. The record it returns (``metric_math.reduce`` reads it):
 
 per measured request ``ttft_ms``, ``tpot_ms``, ``queue_wait_ms``,
 ``submit_late_ms``; per step in the window ``decode_batch`` (sequences
-that got a token), ``waiting`` (requests left in the queue), ``plain_step_ms`` (steps that admitted nothing),
-``admit_extra_ms`` (a step's time beyond the median plain step, per
-request it admitted); scalars ``tokens`` and ``elapsed_s`` over whole
+that got a token), ``waiting`` (requests left in the queue; its means
+over the window's first and last quarter are the scalars
+``waiting_first_quarter`` and ``waiting_last_quarter``, by which
+``sweep.py`` says whether the queue grew), ``plain_step_ms`` (steps
+that admitted nothing); scalars ``tokens`` and ``elapsed_s`` over whole
 steps, ``cached_tokens`` and ``prompt_tokens`` summed per admission,
 ``blocks_used_peak_share``.
 """
@@ -33,6 +35,8 @@ from distributed_tensorflow_tpu.serving.scheduler import QueueOverflowError
 
 DRAIN_S = 15.0           # measured requests unfinished this long after
                          # the window closes have failed
+GC_LEAD_S = 0.5          # the one collection comes this long before
+                         # the window, or before the clock starts
 
 
 class Runner:
@@ -92,14 +96,20 @@ class Runner:
         # per step: (end_s, ms, admitted, decoded, tokens, left waiting)
         steps: list[tuple] = []
         min_free = blocks_total
+        # a full collection walks every object the process holds: tens
+        # of milliseconds here, a second in a test worker that has run
+        # a few hundred tests. A ramp too short to hold it must not
+        # lose its window to it
+        collected = source.ramp_s <= GC_LEAD_S
+        if collected:
+            gc.collect()
         epoch = time.monotonic()
         open_s = epoch + source.ramp_s
         close_s = open_s + seconds
         tracer.window(open_s, close_s)
-        collected = False
         while True:
             now = time.monotonic()
-            if not collected and now >= open_s - 0.5:
+            if not collected and now >= open_s - GC_LEAD_S:
                 gc.collect()                # once, before the window
                 collected = True
             tracer.tick(now)
@@ -184,9 +194,8 @@ class Runner:
             ends, [s[4] for s in steps], open_s, close_s)
         inside = [s for s in steps if open_s <= s[0] < close_s]
         plain = [s[1] for s in inside if s[2] == 0 and s[3] > 0]
-        plain_p50 = metric_math.percentile(plain, 50)
-        extra = ([(s[1] - plain_p50) / s[2] for s in inside if s[2] > 0]
-                 if plain_p50 is not None else [])
+        waiting = [s[5] for s in inside]
+        began, ended = metric_math.quarter_means(waiting)
         return {
             "window_open_s": open_s,
             "attempted": len(measured),
@@ -202,8 +211,9 @@ class Runner:
             "submit_late_ms": [r["late_ms"] for r in good],
             "tokens": tokens, "elapsed_s": elapsed,
             "decode_batch": [s[3] for s in inside if s[3] > 0],
-            "waiting": [s[5] for s in inside],
-            "plain_step_ms": plain, "admit_extra_ms": extra,
+            "waiting": waiting,
+            "waiting_first_quarter": began, "waiting_last_quarter": ended,
+            "plain_step_ms": plain,
             "cached_tokens": float(sum(r["cached"] for r in good)),
             "prompt_tokens": float(sum(r["n_prompt"] for r in good)),
             "blocks_used_peak_share": used_share,

@@ -49,9 +49,7 @@ def main(argv=None) -> int:
         rec = runner.drive(source, args.seconds, harness.Tracer(None))
         runner.engine.run_until_idle()
         p = lambda series, q: metric_math.percentile(rec[series], q)
-        quarter = max(1, len(rec["waiting"]) // 4)
-        began = sum(rec["waiting"][:quarter]) / quarter
-        ended = sum(rec["waiting"][-quarter:]) / quarter
+        began, ended = metric_math.quarter_means(rec["waiting"])
         row = {"rate_rps": rate, "attempted": rec["attempted"],
                "failed": rec["failed"], "rejected": rec["rejected"],
                "waiting_first_quarter": began,

@@ -1,7 +1,8 @@
 """From the profiler's xplane file to numbers: the busy union and idle
 share of each chip, the device time of each operation, the runs of each
-compiled program, and each long idle gap attributed to the host span of
-the benchmark's own that covers it.
+compiled program, and each long idle gap split over the host spans that
+cover it: the program's own (``telemetry.span``: ``serve.decode.wait``)
+and the benchmark's (``bench.wait_request``).
 
 The arithmetic works on plain ``(name, start_s, duration_s)`` tuples so
 that it can be checked on a hand-written event list; ``reduce_xplane``
@@ -17,8 +18,12 @@ from collections import defaultdict
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 PROGRAMS_LINE = "XLA Modules"
-HOST_SPAN_PREFIX = "bench."
+#: a span somebody named: dotted lower case, which is what the program's
+#: ``telemetry.span`` names and the benchmark's ``bench.*`` annotations
+#: are and the runtime's own host events are not
+HOST_SPAN = re.compile(r"^[a-z_]+(\.[a-z_]+)+$")
 NO_SPAN = "_none_"
+OTHER = "_other_"        # what a list cut to its longest entries left out
 MIN_GAP_S = 20e-6        # shorter gaps are launch latency, not waiting
 
 
@@ -103,9 +108,41 @@ def gap_attribution(events, host_spans, min_gap_s: float = MIN_GAP_S
     return dict(out)
 
 
-def top(totals: dict[str, float], n: int = 10) -> list[list]:
-    return [[k, v] for k, v in sorted(totals.items(),
-                                      key=lambda kv: -kv[1])[:n]]
+def gap_shares(events, host_spans, min_gap_s: float = MIN_GAP_S
+               ) -> dict[str, float]:
+    """Idle seconds between busy intervals, each gap cut where a span
+    starts or ends and every piece given to the innermost span that
+    covers it (``gap_attribution`` gives a whole gap to the span at its
+    middle, which is what a metric per span wants; this says what the
+    host was doing all through it, and sums to the same seconds). Of a
+    span only its first three fields are read: name, start, seconds."""
+    union = busy_union(events)
+    out: dict[str, float] = defaultdict(float)
+    for (_, lo), (hi, _) in zip(union, union[1:]):
+        if hi - lo < min_gap_s:
+            continue
+        near = [s for s in host_spans if s[1] < hi and s[1] + s[2] > lo]
+        cuts = sorted({lo, hi} | {t for s in near
+                                  for t in (s[1], s[1] + s[2])
+                                  if lo < t < hi})
+        for a, b in zip(cuts, cuts[1:]):
+            mid = (a + b) / 2
+            over = [(s[2], s[0]) for s in near
+                    if s[1] <= mid <= s[1] + s[2]]
+            out[min(over)[1] if over else NO_SPAN] += b - a
+    return dict(out)
+
+
+def top(totals: dict[str, float], n: int = 10,
+        rest: str | None = None) -> list[list]:
+    """The ``n`` largest entries; with ``rest``, where there are more,
+    the ``n - 1`` largest and the sum of the others under that name, so
+    that the list still sums to the whole."""
+    ranked = [[k, v] for k, v in sorted(totals.items(),
+                                        key=lambda kv: -kv[1])]
+    if rest is None or len(ranked) <= n:
+        return ranked[:n]
+    return ranked[:n - 1] + [[rest, sum(v for _, v in ranked[n - 1:])]]
 
 
 def summarize(ops_by_chip: dict[int, list], programs: list,
@@ -131,7 +168,7 @@ def summarize(ops_by_chip: dict[int, list], programs: list,
         "main_program_s": main,
         "programs": {k: [len(v), sum(v)] for k, v in by_program.items()},
         "device_ops": top(totals),
-        "idle_gaps": top(gap_attribution(first, host_spans)),
+        "idle_gaps": top(gap_shares(first, host_spans), rest=OTHER),
     }
 
 
@@ -161,7 +198,7 @@ def reduce_xplane(path: str) -> dict | None:
                 host_spans.extend(
                     (e.name, e.start_ns * 1e-9, e.duration_ns * 1e-9)
                     for e in line.events
-                    if e.name.startswith(HOST_SPAN_PREFIX))
+                    if HOST_SPAN.match(e.name))
     first = min(programs_by_chip, default=None)
     return summarize(ops_by_chip, programs_by_chip.get(first, []),
                      host_spans)

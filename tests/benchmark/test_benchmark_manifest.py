@@ -16,7 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark import harness  # noqa: E402
+from benchmark import harness, spread  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -114,6 +114,16 @@ def test_metric_is_well_formed_and_has_a_reader(group, name):
                    if _reports(metric, c))
         if name.endswith("_roofline") or "mfu" in name:
             assert metric["unit"] == "%"
+
+
+@pytest.mark.parametrize("metric", MANIFEST["end_to_end"],
+                         ids=lambda m: m["name"])
+def test_bound_is_a_whole_number_of_half_per_cents(metric):
+    # spread.bound_for's grid: five times the widest spread any set
+    # read, to the nearest half per cent, from 1% to 10%
+    steps = metric["bound"] / spread.BOUND_STEP
+    assert steps == pytest.approx(round(steps), abs=1e-9)
+    assert spread.BOUND_MIN <= metric["bound"] <= spread.BOUND_MAX
 
 
 def test_metric_names_are_unique_and_every_file_is_named():

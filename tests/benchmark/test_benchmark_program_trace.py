@@ -273,13 +273,14 @@ def test_a_gap_is_split_over_the_spans_it_crosses():
     and decode.wait after; the one from 9 to 12 crosses decode (to 9.5),
     step 1 (to 10), step 2's own time (to 11) and its decode; the one
     from 14 to 19.5 is half a millisecond of decode and 5 of its wait."""
-    assert pt.gap_shares(OPS, SPANS[:-1]) == pytest.approx({
+    ops = [o[:3] for o in OPS]
+    assert trace_reduce.gap_shares(ops, SPANS[:-1]) == pytest.approx({
         "serve.step": (0.5 + 0.5 + 1.0) * MS,
         "serve.decode": (0.5 + 0.5 + 1.0 + 0.5) * MS,
         "serve.decode.wait": (1.0 + 5.0) * MS})
-    assert sum(pt.gap_shares(OPS, SPANS).values()) == pytest.approx(
-        sum(trace_reduce.gap_attribution(
-            [o[:3] for o in OPS], [s[:3] for s in SPANS]).values()))
+    assert sum(trace_reduce.gap_shares(ops, SPANS).values()) == (
+        pytest.approx(sum(trace_reduce.gap_attribution(
+            ops, [s[:3] for s in SPANS]).values())))
 
 
 def test_program_span_names(tmp_path):

@@ -143,6 +143,24 @@ def test_harness_runs_a_cell_made_of_files(root, config, traffic, chips,
                                            "warm", "ramp"}
 
 
+def test_a_slow_collection_costs_the_window_nothing(root, monkeypatch):
+    """The runner collects garbage once before the window. In a test
+    worker that has run a few hundred tests a full collection takes a
+    second (it walks every object alive), longer than this ramp and
+    window together: the closed loop then served nothing in its window.
+    With a ramp too short to hold it, it comes before the clock starts."""
+    from benchmark.runners import serve
+    calls = []
+    monkeypatch.setattr(serve.gc, "collect",
+                        lambda: calls.append(time.sleep(1.0)))
+    rc, line, split = _run(root, "tiny_serve.two_clients", trace=False)
+    assert rc == 0 and len(calls) == 1
+    assert line["correct"] is True, split["failures"]
+    assert line["attempted"] > 0 and line["failed"] == 0
+    # the second that passed is set-up's, not the window's
+    assert split["setup_split_s"]["ramp"] > 1.0
+
+
 def test_traced_run_reports_the_per_layer_metrics(root):
     rc, line, split = _run(root, "tiny_serve.tiny_sessions", trace=True)
     assert rc == 0 and line["correct"] is True, split["failures"]

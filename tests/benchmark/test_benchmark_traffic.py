@@ -21,6 +21,8 @@ from benchmark.readers import device_trace  # noqa: E402
 
 SEEDS = (0, 7, 2**31 + 5)
 VOCAB = 32768
+with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
+    MANIFEST_RUN_SECONDS = json.load(_f)["run_seconds"]
 
 
 def _mix(name):
@@ -51,6 +53,10 @@ def test_open_sessions_keep_the_declared_rate_and_bursts():
     rate = mix["rate_rps"]
     assert rate == pytest.approx(mix["share_of_knee"] * mix["knee_rps"],
                                  rel=0.02)
+    # below the knee, where latency is the metric, and some hundreds of
+    # requests in the benchmark's window
+    assert 0.4 <= mix["share_of_knee"] <= 0.8
+    assert rate * MANIFEST_RUN_SECONDS >= 400
     due = [open_sessions.due_s(mix, i, rate) for i in range(161)]
     assert due[160] == pytest.approx(160 / rate)
     for i, t in enumerate(due[:160]):
@@ -174,6 +180,19 @@ def test_tokens_per_second_over_whole_steps():
     assert seconds == pytest.approx(0.95)
 
 
+@pytest.mark.parametrize("waiting,began,ended", [
+    # eight steps: the first two against the last two
+    ([0, 2, 4, 0, 1, 3, 5, 7], 1.0, 6.0),
+    # a queue that bursts of four fill and the engine drains: level
+    ([4, 3, 2, 1, 0, 4, 3, 2, 1, 0, 4, 3, 2, 1, 0, 4, 3, 2, 1, 0],
+     2.0, 2.0),
+    ([3], 3.0, 3.0), ([1, 5], 1.0, 5.0)])
+def test_the_queue_is_read_by_its_first_and_last_quarter(waiting, began,
+                                                         ended):
+    assert metric_math.quarter_means(waiting) == (
+        pytest.approx(began), pytest.approx(ended))
+
+
 @pytest.mark.parametrize("args,expected", [
     ({"stat": "ratio", "num": "tokens", "den": "elapsed_s"}, 500.0),
     ({"stat": "ratio", "num": "flops", "den": ["elapsed_s", "peak"],
@@ -242,6 +261,48 @@ def test_gaps_go_to_the_innermost_host_span_that_covers_them():
         trace_reduce.NO_SPAN: pytest.approx(1.5)}
 
 
+def test_a_gap_is_split_over_the_host_spans_it_crosses():
+    # 1.5..2.0 lies inside the engine step; 3.0..4.0 is the outer
+    # span's to 3.2 and from 3.8, the wait's between; the same seconds
+    # as whole gaps given to the span at their middle
+    shares = trace_reduce.gap_shares(OPS, SPANS)
+    assert shares == {"bench.engine_step": pytest.approx(0.5),
+                      "bench.wait_request": pytest.approx(0.6),
+                      "bench.outer": pytest.approx(0.4)}
+    assert sum(shares.values()) == pytest.approx(
+        sum(trace_reduce.gap_attribution(OPS, SPANS).values()))
+    # the program's spans beside the benchmark's: the innermost wins,
+    # and what no span covers is between steps
+    program = [("serve.step", 1.45, 0.5), ("serve.decode.wait", 1.6, 0.3)]
+    assert trace_reduce.gap_shares(OPS, SPANS[:1] + program) == {
+        "bench.engine_step": pytest.approx(0.05),       # 1.95..2.0
+        "serve.step": pytest.approx(0.1 + 0.05),        # to 1.6, from 1.9
+        "serve.decode.wait": pytest.approx(0.3),
+        trace_reduce.NO_SPAN: pytest.approx(1.0)}
+
+
+@pytest.mark.parametrize("name,named", [
+    ("bench.wait_request", True), ("serve.decode.wait", True),
+    ("kv.copy_on_write", True), ("serve.step", True),
+    ("PjitFunction(decode)", False), ("copy.1", False),
+    ("$profiler.py:91 trace", False), ("ThunkExecutor::Execute", False)])
+def test_host_spans_are_the_named_ones(name, named):
+    assert bool(trace_reduce.HOST_SPAN.match(name)) is named
+
+
+def test_a_cut_list_still_sums_to_the_whole():
+    totals = {f"span.n{chr(97 + i)}": float(20 - i) for i in range(14)}
+    assert trace_reduce.top(totals) == [
+        [f"span.n{chr(97 + i)}", float(20 - i)] for i in range(10)]
+    cut = trace_reduce.top(totals, rest=trace_reduce.OTHER)
+    assert len(cut) == 10 and cut[-1] == [
+        trace_reduce.OTHER, float(sum(20 - i for i in range(9, 14)))]
+    assert sum(v for _, v in cut) == pytest.approx(sum(totals.values()))
+    few = {"serve.step": 2.0, "_none_": 1.0}
+    assert trace_reduce.top(few, rest=trace_reduce.OTHER) == [
+        ["serve.step", 2.0], ["_none_", 1.0]]
+
+
 def test_summary_feeds_the_trace_reader():
     programs = [("jit_step.9", 0.0, 1.5), ("jit_step.9", 2.0, 1.0),
                 ("jit_other", 4.0, 0.1)]
@@ -251,6 +312,10 @@ def test_summary_feeds_the_trace_reader():
     assert summary["window_s"] == pytest.approx((5.0 + 2.5) / 2)
     assert summary["device_ops"][0] == ["fusion", 3.0]
     assert summary["main_program_s"] == [1.5, 1.0]
+    assert summary["idle_gaps"] == [
+        ["bench.wait_request", pytest.approx(0.6)],
+        ["bench.engine_step", pytest.approx(0.5)],
+        ["bench.outer", pytest.approx(0.4)]]
     read = lambda **a: device_trace.read(a, {}, summary)
     assert read(what="idle_share") == pytest.approx(
         100 * (1 - 2.75 / 3.75))
