@@ -74,6 +74,66 @@ LOGICAL_AXIS_RULES = (
 
 
 @dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """The shape of latent (MLA) attention: queries through a rank
+    ``q_rank`` bottleneck into heads of ``[nope_dim | rope_dim]``; keys
+    and values through one shared row of ``kv_rank`` values, normed, and
+    ``rope_dim`` rotary values shared by all heads, which is all a token
+    leaves in the cache (``row_dim``); heads of ``v_dim`` values.
+    ``scale_q`` / ``scale_kv`` multiply the normed bottlenecks by
+    ``sqrt(d_model / rank)``."""
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    scale_q: bool = False
+    scale_kv: bool = False
+
+    @property
+    def row_dim(self) -> int:
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertLayer:
+    """The shape of a layer of sparse experts and the share of it held
+    here: a router over ``n_routed`` gated feed-forwards of width
+    ``d_expert`` and then ``n_identity`` experts that return their input
+    ("zero-computation" experts), ``top_k`` of them a token, their
+    softmax scores times ``scaling`` as weights. Of the routed experts
+    this rank holds ``held`` (all of them when None), the first being
+    ``offset``; the identity experts have no weights and belong to the
+    token's own rank."""
+    n_routed: int
+    top_k: int
+    d_expert: int
+    n_identity: int = 0
+    scaling: float = 1.0
+    held: int | None = None
+    offset: int = 0
+
+    def __post_init__(self):
+        if self.held is None:
+            object.__setattr__(self, "held", self.n_routed)
+        if not 0 <= self.offset <= self.offset + self.held <= self.n_routed:
+            raise ValueError(
+                f"experts {self.offset}..{self.offset + self.held} held "
+                f"of {self.n_routed} routed")
+        if not 1 <= self.top_k <= self.n_routed + self.n_identity:
+            raise ValueError(f"top_k={self.top_k} of "
+                             f"{self.n_routed + self.n_identity} experts")
+
+    @property
+    def n_outputs(self) -> int:
+        return self.n_routed + self.n_identity
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32000
     d_model: int = 1024
@@ -157,11 +217,32 @@ class TransformerConfig:
     # the type the parameters are created and kept in (the compute type
     # stays ``dtype``); bfloat16 is a serving configuration's choice
     param_dtype: Any = jnp.float32
+    norm_eps: float = 1e-6
+    # The shape of a shortcut-connected layer of experts over latent
+    # attention (serving only: ``serving/decode.py`` and
+    # ``serving/experts.py`` run it, ``models/scmoe.py`` draws its
+    # weights, ``TransformerLM`` refuses it). ``latent`` replaces per-head
+    # K and V by one latent row a token (a :class:`LatentAttention`, or
+    # its fields as a dict); ``sub_blocks`` is the number of attention +
+    # feed-forward pairs a layer holds (each its own cache layer);
+    # ``experts`` gives a layer one sparse layer beside them (an
+    # :class:`ExpertLayer` or its fields), fed what the first pair's
+    # feed-forward is fed and added at the layer's end.
+    latent: Any = None
+    sub_blocks: int = 1
+    experts: Any = None
 
     def __post_init__(self):
         if self.passes < 1:
             raise ValueError(f"passes={self.passes}; a stack runs at "
                              f"least once")
+        for name, kind in (("latent", LatentAttention),
+                           ("experts", ExpertLayer)):
+            group = getattr(self, name)
+            if isinstance(group, dict):
+                object.__setattr__(self, name, kind(**group))
+        if self.sub_blocks < 1:
+            raise ValueError(f"sub_blocks={self.sub_blocks}")
 
     @property
     def head_dim(self) -> int:
@@ -380,7 +461,7 @@ class Block(nn.Module):
         cfg = self.cfg
 
         def norm(name=None, scale_init=1.0):
-            return RMSNorm(cfg.dtype, mesh=cfg.mesh,
+            return RMSNorm(cfg.dtype, eps=cfg.norm_eps, mesh=cfg.mesh,
                            param_dtype=cfg.param_dtype,
                            scale_init=scale_init, name=name)
 
@@ -431,6 +512,19 @@ class TransformerLM(nn.Module):
         recompute side of the serving KV-cache correctness contract).
         None (the default) is the historical full-sequence behavior."""
         cfg = self.cfg
+        for name, lacks in (
+                ("latent", "latent (MLA) attention"),
+                ("experts", "a layer of sparse experts held by share")):
+            if getattr(cfg, name) is not None:
+                raise NotImplementedError(
+                    f"TransformerLM has no {lacks} (cfg.{name}): the "
+                    f"training path does not run this shape; it is "
+                    f"served by serving.InferenceEngine over weights "
+                    f"from models.scmoe.init_params")
+        if cfg.sub_blocks != 1:
+            raise NotImplementedError(
+                f"TransformerLM has no layer of {cfg.sub_blocks} "
+                f"attention + feed-forward pairs (cfg.sub_blocks)")
         embed = param_with_axes(
             "embed", nn.initializers.normal(0.02),
             (cfg.vocab_size, cfg.d_model), cfg.param_dtype,
@@ -472,7 +566,7 @@ class TransformerLM(nn.Module):
             layers = [
                 (lambda x, b=block(cfg, name=f"layer_{i}"): b(x, lengths)[0])
                 for i in range(cfg.n_layers)]
-        final_norm = RMSNorm(cfg.dtype, mesh=cfg.mesh,
+        final_norm = RMSNorm(cfg.dtype, eps=cfg.norm_eps, mesh=cfg.mesh,
                              param_dtype=cfg.param_dtype, name="final_norm")
         # a looped stack: the same modules (so the same weights) at every
         # pass, the final norm after each, its output the next pass's input
@@ -1303,7 +1397,7 @@ def make_pipelined_train_step(cfg: TransformerConfig, mesh: Mesh,
         return out
 
     mb_spec = P(None, "dp" if "dp" in mesh.shape else None)
-    norm = RMSNorm(cfg.dtype)
+    norm = RMSNorm(cfg.dtype, eps=cfg.norm_eps)
 
     if schedule in ("1f1b", "interleaved"):
         def head_fn(head_params, y_mb, tokens_mb):

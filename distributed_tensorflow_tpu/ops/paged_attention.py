@@ -47,6 +47,16 @@ with the queries, of which the entries whose key head is the query's head
 are kept, and the value product is a second one. XLA's scatter writes that
 layout in place, so :func:`write_rows` needs no kernel there.
 
+**A latent pool** (``"latent"``: latent attention keeps ONE row of
+``latent_dim`` values a token, shared by all query heads, and no per-head
+K or V) is ``(L, rows, latent_dim)``, row-major as written, and has a
+kernel of its own (``paged_attn_decode_latent``): a grid step reads
+``LATENT_BLOCKS_PER_STEP`` blocks of a slot once for all heads, the
+logits are one matrix product of the heads' absorbed queries with those
+rows over the whole row, and the values are the rows' first ``v_dim``
+values, so the output is a latent vector a head that the caller expands.
+XLA's scatter writes that pool in place (:func:`write_latent_rows`).
+
 The token being decoded never comes out of the pool: its K and V are in
 registers when the step runs, so :func:`paged_attention_decode` merges
 that one key into the softmax after the kernel (the query sees its own
@@ -80,10 +90,14 @@ GROUP_ROWS = 128
 #: 128 KB (0.16 us at the v5e's 819 GB/s): one block a step read 17,700
 #: blocks in 9.1 ms (0.51 us each, 30% of the roofline; PERF.md, PR 28).
 BLOCKS_PER_STEP = 4
+#: Blocks of a latent pool one grid step reads: a 16-row block of 576
+#: bfloat16 values is 18 KB, and 8 of them are the 128 rows that fill the
+#: lanes of the logits and the contraction of the value product.
+LATENT_BLOCKS_PER_STEP = 8
 
 
 def supported(rows: int, block_size: int, head_dim: int, dtype,
-              n_heads: int | None = None) -> str | None:
+              n_heads: int | None = None, latent_dim: int = 0) -> str | None:
     """The layout a floating-point pool of this shape lies in on the
     device, if a kernel here reads it, else ``None``.
 
@@ -96,13 +110,17 @@ def supported(rows: int, block_size: int, head_dim: int, dtype,
     keys are one matrix operand, so the heads fill the sublane packing
     (asked only where ``n_heads`` is given) and blocks are a power of two
     of at most 128 rows. Any other ``head_dim`` pads on the device in a
-    way no kernel here reads."""
+    way no kernel here reads. ``"latent"``: a pool of latent rows
+    (``latent_dim`` > 0, no heads), row-major; read by blocks of whole
+    rows, which fill the dtype's sublane packing."""
     dtype = jnp.dtype(dtype)
     if not jnp.issubdtype(dtype, jnp.floating):
         return None
     packing = 8 * (4 // dtype.itemsize)
     if block_size & (block_size - 1) or not 8 <= block_size <= GROUP_ROWS:
         return None
+    if latent_dim:
+        return "latent" if block_size % packing == 0 else None
     if head_dim < GROUP_ROWS:
         return ("lanes" if rows % GROUP_ROWS == 0
                 and head_dim % packing == 0 else None)
@@ -156,9 +174,11 @@ def decode_plan(block_table, lengths, *, block_size: int) -> dict:
     }
 
 
-def block_plan(block_table, lengths, *, block_size: int) -> dict:
-    """The run list of one decode step over a ``"rows"`` pool, shared by
-    every cache layer: a *run* is ``BLOCKS_PER_STEP`` consecutive entries
+def block_plan(block_table, lengths, *, block_size: int,
+               per_step: int = BLOCKS_PER_STEP) -> dict:
+    """The run list of one decode step over a ``"rows"`` pool (or, with
+    ``per_step`` = ``LATENT_BLOCKS_PER_STEP``, a ``"latent"`` one), shared
+    by every cache layer: a *run* is ``per_step`` consecutive entries
     of a slot's table. Arguments as :func:`decode_plan`. Returns int32
     arrays: per run (flat, slot-major) its ``slot``, the ``rows`` of it
     that are live (counted from its first row) and its ``blocks``
@@ -166,7 +186,7 @@ def block_plan(block_table, lengths, *, block_size: int) -> dict:
     block 0, whose rows are never live); per slot the ``first`` run and
     the ``count`` of runs; and ``n_runs`` ``(1,)``, the grid's size."""
     B, M = block_table.shape
-    P = BLOCKS_PER_STEP
+    P = per_step
     C = -(-M // P)                                   # runs a slot at most
     lengths = lengths.astype(jnp.int32)
     n_blocks = (lengths + block_size - 1) // block_size           # (B,)
@@ -194,6 +214,9 @@ def block_plan(block_table, lengths, *, block_size: int) -> dict:
 
 def plan_for(layout: str, block_table, lengths, *, block_size: int) -> dict:
     """The run list the ``layout``'s kernel takes."""
+    if layout == "latent":
+        return block_plan(block_table, lengths, block_size=block_size,
+                          per_step=LATENT_BLOCKS_PER_STEP)
     plan = decode_plan if layout == "lanes" else block_plan
     return plan(block_table, lengths, block_size=block_size)
 
@@ -204,8 +227,10 @@ def count_runs(layout: str, block_table, n_blocks, block_size: int) -> int:
     ``n_runs`` reads, computed on the host with numpy for the engine's
     ``runs_read`` counter."""
     import numpy as np
-    if layout == "rows":
-        return int(np.sum(-(-n_blocks // BLOCKS_PER_STEP)))
+    if layout in ("rows", "latent"):
+        per_step = (BLOCKS_PER_STEP if layout == "rows"
+                    else LATENT_BLOCKS_PER_STEP)
+        return int(np.sum(-(-n_blocks // per_step)))
     group = block_table // (GROUP_ROWS // block_size)
     opens = np.ones_like(group, bool)
     opens[:, 1:] = group[:, 1:] != group[:, :-1]
@@ -467,6 +492,136 @@ def paged_attention_decode(q, k_new, v_new, k_pool, v_pool, layer, plan,
     out = ((w_pool * o + w_new * v_new.astype(jnp.float32))
            / (w_pool * l[..., None] + w_new))
     return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
+
+
+def _decode_latent_kernel(layer_ref, slot_ref, rows_ref, blocks_ref,
+                          first_ref, count_ref,             # scalar prefetch
+                          q_ref, *refs, sm_scale: float, v_dim: int):
+    """One run of a latent pool: ``LATENT_BLOCKS_PER_STEP`` blocks of one
+    slot's rows, ``(G, W)`` each, against the ``(H, W)`` absorbed queries
+    of all heads: the rows are read once. A row's first ``v_dim`` values
+    are its value."""
+    del layer_ref, blocks_ref                    # the index maps read them
+    P = LATENT_BLOCKS_PER_STEP
+    kv_refs = refs[:P]
+    o_ref, m_ref, l_ref, acc_scr, m_scr, l_scr = refs[P:]
+    i = pl.program_id(0)
+    b = slot_ref[i]
+    H = q_ref.shape[1]
+
+    @pl.when(i == first_ref[b])
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, DEFAULT_MASK_VALUE, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    kv = jnp.concatenate([r[...] for r in kv_refs], axis=0)  # (P * G, W)
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, kv.shape[0]), 1)
+    valid = col < rows_ref[i]
+    s = _dot(q_ref[0], kv, 1) * sm_scale                     # (H, P * G)
+    s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
+    m_prev = m_scr[...]                                      # (H, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[...] = m_new
+    acc_scr[...] = acc_scr[...] * alpha + _dot(p, kv[:, :v_dim], 0)
+
+    @pl.when(i == first_ref[b] + count_ref[b] - 1)
+    def _():
+        o_ref[0] = acc_scr[...]
+        m_ref[0] = jnp.broadcast_to(m_scr[...], (H, GROUP_ROWS))
+        l_ref[0] = jnp.broadcast_to(l_scr[...], (H, GROUP_ROWS))
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block_size",
+                                             "v_dim", "interpret"))
+def _latent_pool_attention(q, pool, layer, plan, *, sm_scale: float,
+                           block_size: int, v_dim: int, interpret: bool):
+    """The latent kernel call, as :func:`_pool_attention`: ``q`` (B, H, W)
+    over the rows of ``pool`` (L, rows, W) the plan lists, unnormalised:
+    float32 ``o`` (B, H, v_dim), ``m`` and ``l`` (B, H)."""
+    B, H, W = q.shape
+    P = LATENT_BLOCKS_PER_STEP
+
+    def slot_map(i, layer_r, slot_r, *_):
+        return (slot_r[i], 0, 0)
+
+    def block_map(n):
+        def index(i, layer_r, slot_r, rows_r, blocks_r, *_):
+            return (layer_r[0], blocks_r[i * P + n], 0)
+        return pl.BlockSpec((None, block_size, W), index)
+
+    stat = pl.BlockSpec((1, H, GROUP_ROWS), slot_map)
+    col = pltpu.VMEM((H, 1), jnp.float32)
+    o, m, l = pl.pallas_call(
+        functools.partial(_decode_latent_kernel, sm_scale=sm_scale,
+                          v_dim=v_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(plan["n_runs"][0],),
+            in_specs=[pl.BlockSpec((1, H, W), slot_map)]
+            + [block_map(n) for n in range(P)],
+            out_specs=[pl.BlockSpec((1, H, v_dim), slot_map), stat, stat],
+            scratch_shapes=[pltpu.VMEM((H, v_dim), jnp.float32), col, col],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, H, v_dim), jnp.float32)]
+        + [jax.ShapeDtypeStruct((B, H, GROUP_ROWS), jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_attn_decode_latent",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan["slot"], plan["rows"],
+      plan["blocks"], plan["first"], plan["count"], q, *([pool] * P))
+    return _unseen_masked(plan, o, m, l)
+
+
+def latent_attention_decode(q, row_new, pool, layer, plan, lengths, *,
+                            block_size: int, v_dim: int, sm_scale: float,
+                            interpret: bool = False):
+    """Latent attention of one query per slot over positions
+    ``0..length-1``, in the absorbed form: ``q`` (B, H, W) are the heads'
+    queries in the space of the cached rows (``W`` = latent_dim), a row
+    scores ``q . row`` over all ``W`` values and contributes its first
+    ``v_dim`` values. ``row_new`` (B, W) is the row of position ``lengths
+    - 1`` as the pool will hold it (merged into the softmax from
+    registers, as :func:`paged_attention_decode` does K and V); the
+    positions below it are read out of layer ``layer`` of ``pool`` (L,
+    rows, W) through ``plan`` = :func:`plan_for` ``"latent"`` of the block
+    table and ``lengths - 1``. Returns (B, H, v_dim) in ``q.dtype``; a
+    slot of length 0 returns zeros."""
+    o, m, l = _latent_pool_attention(q, pool, layer, plan, sm_scale=sm_scale,
+                                     block_size=block_size, v_dim=v_dim,
+                                     interpret=interpret)
+    new = row_new.astype(jnp.float32)
+    s_new = jnp.einsum("bhw,bw->bh", q.astype(jnp.float32), new,
+                       precision=jax.lax.Precision.HIGHEST) * sm_scale
+    top = jnp.maximum(m, s_new)
+    w_pool = jnp.exp(m - top)[..., None]
+    w_new = jnp.exp(s_new - top)[..., None]
+    out = ((w_pool * o + w_new * new[:, None, :v_dim])
+           / (w_pool * l[..., None] + w_new))
+    return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
+
+
+def write_latent_rows(pool, new, rows, active=None, layers=None):
+    """The latent pool (L, R, W) with row ``rows[n]`` of the cache layers
+    ``layers`` (Ln,; all of them when None) set to ``new[:, n]`` (``(Ln,
+    N, W)``) for every entry with ``active[n]`` (all when None), by XLA's
+    scatter, in place when the pool is donated (an inactive entry's index
+    is out of range and dropped). The scatter names (layer, row) pairs,
+    so that what it writes is whole rows of ``W`` values wherever they
+    lie: a scatter over the rows alone, with the layers as part of the
+    written window, has the device turn the whole pool round to bring a
+    row's layers together, and back (compiled for a described v5e)."""
+    at = rows.astype(jnp.int32)
+    if active is not None:
+        at = jnp.where(active, at, pool.shape[1])
+    if layers is None:
+        layers = jnp.arange(pool.shape[0], dtype=jnp.int32)
+    return pool.at[layers[:, None], at[None, :]].set(
+        new.astype(pool.dtype), mode="drop")
 
 
 def write_rows(k_pool, v_pool, k_new, v_new, rows, active, *,

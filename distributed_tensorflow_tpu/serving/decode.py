@@ -38,6 +38,14 @@ tree (stacked-layers layout) and the block-allocated KV pool
   plus the bonus position in one forward). At E=1 it is exactly
   :func:`make_decode_fn`.
 
+A model with latent attention (``cfg.latent``: one row a token and
+cache layer shared by all heads, in the pool array ``latent``) and
+shortcut-connected layers of sparse experts (``cfg.experts``,
+``cfg.sub_blocks``) is served by the same four builders: they hand such a
+configuration to the section "latent attention and the shortcut-connected
+layer" below, whose programs take the same arguments and return, after
+the logits and the pool, the layers' pick counts.
+
 Every program takes and returns the pool as ONE dict (``{"k", "v"}``
 plus ``{"k_scale", "v_scale"}`` when the cache config is int8): writes
 quantize on the way in, gathers dequantize on the way out, so the whole
@@ -64,7 +72,9 @@ import numpy as np
 from distributed_tensorflow_tpu.models.transformer import (
     TransformerConfig, TransformerLM, mesh_axis_rules, rotary_embedding)
 from distributed_tensorflow_tpu.ops import paged_attention
-from distributed_tensorflow_tpu.ops.attention import mha_reference
+from distributed_tensorflow_tpu.ops.attention import (
+    DEFAULT_MASK_VALUE, length_valid_mask, mha_reference)
+from distributed_tensorflow_tpu.serving.experts import COUNTS, expert_layer
 
 
 def _plain(tree):
@@ -135,7 +145,7 @@ def wants_resident(cfg: TransformerConfig) -> bool:
     model's, a nearly empty one 1.64 and 1.45: PERF.md section 6,
     PR 35). From 128 up it keeps ``(H, hd)`` tiles, the case
     ``resident_params`` describes."""
-    return (jnp.dtype(cfg.dtype).itemsize == 2
+    return (cfg.latent is None and jnp.dtype(cfg.dtype).itemsize == 2
             and cfg.head_dim >= paged_attention.GROUP_ROWS)
 
 
@@ -158,8 +168,10 @@ def compute_params(cfg: TransformerConfig, params, *,
         if name in params:
             params[name] = params[name].astype(dt)
     layers = dict(params["layers"])
-    for group in ("attn", "mlp"):
-        layers[group] = {n: w.astype(dt) for n, w in layers[group].items()}
+    for group in ("attn", "mlp", "moe"):
+        if group in layers:
+            layers[group] = {n: w.astype(dt)
+                             for n, w in layers[group].items()}
     params["layers"] = layers
     return resident_params(cfg, params) if resident else params
 
@@ -312,7 +324,8 @@ def _run_stack(cfg: TransformerConfig, params, x, carry, layer):
     bodies and kernel calls are never unrolled; the body of a pass lies
     under the scope ``loop.pass``."""
     def final_norm(x):
-        return _rms_norm(x, params["final_norm"]["scale"], cfg.dtype)
+        return _rms_norm(x, params["final_norm"]["scale"], cfg.dtype,
+                         cfg.norm_eps)
 
     if cfg.passes == 1:
         outs = []
@@ -366,21 +379,24 @@ def _post_norm(cfg: TransformerConfig, p, name: str, y):
     if not cfg.post_norms:
         return y
     with jax.named_scope("norm.post"):
-        return _rms_norm(y, p[name]["scale"], cfg.dtype)
+        return _rms_norm(y, p[name]["scale"], cfg.dtype, cfg.norm_eps)
+
+
+def _gated_mlp(dt, mlp, h):
+    """The SiLU-gated feed-forward of ``h``: ``wi`` holds gate then up."""
+    hh = jnp.einsum("...d,df->...f", h, mlp["wi"].astype(dt))
+    gate, up = jnp.split(hh, 2, axis=-1)
+    hh = jax.nn.silu(gate) * up
+    return jnp.einsum("...f,fd->...d", hh, mlp["wo"].astype(dt))
 
 
 def _mlp_residual(cfg: TransformerConfig, p, x):
     """``x`` plus the gated feed-forward of its norm."""
     dt = cfg.dtype
-    h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt)
-    mlp = p["mlp"]
+    h = _rms_norm(x, p["RMSNorm_1"]["scale"], dt, cfg.norm_eps)
     with jax.named_scope("mlp"):
-        hh = jnp.einsum("...d,df->...f", h, mlp["wi"].astype(dt))
-        gate, up = jnp.split(hh, 2, axis=-1)
-        hh = jax.nn.silu(gate) * up
-        return x + _post_norm(
-            cfg, p, "post_mlp_norm",
-            jnp.einsum("...f,fd->...d", hh, mlp["wo"].astype(dt)))
+        return x + _post_norm(cfg, p, "post_mlp_norm",
+                              _gated_mlp(dt, p["mlp"], h))
 
 
 def _logits(cfg: TransformerConfig, params, x):
@@ -411,7 +427,7 @@ def model_forward(cfg: TransformerConfig, params, tokens, lengths=None,
     x = params["embed"].astype(dt)[tokens]             # (B, S, D)
 
     def layer(x, carry, p, cl):
-        h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
+        h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt, cfg.norm_eps)
         att = p["attn"]
         q = _heads(h, att["query"].astype(dt), cfg.n_heads)
         k = _heads(h, att["key"].astype(dt), cfg.n_heads)
@@ -440,10 +456,11 @@ def _kernel_path(implementation, plain: str, cache_cfg):
     the kernels, compiled or interpreted; ``None`` is "paged" where a
     kernel reads the pool and the backend is a TPU, else ``plain``) and
     the layout the kernels of ``ops/paged_attention.py`` read this pool
-    in (``"lanes"`` / ``"rows"``), or a false value."""
+    in (``"lanes"`` / ``"rows"`` / ``"latent"``), or a false value."""
     layout = cache_cfg is not None and paged_attention.supported(
         cache_cfg.num_blocks * cache_cfg.block_size, cache_cfg.block_size,
-        cache_cfg.head_dim, cache_cfg.dtype, cache_cfg.n_heads)
+        cache_cfg.head_dim, cache_cfg.dtype, cache_cfg.n_heads,
+        cache_cfg.latent_dim)
     if implementation is None:
         implementation = ("paged" if layout
                           and jax.default_backend() == "tpu" else plain)
@@ -506,6 +523,8 @@ def make_prefill_fn(cfg: TransformerConfig, cache_cfg=None, *,
     the forward writes every cache layer's rows by blocks
     (``paged_attention.write_blocks``); the trash block is not written.
     ``"scatter"``: ``_pool_write``, also where the pool is row-major."""
+    if cfg.latent is not None or cfg.experts is not None:
+        return _make_latent_prefill_fn(cfg, cache_cfg, implementation)
     quantized = cache_cfg.quantized if cache_cfg is not None else False
     write = _block_writer(implementation, cache_cfg)
 
@@ -569,6 +588,8 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None, *,
         raise ValueError("incremental decode requires a causal model; "
                          "serve bidirectional (BERT) configs through the "
                          "prefill/scoring path")
+    if cfg.latent is not None or cfg.experts is not None:
+        return _make_latent_decode_fn(cfg, cache_cfg, implementation)
     quantized = cache_cfg.quantized if cache_cfg is not None else False
     implementation, layout = _kernel_path(implementation, "window",
                                           cache_cfg)
@@ -588,7 +609,7 @@ def make_decode_fn(cfg: TransformerConfig, cache_cfg=None, *,
                     block_size=bs)
 
         def layer(x, pool, p, cl):
-            h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
+            h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt, cfg.norm_eps)
             att = p["attn"]
             q = _heads(h, att["query"].astype(dt), cfg.n_heads)
             k = _heads(h, att["key"].astype(dt), cfg.n_heads)
@@ -671,6 +692,11 @@ def make_multi_decode_fn(step, steps: int):
     if steps < 2:
         raise ValueError(f"a multi-step decode runs 2 steps or more, "
                          f"not {steps}")
+    if getattr(step, "counts", False):
+        raise NotImplementedError(
+            "several decode steps a launch are not written for a program "
+            "that returns expert pick counts (a layer of sparse experts): "
+            "use decode_steps=1")
 
     def decode(params, pool, tokens, positions, lengths, write_rows, table,
                budget):
@@ -723,6 +749,8 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
         raise ValueError("extend requires a causal model; serve "
                          "bidirectional (BERT) configs through the "
                          "prefill/scoring path")
+    if cfg.latent is not None or cfg.experts is not None:
+        return _make_latent_extend_fn(cfg, cache_cfg, implementation)
     quantized = cache_cfg.quantized if cache_cfg is not None else False
     write = _block_writer(implementation, cache_cfg)
 
@@ -736,7 +764,7 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
             plan = _write_plan(rows, cache_cfg)         # every layer's
 
         def layer(x, pool, p, cl):
-            h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt)
+            h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt, cfg.norm_eps)
             att = p["attn"]
             q = _heads(h, att["query"].astype(dt), cfg.n_heads)
             k = _heads(h, att["key"].astype(dt), cfg.n_heads)
@@ -766,6 +794,399 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
 
     extend.kv_write = "scatter" if write is None else "paged"
     return extend
+
+
+# ---------------------------------------------------------------------------
+# latent attention and the shortcut-connected layer
+# ---------------------------------------------------------------------------
+#
+# A layer holds ``cfg.sub_blocks`` pairs of latent attention and gated
+# feed-forward and, with ``cfg.experts``, one layer of sparse experts fed
+# what the FIRST pair's feed-forward is fed and added at the layer's end
+# (the shortcut: on several ranks its exchange overlaps the second pair):
+#
+#     for i in range(sub_blocks):
+#         x = x + MLA_i(norm_in_i(x))
+#         h = norm_post_i(x)
+#         if i == 0: m = MoE(h)
+#         x = x + FFN_i(h)
+#     x = x + m
+#
+# Latent attention (MLA) of a token at position p: ``cq = norm(h Wqa)``,
+# ``q = cq Wqb`` in heads of ``[q_nope | q_rope]``; ``[c | kr] = h Wkva``,
+# ``c = norm(c)``; the token's CACHE ROW is ``[c | rope(kr, p)]``, shared
+# by all heads. Expanded form: ``[k_nope | v] = c Wkvb`` a head, ``k =
+# [k_nope | rope(kr)]``, softmax of ``q.k / sqrt(nope + rope)`` over the
+# visible rows, the heads' values through ``Wo``. Absorbed form: ``q_lat =
+# q_nope Wkvb_K`` so that a row scores ``q_lat.c + q_rope.rope(kr)`` as it
+# lies in the cache, the values are the rows' ``c``, and ``Wkvb_V`` then
+# ``Wo`` follow the softmax: nothing a head wide is made per cached row.
+# Prefill and extend run the expanded form, decode the absorbed one; the
+# two are the same function of the rows (tests/test_latent_serving.py).
+#
+# One layer's parameters, each leaf of ``attn``, ``mlp``, ``norm_in`` and
+# ``norm_post`` with a leading axis over the sub-blocks: ``attn``: ``q_a``
+# (D, q_rank), ``q_norm`` (q_rank,), ``q_b_nope`` (H x nope, q_rank) and
+# ``q_b_rope`` (H x rope, q_rank),
+# ``kv_a`` (D, kv_rank + rope), ``kv_norm`` (kv_rank,), ``kv_b_k`` (H,
+# kv_rank, nope), ``kv_b_v`` (H, kv_rank, v), ``out`` (H, v, D); ``mlp``
+# as every model's; ``moe`` as ``serving/experts.py`` says.
+
+def _mla_project(cfg: TransformerConfig, att, h, positions):
+    """``h`` (B, S, D) at ``positions`` (B, S) → the queries ``q_nope``
+    (B, H, S, nope) and ``q_rope`` (B, H, S, rope; rotated), and the
+    tokens' cache rows (B, S, kv_rank + rope)."""
+    la, dt, eps = cfg.latent, cfg.dtype, cfg.norm_eps
+    B, S, D = h.shape
+
+    def normed(x, scale, rank, scaled):
+        mult = (D / rank) ** 0.5 if scaled else 1.0
+        return _rms_norm(x, scale.astype(jnp.float32) * mult, dt, eps)
+
+    with jax.named_scope("mla.q"):
+        cq = normed(jnp.einsum("bsd,dr->bsr", h, att["q_a"].astype(dt)),
+                    att["q_norm"], la.q_rank, la.scale_q)
+        q_nope, q_rope = (
+            jnp.einsum("bsr,nr->bsn", cq, att[name].astype(dt)).reshape(
+                B, S, cfg.n_heads, -1).transpose(0, 2, 1, 3)
+            for name in ("q_b_nope", "q_b_rope"))
+        q_rope = rotary_at(q_rope, positions, base=cfg.rope_base)
+    with jax.named_scope("mla.kv"):
+        ckr = jnp.einsum("bsd,dr->bsr", h, att["kv_a"].astype(dt))
+        c = normed(ckr[..., :la.kv_rank], att["kv_norm"], la.kv_rank,
+                   la.scale_kv)
+        kr = rotary_at(ckr[:, None, :, la.kv_rank:], positions,
+                       base=cfg.rope_base)[:, 0]
+        return q_nope, q_rope, jnp.concatenate([c, kr], axis=-1)
+
+
+def _mla_out(cfg: TransformerConfig, att, o):
+    """The heads' values ``o`` (B, H, S, v) through the output matrix."""
+    return jnp.einsum("bhsv,hvd->bsd", o, att["out"].astype(cfg.dtype))
+
+
+def _mla_expanded(cfg: TransformerConfig, att, q_nope, q_rope, rows,
+                  lengths, q_positions=None):
+    """Attention of the queries over the cache rows ``rows`` (B, K, W) in
+    the expanded form: keys and values a head are made from every row.
+    Row ``j`` sits at position ``j``; the queries at ``q_positions``, or
+    ``0..S-1``."""
+    la, dt = cfg.latent, cfg.dtype
+    c, kr = rows[..., :la.kv_rank], rows[..., la.kv_rank:]
+    with jax.named_scope("mla.kv"):
+        k_nope = jnp.einsum("bkc,hcn->bhkn", c, att["kv_b_k"].astype(dt))
+        v = jnp.einsum("bkc,hcv->bhkv", c, att["kv_b_v"].astype(dt))
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(kr[:, None], k_nope.shape[:3]
+                                      + kr.shape[-1:])], axis=-1)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    with jax.named_scope("attn"):
+        o = mha_reference(q, k, v, causal=True, lengths=lengths,
+                          q_positions=q_positions,
+                          sm_scale=la.qk_dim ** -0.5)
+    return _mla_out(cfg, att, o)
+
+
+def _mla_absorbed(cfg: TransformerConfig, att, q_nope, q_rope, attend):
+    """One query a slot in the absorbed form: ``attend(q)`` takes the
+    heads' queries in the rows' own space (B, H, W) and returns the
+    softmax-weighted sum of the visible rows' first ``kv_rank`` values
+    (B, H, kv_rank): the cache access, whatever reads the pool."""
+    dt = cfg.dtype
+    with jax.named_scope("mla.q"):
+        q_lat = jnp.einsum("bhn,hcn->bhc", q_nope[:, :, 0],
+                           att["kv_b_k"].astype(dt))
+        q = jnp.concatenate([q_lat, q_rope[:, :, 0]], axis=-1)
+    o_lat = attend(q)
+    with jax.named_scope("mla.kv"):
+        o = jnp.einsum("bhc,hcv->bhv", o_lat, att["kv_b_v"].astype(dt))
+    return _mla_out(cfg, att, o[:, :, None])[:, 0]
+
+
+def _window_attend(cfg: TransformerConfig, q, rows, lengths, positions):
+    """The absorbed form's cache access in plain jnp: ``q`` (B, H, W) at
+    ``positions`` (B,) over a slot's window of rows (B, K, W)."""
+    la = cfg.latent
+    with jax.named_scope("attn"):
+        logits = jnp.einsum("bhw,bkw->bhk", q.astype(jnp.float32),
+                            rows.astype(jnp.float32)) * la.qk_dim ** -0.5
+        valid = length_valid_mask(lengths, 1, rows.shape[1], causal=True,
+                                  q_positions=positions)[:, :, 0]
+        probs = jax.nn.softmax(jnp.where(valid, logits, DEFAULT_MASK_VALUE),
+                               axis=-1)
+        probs = probs * jnp.any(valid, axis=-1, keepdims=True)
+        return jnp.einsum("bhk,bkc->bhc", probs,
+                          rows[..., :la.kv_rank].astype(jnp.float32)
+                          ).astype(q.dtype)
+
+
+def _shortcut_layer(cfg: TransformerConfig, params, l: int, x, attention,
+                    valid, expert_impl: str):
+    """Layer ``l``, as the section's head writes it. ``attention(i, att,
+    h)`` is sub-block ``i``'s latent attention of the normed stream
+    through whatever cache access the program has; ``valid`` marks the
+    real tokens of ``x``. Returns the stream and the expert layer's
+    counts (``experts.COUNTS``; None without one).
+
+    Every matrix is sliced out of the stacked tree where it is used, once
+    (a slice with one consumer fuses into it; a layer's slice shared by
+    its sub-blocks is copied, 600 MB a feed-forward here), and the
+    experts' stacks go to their kernel whole."""
+    dt, eps = cfg.dtype, cfg.norm_eps
+    layers = params["layers"]
+    moe = counts = None
+    for i in range(cfg.sub_blocks):
+        sub = jax.tree_util.tree_map(
+            lambda a: a[l, i], {n: layers[n] for n in (
+                "attn", "mlp", "norm_in", "norm_post")})
+        h = _rms_norm(x, sub["norm_in"]["scale"], dt, eps)
+        x = x + attention(i, sub["attn"], h)
+        h = _rms_norm(x, sub["norm_post"]["scale"], dt, eps)
+        if i == 0 and cfg.experts is not None:
+            tokens = valid.size
+            stacks = {n: layers["moe"][n].reshape(
+                (-1,) + layers["moe"][n].shape[2:]) for n in ("wi", "wo")}
+            moe, counts = expert_layer(
+                cfg.experts,
+                dict(stacks, router=layers["moe"]["router"][l],
+                     bias=layers["moe"]["bias"][l]),
+                h.reshape(tokens, -1), valid.reshape(tokens), dtype=dt,
+                implementation=expert_impl,
+                tile_rows=64 if tokens >= 256 else 16,
+                first_group=l * cfg.experts.held)
+        with jax.named_scope("mlp"):
+            x = x + _gated_mlp(dt, sub["mlp"], h)
+    if moe is not None:
+        x = x + moe.reshape(x.shape).astype(dt)
+    return x, counts
+
+
+def _latent_paths(cfg: TransformerConfig, cache_cfg, implementation,
+                  plain: str):
+    """``(paged, interpret, expert_impl)`` of a latent program: whether
+    its decode step reads the pool through the kernel, whether kernels
+    are interpreted, and how the experts' product runs: the kernels
+    together, compiled on a TPU (``None``, ``"paged"``) or interpreted,
+    the plain forms together (``plain``; ``None`` off the TPU)."""
+    if cfg.latent is None:
+        raise NotImplementedError(
+            "a layer of sparse experts (cfg.experts) is served only with "
+            "latent attention (cfg.latent): no program here runs experts "
+            "beside per-head K and V")
+    if cfg.passes != 1:
+        raise NotImplementedError("a looped stack (passes > 1) of latent "
+                                  "attention layers is not written")
+    if cache_cfg is None or not cache_cfg.latent_dim:
+        raise ValueError(f"latent attention needs a pool of latent rows "
+                         f"(CacheConfig.for_model), not {cache_cfg}")
+    implementation, _ = _kernel_path(implementation, plain, cache_cfg)
+    kernels = implementation != plain
+    interpret = implementation == "interpret"
+    return kernels, interpret, ("interpret" if interpret else
+                                "grouped" if kernels else "dense")
+
+
+def _pool_wide(x, pool):
+    """``x`` (..., W) padded with zeros to the width of the pool's rows
+    (whole 128-value tiles: ``CacheConfig.row_shape``)."""
+    pad = pool.shape[-1] - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _write_latent(pool: dict, layers, rows, new) -> dict:
+    """The latent pool with ``new`` (Ln, N, W) written to the flat
+    ``rows`` (N,) of the cache layers ``layers`` (Ln,)."""
+    with jax.named_scope("kv.write"):
+        pool = dict(pool)
+        pool["latent"] = paged_attention.write_latent_rows(
+            pool["latent"], _pool_wide(new, pool["latent"]), rows,
+            layers=layers)
+        return pool
+
+
+def _latent_window(cfg: TransformerConfig, pool: dict, cl: int, rows):
+    """Cache layer ``cl``'s rows ``rows`` (B, K) of the latent pool, (B,
+    K, W): one gather by (layer, row) out of the pool as it lies (a slice
+    of the layer first is an operation of a cache layer's size)."""
+    return pool["latent"][jnp.full_like(rows, cl), rows][
+        ..., :cfg.latent.row_dim]
+
+
+def _counts(outs):
+    """The layers' expert counts stacked ``(n_layers, len(COUNTS))``;
+    empty for a model without an expert layer."""
+    if outs[0] is None:
+        return jnp.zeros((0, len(COUNTS)), jnp.int32)
+    return jnp.stack(outs)
+
+
+def _make_latent_prefill_fn(cfg: TransformerConfig, cache_cfg,
+                            implementation):
+    """:func:`make_prefill_fn` for latent attention: ``prefill(params,
+    pool, tokens, lengths, write_rows)`` → ``(last_logits, pool,
+    counts)``. The whole prompt in the expanded form over the rows it
+    has just made (no cache access); every cache layer's rows are then
+    written by one scatter."""
+    _, _, expert_impl = _latent_paths(cfg, cache_cfg, implementation,
+                                      "scatter")
+
+    def prefill(params, pool, tokens, lengths, write_rows):
+        B, S = tokens.shape
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
+        valid = positions < lengths[:, None]
+
+        def layer(x, carry, p, l):
+            made = []
+
+            def attention(i, att, h):
+                q_nope, q_rope, rows = _mla_project(cfg, att, h, positions)
+                made.append(rows)
+                return _mla_expanded(cfg, att, q_nope, q_rope, rows,
+                                     lengths)
+
+            x, counts = _shortcut_layer(cfg, params, l, x, attention,
+                                        valid, expert_impl)
+            return x, carry, (jnp.stack(made), counts)
+
+        x, _, outs = _run_stack(cfg, params, x, None, layer)
+        x = x[jnp.arange(B), jnp.maximum(lengths, 1) - 1]
+        logits = _logits(cfg, params, x)
+        rows = jnp.stack([o[0] for o in outs])       # (L, sub, B, S, W)
+        pool = _write_latent(
+            pool, jnp.arange(cache_cfg.n_layers), write_rows.reshape(-1),
+            rows.reshape(-1, B * S, rows.shape[-1]))
+        return logits, pool, _counts([o[1] for o in outs])
+
+    prefill.kv_write = "scatter"
+    prefill.passes = cfg.passes
+    prefill.counts = True
+    return prefill
+
+
+def _make_latent_extend_fn(cfg: TransformerConfig, cache_cfg,
+                           implementation):
+    """:func:`make_extend_fn` for latent attention: ``extend(params,
+    pool, tokens, positions, lengths, write_rows, window_rows)`` →
+    ``(logits, pool, counts)``. Each cache layer's new rows are written,
+    then the slot's window of rows is gathered and attended in the
+    expanded form."""
+    _, _, expert_impl = _latent_paths(cfg, cache_cfg, implementation,
+                                      "scatter")
+    sub = cfg.sub_blocks
+
+    def extend(params, pool, tokens, positions, lengths, write_rows,
+               window_rows):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        valid = positions < lengths[:, None]
+        flat = write_rows.reshape(-1)
+
+        def layer(x, pool, p, l):
+            def attention(i, att, h):
+                nonlocal pool
+                q_nope, q_rope, rows = _mla_project(cfg, att, h, positions)
+                # write THEN gather: query i must see keys 0..i of the span
+                pool = _write_latent(pool, jnp.asarray([l * sub + i]), flat,
+                                     rows.reshape(1, flat.shape[0], -1))
+                with jax.named_scope("kv.gather"):
+                    window = _latent_window(cfg, pool, l * sub + i,
+                                            window_rows)
+                return _mla_expanded(cfg, att, q_nope, q_rope, window,
+                                     lengths, positions)
+
+            x, counts = _shortcut_layer(cfg, params, l, x, attention,
+                                        valid, expert_impl)
+            return x, pool, counts
+
+        x, pool, outs = _run_stack(cfg, params, x, pool, layer)
+        return _logits(cfg, params, x), pool, _counts(outs)
+
+    extend.kv_write = "scatter"
+    extend.counts = True
+    return extend
+
+
+def _make_latent_decode_fn(cfg: TransformerConfig, cache_cfg,
+                           implementation):
+    """:func:`make_decode_fn` for latent attention: ``decode(params,
+    pool, tokens, positions, lengths, write_rows, table)`` → ``(logits,
+    pool, counts)``, in the absorbed form. ``kv_path == "paged"``: per
+    cache layer one kernel over the slot's live blocks of latent rows
+    (``paged_attention.latent_attention_decode``), the new rows written
+    once after the last layer; ``"window"``: write, gather the slot's
+    window, attend in plain jnp."""
+    paged, interpret, expert_impl = _latent_paths(cfg, cache_cfg,
+                                                  implementation, "window")
+    la, sub = cfg.latent, cfg.sub_blocks
+
+    def decode(params, pool, tokens, positions, lengths, write_rows, table):
+        x = params["embed"].astype(cfg.dtype)[tokens][:, None]  # (B, 1, D)
+        pos_q = positions[:, None]
+        valid = lengths > 0
+        if paged:
+            with jax.named_scope("kv.gather"):
+                plan = paged_attention.plan_for(
+                    "latent", table, jnp.maximum(lengths - 1, 0),
+                    block_size=cache_cfg.block_size)
+        latent = pool["latent"]
+        made = []
+
+        def layer(x, pool, p, l):
+            def attention(i, att, h):
+                nonlocal pool
+                q_nope, q_rope, row = _mla_project(cfg, att, h, pos_q)
+                row = row[:, 0].astype(latent.dtype)
+                if paged:
+                    row = _pool_wide(row, latent)
+                    made.append(row)
+
+                    def attend(q):
+                        with jax.named_scope("kv.gather"):
+                            return paged_attention.latent_attention_decode(
+                                _pool_wide(q, latent), row, latent,
+                                l * sub + i, plan, lengths,
+                                block_size=cache_cfg.block_size,
+                                v_dim=la.kv_rank,
+                                sm_scale=la.qk_dim ** -0.5,
+                                interpret=interpret)
+                else:
+                    pool = _write_latent(pool, jnp.asarray([l * sub + i]),
+                                         write_rows, row[None])
+
+                    def attend(q):
+                        with jax.named_scope("kv.gather"):
+                            window = _latent_window(cfg, pool, l * sub + i,
+                                                    table)
+                        return _window_attend(cfg, q, window, lengths,
+                                              positions)
+                return _mla_absorbed(cfg, att, q_nope, q_rope,
+                                     attend)[:, None]
+
+            x, counts = _shortcut_layer(cfg, params, l, x, attention,
+                                        valid, expert_impl)
+            return x, pool, counts
+
+        # the paged path only reads the pool inside the stack
+        x, carried, outs = _run_stack(cfg, params, x,
+                                      None if paged else pool, layer)
+        logits = _logits(cfg, params, x[:, 0])
+        if paged:
+            with jax.named_scope("kv.write"):
+                # every kernel has read the pool before a row changes
+                latent, logits = jax.lax.optimization_barrier(
+                    (latent, logits))
+                pool = dict(pool)
+                pool["latent"] = paged_attention.write_latent_rows(
+                    latent, jnp.stack(made), write_rows, valid)
+        else:
+            pool = carried
+        return logits, pool, _counts(outs)
+
+    decode.kv_path = "paged" if paged else "window"
+    decode.kv_layout = "latent" if paged else None
+    decode.passes = cfg.passes
+    decode.counts = True
+    return decode
 
 
 def make_draft_fn(cfg: TransformerConfig):

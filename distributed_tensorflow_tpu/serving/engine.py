@@ -90,6 +90,7 @@ from distributed_tensorflow_tpu.models.transformer import (
 from distributed_tensorflow_tpu.ops import paged_attention
 from distributed_tensorflow_tpu.resilience import faults
 from distributed_tensorflow_tpu.serving import decode as decode_lib
+from distributed_tensorflow_tpu.serving.experts import COUNTS
 from distributed_tensorflow_tpu.serving.kv_cache import (
     TRASH_BLOCK, CacheConfig, HostTier, init_pool, pool_shardings)
 from distributed_tensorflow_tpu.serving.scheduler import (
@@ -201,6 +202,20 @@ class InferenceEngine:
         if role not in ("both", "prefill"):
             raise ValueError(f"role={role!r}; expected 'both' or "
                              f"'prefill'")
+        if cfg.latent is not None or cfg.experts is not None:
+            # what is not written for a latent pool or an expert layer
+            # refuses here, by name, before anything is built
+            for given, what in (
+                    (mesh is not None, "a mesh (a latent row has no head "
+                     "axis to shard; the expert layer's exchange between "
+                     "ranks is not written)"),
+                    (speculative_k, "speculative decoding (the draft "
+                     "and verify programs are not written for it)")):
+                if given:
+                    raise NotImplementedError(
+                        f"latent attention / a layer of sparse experts "
+                        f"is served on one device, one token a step: "
+                        f"not with {what}")
         self.cfg = cfg
         self.mesh = mesh
         #: "prefill" compiles no decode program: step() admits +
@@ -314,6 +329,10 @@ class InferenceEngine:
         #: ``program``
         self.kv_write = {"prefill": prefill.kv_write,
                          "extend": extend.kv_write if extend else None}
+        #: the held experts a program run could touch (every expert
+        #: layer's), for the spans' ``experts_held``; None without any
+        self._experts_held = (cfg.experts.held * cfg.n_layers
+                              if cfg.experts is not None else None)
         copy_fn = decode_lib.make_copy_fn()
 
         def gather_fn(pool, rows):
@@ -839,7 +858,7 @@ class InferenceEngine:
                 program=program, kv_write=self.kv_write[program],
                 blocks_written=((seq.prompt_len - 1) // bs - C // bs + 1
                                 if C else len(seq.table.blocks)),
-                passes=self.prefill_passes):
+                passes=self.prefill_passes) as psp:
             lengths = np.asarray([seq.prompt_len], np.int32)
             if C:
                 with telemetry.span("serve.prefill.build"):
@@ -857,7 +876,7 @@ class InferenceEngine:
                         np.arange(C, seq.prompt_len))
                     win = seq.table.window_rows()[None]
                 with telemetry.span("serve.prefill.launch"):
-                    logits, self.pool = self._extend_prefill(
+                    logits, self.pool, *picks = self._extend_prefill(
                         self.served_params, self.pool, jnp.asarray(toks),
                         jnp.asarray(pos), jnp.asarray(lengths),
                         jnp.asarray(rows), jnp.asarray(win))
@@ -868,13 +887,14 @@ class InferenceEngine:
                     toks[0, :seq.prompt_len] = seq.request.tokens
                     rows = seq.table.rows(np.arange(E))[None]   # (1, E)
                 with telemetry.span("serve.prefill.launch"):
-                    last, self.pool = self._prefill(
+                    last, self.pool, *picks = self._prefill(
                         self.served_params, self.pool, jnp.asarray(toks),
                         jnp.asarray(lengths), jnp.asarray(rows))
                     last = last[0]
             self.scheduler.commit_prefill(seq)
             with telemetry.span("serve.prefill.wait"):
                 first = int(np.asarray(jnp.argmax(last)))
+            psp.update(self._expert_counts(picks))
         self._m_prompt_tokens.increment(seq.prompt_len)
         if C:
             self._m_cached_tokens.increment(C)
@@ -937,13 +957,14 @@ class InferenceEngine:
                 else:
                     table[s] = seq.table.window_rows()
         with telemetry.span("serve.decode.launch"):
-            logits, self.pool = self._decode(
+            logits, self.pool, *picks = self._decode(
                 self.served_params, self.pool,
                 jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(lengths), jnp.asarray(write_rows),
                 jnp.asarray(table))
         with telemetry.span("serve.decode.wait"):
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            experts = self._expert_counts(picks)
         with telemetry.span("serve.decode.commit", tokens=len(batch)):
             emit = telemetry.enabled()
             for seq in batch:
@@ -955,6 +976,7 @@ class InferenceEngine:
         else:
             read = {"blocks_read": int(np.sum(-(-lengths // bs)))}
         read["token_steps"] = len(batch)
+        read.update(experts)
         if telemetry.recording():
             read["rows_read"] = int(lengths.sum())
             if paged:
@@ -962,6 +984,22 @@ class InferenceEngine:
                     self._kv_layout, table,
                     -(-np.maximum(lengths - 1, 0) // bs), bs)
         return read
+
+    def _expert_counts(self, picks) -> dict:
+        """What a program run's expert layers did, for its span: the
+        layers' counts (``experts.COUNTS``, the last thing a program with
+        expert layers returns) summed, ``experts_held`` (over the layers
+        too) and ``expert_layers``. Fetched
+        only while a span is recorded, after the read of the run's
+        tokens, which has waited for the run; nothing for a model
+        without an expert layer."""
+        if not picks or self._experts_held is None \
+                or not telemetry.recording():
+            return {}
+        totals = np.asarray(picks[0]).sum(axis=0)
+        return dict(zip(COUNTS, map(int, totals)),
+                    experts_held=self._experts_held,
+                    expert_layers=self.cfg.n_layers)
 
     # -- several decode steps a launch ------------------------------------
     def _decode_span(self, seq: Sequence) -> int:
@@ -1296,6 +1334,15 @@ class InferenceEngine:
                 "block_size": c.block_size, "n_layers": c.n_layers,
                 "n_heads": c.n_heads, "head_dim": c.head_dim}
 
+    def _migratable(self, what: str) -> None:
+        """Migration moves per-head K and V (``MigrationPayload``, its
+        wire format and the fingerprint name heads): a latent pool
+        refuses, before a slot or a block is touched."""
+        if self.cache_cfg.latent_dim:
+            raise NotImplementedError(
+                f"{what}: a pool of latent rows does not migrate "
+                f"(serving/migrate.py describes per-head K and V)")
+
     def _block_rows(self, blocks) -> np.ndarray:
         bs = self.cache_cfg.block_size
         return np.concatenate(
@@ -1321,6 +1368,7 @@ class InferenceEngine:
         monolithic step."""
         rid = seq.request.id
         sched = self.scheduler
+        self._migratable(f"export {rid}")
         if not seq.prefilled:
             raise ValueError(f"export {rid}: sequence not prefilled "
                              f"(nothing in the cache to migrate)")
@@ -1386,6 +1434,7 @@ class InferenceEngine:
         incompatible pool) and ``OutOfBlocksError`` when capacity is
         short (see :meth:`can_adopt`)."""
         rid = payload.request_id
+        self._migratable(f"adopt {rid}")
         fp = self.pool_fingerprint()
         if payload.fingerprint != fp:
             raise ValueError(

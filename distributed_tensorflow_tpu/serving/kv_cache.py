@@ -90,7 +90,22 @@ class CacheConfig:
     pass of a looped stack (every pass attends to the keys and values
     that pass produced), pass-major: cache layer ``pass * model layers
     + layer``. A block of the table stands for that many layer slices,
-    so sharing, copy-on-write and eviction never see the passes."""
+    so sharing, copy-on-write and eviction never see the passes.
+
+    What a cache layer keeps of a token is one of two kinds of row:
+    per-head K and V (``n_heads`` x ``head_dim`` each: the pool arrays
+    ``k`` and ``v``), or, with ``latent_dim`` > 0, ONE latent row of
+    that many values shared by all heads (latent attention: the normed
+    compressed key/value vector and the rotary key; the pool array
+    ``latent``; ``n_heads`` and ``head_dim`` are then 0). The pool's rows
+    are padded with zeros to whole 128-value tiles (576 values lie in
+    640): a TPU keeps an array whose minor dimension is no multiple of
+    128 with its rows on the lanes instead, and every program that wants
+    them row-major then turns the whole pool round (compiled for a
+    described v5e, PERF.md section 6, PR 37). A block is
+    ``block_size`` rows of every cache layer either way, so the
+    allocator, the tables, the prefix cache and eviction see no
+    difference."""
 
     n_layers: int
     n_heads: int
@@ -99,6 +114,7 @@ class CacheConfig:
     block_size: int = 16
     dtype: object = jnp.float32
     kv_dtype: str | None = None
+    latent_dim: int = 0
 
     def __post_init__(self):
         if self.num_blocks < 2:
@@ -112,10 +128,26 @@ class CacheConfig:
                     f"kv_dtype={self.kv_dtype!r}; expected one of "
                     f"{sorted(KV_DTYPES)}")
             object.__setattr__(self, "dtype", KV_DTYPES[self.kv_dtype])
+        if self.latent_dim and self.quantized:
+            raise NotImplementedError(
+                "an int8 pool quantizes per (row, head); a latent row "
+                "has no heads and no quantized form here: use a "
+                "floating-point kv_dtype")
 
     @property
     def quantized(self) -> bool:
         return jnp.dtype(self.dtype) == jnp.dtype(jnp.int8)
+
+    @property
+    def row_shape(self) -> tuple[int, ...]:
+        """One cached token in one pool array of one cache layer."""
+        return ((-(-self.latent_dim // 128) * 128,) if self.latent_dim
+                else (self.n_heads, self.head_dim))
+
+    @property
+    def pool_names(self) -> tuple[str, ...]:
+        """The pool's value arrays (an int8 pool adds their scales)."""
+        return ("latent",) if self.latent_dim else ("k", "v")
 
     @property
     def usable_blocks(self) -> int:
@@ -131,7 +163,7 @@ class CacheConfig:
         """Pool bytes one cached token costs (K + V over every cache
         layer, scales included for quantized dtypes) — the
         slots-per-chip arithmetic behind the README's KV-dtype table."""
-        per = 2 * self.n_heads * self.head_dim \
+        per = len(self.pool_names) * math.prod(self.row_shape) \
             * jnp.dtype(self.dtype).itemsize
         if self.quantized:
             per += 2 * self.n_heads * 4          # f32 scale per head
@@ -152,14 +184,18 @@ class CacheConfig:
                   block_size: int = 16, dtype=None,
                   kv_dtype: str | None = None) -> "CacheConfig":
         """Pool sized for a TransformerConfig-shaped model config: one
-        cache layer per layer and pass."""
+        cache layer per layer, pass and attention sub-block, of the kind
+        of row the model's attention keeps."""
+        latent = getattr(model_cfg, "latent", None)
         return cls(n_layers=model_cfg.n_layers
-                   * getattr(model_cfg, "passes", 1),
-                   n_heads=model_cfg.n_heads,
-                   head_dim=model_cfg.head_dim, num_blocks=num_blocks,
-                   block_size=block_size,
+                   * getattr(model_cfg, "passes", 1)
+                   * getattr(model_cfg, "sub_blocks", 1),
+                   n_heads=0 if latent else model_cfg.n_heads,
+                   head_dim=0 if latent else model_cfg.head_dim,
+                   num_blocks=num_blocks, block_size=block_size,
                    dtype=dtype if dtype is not None else model_cfg.dtype,
-                   kv_dtype=kv_dtype)
+                   kv_dtype=kv_dtype,
+                   latent_dim=latent.row_dim if latent else 0)
 
 
 class BlockAllocator:
@@ -767,15 +803,15 @@ class PrefixCache:
 
 
 def init_pool(cache_cfg: CacheConfig, mesh=None):
-    """Zero-initialized ``{"k", "v"}`` pools (plus ``k_scale`` /
+    """Zero-initialized ``{"k", "v"}`` pools (``{"latent"}`` for latent
+    rows; plus ``k_scale`` /
     ``v_scale`` per-(row, head) f32 scales when the config is int8-
     quantized), placed with :func:`pool_shardings` when a mesh is
     given."""
     rows = cache_cfg.num_blocks * cache_cfg.block_size
-    shape = (cache_cfg.n_layers, rows, cache_cfg.n_heads,
-             cache_cfg.head_dim)
-    pool = {"k": jnp.zeros(shape, cache_cfg.dtype),
-            "v": jnp.zeros(shape, cache_cfg.dtype)}
+    shape = (cache_cfg.n_layers, rows) + cache_cfg.row_shape
+    pool = {n: jnp.zeros(shape, cache_cfg.dtype)
+            for n in cache_cfg.pool_names}
     if cache_cfg.quantized:
         sshape = (cache_cfg.n_layers, rows, cache_cfg.n_heads)
         pool["k_scale"] = jnp.zeros(sshape, jnp.float32)
@@ -793,6 +829,10 @@ def pool_shardings(mesh, cache_cfg: CacheConfig | None = None) -> dict:
     SLOTS, and any slot's block gather may touch any physical row, so
     the row axis stays unsharded. Quantisation scales follow their
     pool's head axis."""
+    if cache_cfg is not None and cache_cfg.latent_dim:
+        raise NotImplementedError(
+            "a latent pool has no head axis to shard over tp: no mesh "
+            "layout is defined for it (serve it on one device)")
     head_axis = "tp" if "tp" in mesh.shape else None
     kv = NamedSharding(mesh, P(None, None, head_axis, None))
     out = {"k": kv, "v": kv}

@@ -154,6 +154,13 @@ class MigrationPayload:
     preemptions: int
     arrays: dict
 
+    def __post_init__(self):
+        if "k" not in self.arrays or "v" not in self.arrays:
+            raise NotImplementedError(
+                f"a migration payload holds per-head K and V block rows, "
+                f"not {sorted(self.arrays)}: a pool of latent rows has no "
+                f"payload or wire format here")
+
     @property
     def nbytes(self) -> int:
         return sum(a.nbytes for a in self.arrays.values())

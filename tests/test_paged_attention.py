@@ -814,3 +814,94 @@ def test_compiled_checksum_holds_no_copy_of_its_leaf(one_chip,
     memory = jax.jit(_leaf_checksum).lower(leaf).compile().memory_analysis()
     assert memory.argument_size_in_bytes == 48 * 2048 * 11264 * 2
     assert memory.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "extend"])
+def test_compiled_latent_programs_hold_nothing_of_the_pools_size(
+        one_chip, no_compile_cache, program):
+    """One shortcut-connected layer at the published widths of the
+    benchmark's latent configuration (hidden 6144, 64 heads over a row of
+    512 + 64, 16 held experts of width 2048; 1.24B parameters in
+    bfloat16) over its pool of 4,608 blocks, compiled for one v5e chip:
+    the latent decode kernel and the grouped expert product compile at
+    these widths, no program produces an array of the pool's or a cache
+    layer's size (the pool lies row-major, its rows in 640 values, and
+    the (layer, row) scatter writes it in place), and none converts,
+    copies or slices out a weight matrix (each is sliced where it is
+    used; the experts' stacks go to their kernel whole)."""
+    import chip_smoke
+    from distributed_tensorflow_tpu.models import scmoe
+
+    cfg = TransformerConfig(
+        vocab_size=16384, d_model=6144, n_layers=1, n_heads=64, d_ff=12288,
+        max_seq_len=1024, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+        tie_embeddings=False, rope_base=1e7, norm_eps=1e-5, sub_blocks=2,
+        latent=dict(q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+                    v_dim=128, scale_q=True, scale_kv=True),
+        experts=dict(n_routed=512, n_identity=256, top_k=12, d_expert=2048,
+                     scaling=6.0, held=16, offset=80))
+    cc = CacheConfig.for_model(cfg, num_blocks=4608, block_size=16)
+    assert (cc.n_layers, cc.row_shape, cc.bytes_per_token) == (2, (640,),
+                                                               2 * 1280)
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params: dict = {}
+    for path, (shape, _) in scmoe.param_plan(cfg).items():
+        node = params
+        for name in path[:-1]:
+            node = node.setdefault(name, {})
+        node[path[-1]] = spec(shape, jnp.bfloat16)
+    rows = cc.num_blocks * cc.block_size
+    pool = {"latent": spec((cc.n_layers, rows) + cc.row_shape, cc.dtype)}
+    slots, window = 64, cfg.max_seq_len
+    vec, one = spec((slots,)), spec((1,))
+    if program == "decode":
+        fn = decode_lib.make_decode_fn(cfg, cc, implementation="paged")
+        assert (fn.kv_path, fn.kv_layout) == ("paged", "latent")
+        args = (vec, vec, vec, vec, spec((slots, window // cc.block_size)))
+    elif program == "prefill":
+        fn = decode_lib.make_prefill_fn(cfg, cc, implementation="paged")
+        args = (spec((1, window)), one, spec((1, window)))
+    else:
+        fn = decode_lib.make_extend_fn(cfg, cc, implementation="paged")
+        wide = spec((1, 128))
+        args = (wide, wide, one, wide, spec((1, window)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *args).compile()
+    hlo = compiled.as_text()
+    def scatters(line):
+        # a fusion whose root is the scatter itself writes the donated
+        # pool where it lies (prefill's carries no op_name to say so)
+        called = re.search(r"calls=(%[\w.]+)", line)
+        body = hlo[hlo.index(f"\n{called.group(1)} ("):] if called else ""
+        return " scatter(" in body[:body.find("\n}")].rsplit("ROOT", 1)[-1]
+
+    found = [line for line in chip_smoke.pool_sized_ops(
+        hlo, {cc.n_layers * rows * cc.row_shape[0], rows * cc.row_shape[0]})
+        if not scatters(line)]
+    assert not found, found[:5]
+    memory = compiled.memory_analysis()
+    pool_bytes = rows * cc.bytes_per_token
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # what is left is the program's own activations: the decode step's
+    # are small, an admission's hold its attention scores and the
+    # worst-case layout of the expert product
+    assert memory.temp_size_in_bytes < ((64 << 20) if program == "decode"
+                                        else (1536 << 20))
+    assert not _weight_shaped_work(hlo, params)
+    # nor a matrix sliced out of its stack into an array of its own
+    matrices = {",".join(map(str, leaf.shape[-2:]))
+                for leaf in jax.tree_util.tree_leaves(params)
+                if np.prod(leaf.shape[-2:]) >= 1 << 20}
+    copies = [line.strip()[:160]
+              for line in hlo[hlo.index("\nENTRY "):].splitlines()
+              if (m := re.match(r"\s*(?:ROOT )?%\S+ = bf16\[(?:1,)*([0-9]+,"
+                                r"[0-9]+)\]\S* (copy|fusion|transpose)\(",
+                                line)) and m.group(1) in matrices]
+    assert not copies, copies[:5]
+    kernels = set(re.findall(r"%((?:paged_attn|expert)_\w+?)\.\d+ = ", hlo))
+    assert kernels == ({"paged_attn_decode_latent", "expert_grouped_matmul"}
+                       if program == "decode"
+                       else {"expert_grouped_matmul"})
