@@ -326,16 +326,15 @@ def program_check(engine: InferenceEngine) -> None:
     row = cc.n_heads * cc.head_dim
     rows = cc.num_blocks * cc.block_size
     whole, layer = cc.n_layers * rows * row, rows * row
-    i32 = jnp.zeros((slots,), jnp.int32)
-    one = jnp.ones((1,), jnp.int32)
-    table = jnp.zeros((slots, engine.window // cc.block_size), jnp.int32)
-    wide = jnp.zeros((1, engine.max_seq_len), jnp.int32)
+    chosen = jnp.zeros((slots,), jnp.int32)
     pool_bytes = engine.pool["k"].nbytes
-    # name: program, arguments after params and pool, the element counts
-    # no output may have, whether the temporaries are held under one
-    # pool array (a 1024-wide forward's own activations are not, beside
-    # this file's small pool)
-    programs = {"decode": (engine._decode, (i32, i32, i32, i32, table),
+    # name: the program the engine launches, what it takes after params
+    # and pool (the chosen tokens, the one array the host sends), the
+    # element counts no output may have, whether the temporaries are
+    # held under one pool array (a 1024-wide forward's own activations
+    # are not, beside this file's small pool)
+    host = jnp.zeros((slots, 4 + engine.window // cc.block_size), jnp.int32)
+    programs = {"decode": (engine._decode_next, (chosen, host),
                            {whole, layer, slots * engine.window * row},
                            True)}
     by_blocks = engine.kv_write["prefill"] == "paged"
@@ -345,14 +344,15 @@ def program_check(engine: InferenceEngine) -> None:
         # own attention matrix is not the pool's either, whatever it
         # counts (at this file's serving shapes, one cache layer)
         programs["prefill"] = (
-            engine._prefill, (wide, one, wide),
+            engine._prefill_next,
+            (chosen, jnp.zeros(2 + 2 * engine.max_seq_len, jnp.int32)),
             {whole, layer} - {cc.n_heads * engine.max_seq_len ** 2},
             not by_blocks)
     if engine.kv_write["extend"] == "paged":
-        span = jnp.zeros((1, min(64, engine.max_seq_len)), jnp.int32)
+        span = min(64, engine.max_seq_len)
         programs["extend"] = (
-            engine._extend_prefill,
-            (span, span, one, span, jnp.zeros((1, engine.window), jnp.int32)),
+            engine._extend_next,
+            (chosen, jnp.zeros(2 + 3 * span + engine.window, jnp.int32)),
             {whole}, False)
     for name, (program, args, sizes, small) in programs.items():
         compiled = program.lower(engine.served_params, engine.pool,

@@ -719,6 +719,98 @@ def make_multi_decode_fn(step, steps: int):
     return decode
 
 
+# ---------------------------------------------------------------------------
+# the engine's launches: the next token chosen on the device
+# ---------------------------------------------------------------------------
+#
+# An engine launches the next decode before it reads the last one's
+# tokens, so each slot's last chosen token lives on the device: a
+# (slots,) int32 vector ``chosen`` that every launch takes (not donated:
+# the host reads the copy a launch was fed) and returns updated. What the
+# host sends a launch is ONE int32 array (an upload costs about 0.25 ms
+# of host time whatever its size: PERF.md section 6, PR 38). A launch
+# returns ``(chosen, pool, scores, *counts)``: ``scores`` are the logits
+# it chose from (the multi-step decode: its tokens), which the engine
+# drops and a reference check reads (``InferenceEngine._prefill``).
+
+
+def _greedy(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def _named(run, program):
+    """``run`` under ``program``'s name: the compiled program keeps its
+    name (``jit_decode``, ``jit_prefill``, ``jit_extend``), which the
+    device trace and the benchmark's readers know it by."""
+    run.__name__ = run.__qualname__ = program.__name__
+    return run
+
+
+def launch_prefill(prefill):
+    """``prefill`` (:func:`make_prefill_fn`) as the engine launches it:
+    ``(params, pool, chosen, host)`` → ``(chosen, pool, last, *counts)``,
+    ``last`` the (1, V) logits of the prompt's last row. ``host``
+    (2 + 2S,) is ``[slot, prompt length, tokens (S,), pool rows (S,)]``,
+    the prompt right-padded to the program's width S; the greedy first
+    token lands in ``chosen[slot]``."""
+    def run(params, pool, chosen, host):
+        S = (host.shape[0] - 2) // 2
+        last, pool, *counts = prefill(params, pool, host[None, 2:2 + S],
+                                      host[1:2], host[None, 2 + S:])
+        return chosen.at[host[0]].set(_greedy(last[0])), pool, last, *counts
+    return _named(run, prefill)
+
+
+def launch_extend(extend, window: int):
+    """``extend`` (:func:`make_extend_fn`) for one admission's suffix, as
+    the engine launches it: ``(params, pool, chosen, host)`` →
+    ``(chosen, pool, last, *counts)``, ``last`` as
+    :func:`launch_prefill`'s. ``host`` (2 + 3E + window,) is ``[slot,
+    prompt length, tokens (E,), positions (E,), pool rows (E,), window
+    rows]``; the suffix's last real position is ``length - 1`` and its
+    greedy token lands in ``chosen[slot]``."""
+    def run(params, pool, chosen, host):
+        E = (host.shape[0] - 2 - window) // 3
+        positions = host[None, 2 + E:2 + 2 * E]
+        logits, pool, *counts = extend(
+            params, pool, host[None, 2:2 + E], positions, host[1:2],
+            host[None, 2 + 2 * E:2 + 3 * E], host[None, 2 + 3 * E:])
+        last = logits[0, host[1] - 1 - positions[0, 0]][None]
+        return chosen.at[host[0]].set(_greedy(last[0])), pool, last, *counts
+    return _named(run, extend)
+
+
+def launch_decode(decode, steps: int = 1):
+    """``decode`` (:func:`make_decode_fn`, or with ``steps`` > 1
+    :func:`make_multi_decode_fn`'s) as the engine launches it:
+    ``(params, pool, chosen, host)`` → ``(chosen, pool, scores, *counts)``.
+
+    ``host`` (slots, 3 + steps + T) holds a row a slot: ``[fed, length,
+    budget, pool rows (steps,), table (T,)]``. A slot is fed ``fed``, or
+    where that is -1 the token ``chosen`` holds (one the host has not
+    read), at position ``length - 1``; an idle slot has length 0 and
+    keeps its ``chosen``. One step: ``scores`` are the (slots, V) logits
+    and ``chosen`` each live slot's greedy next token. Several:
+    ``budget`` is each slot's inner steps, ``scores`` the ``(slots,
+    steps)`` tokens and ``chosen`` each slot's last of them."""
+    def run(params, pool, chosen, host):
+        fed, lengths, budget = host[:, 0], host[:, 1], host[:, 2]
+        rows, table = host[:, 3:3 + steps], host[:, 3 + steps:]
+        tokens = jnp.where(fed >= 0, fed, chosen)
+        positions = jnp.maximum(lengths - 1, 0)
+        if steps == 1:
+            logits, pool, *counts = decode(params, pool, tokens, positions,
+                                           lengths, rows[:, 0], table)
+            return (jnp.where(lengths > 0, _greedy(logits), tokens), pool,
+                    logits, *counts)
+        out, pool = decode(params, pool, tokens, positions, lengths, rows,
+                           table, budget)
+        last = jnp.take_along_axis(out, jnp.maximum(budget - 1, 0)[:, None],
+                                   axis=1)[:, 0]
+        return jnp.where(budget > 0, last, tokens), pool, out
+    return _named(run, decode)
+
+
 def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
                    implementation: str | None = None):
     """``extend(params, pool, tokens, positions, lengths, write_rows,

@@ -17,6 +17,16 @@ One :class:`InferenceEngine` is one serving replica's model runtime:
   under the token budget, prefill the newly admitted, decode one token
   for every running sequence. Greedy (argmax) sampling — the decode
   path's output is exactly comparable to full-sequence recompute.
+  Launch ahead: the argmax runs inside each program, into a (slots,)
+  vector of each slot's last chosen token that stays on the device, so
+  a step launches its decode BEFORE it reads the last decode's tokens
+  and its admissions' first ones (which the vector that decode is fed
+  holds): the host's launch, read-back and bookkeeping overlap the
+  device's work. A sequence whose read token ends it had one more
+  token-step in flight, which is dropped; where the host needs every
+  token (a preemption's replay, a version install, a migration, an
+  engine left with nothing running) it drains first (``serve.drain``).
+  Speculation stays synchronous: its drafts are built from read tokens.
 - **Telemetry** — every step is a ``serve.step`` span; every completed
   request emits a ``serve.request`` event whose ``dur_s`` is the
   queue→completion latency (both render in tools/obs_report.py and as
@@ -72,6 +82,7 @@ tests/test_serving_speed.py pins):
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import itertools
 import os
@@ -103,6 +114,21 @@ _pool_epochs = itertools.count()
 #: configuration: a hot-swap's new weights run it again
 _compute_params = jax.jit(decode_lib.compute_params, static_argnums=(0,),
                           static_argnames=("resident",))
+
+
+@dataclasses.dataclass
+class _Launch:
+    """A decode launch whose tokens the host has not read: each
+    sequence it advanced with the tokens it launched for it, where those
+    lie on the device (``tokens`` (slots, k) for several steps a launch;
+    None: in the ``chosen`` vector it returned), the expert counts it
+    returned, and what it read of the pool (``counts``), which the span
+    of its read carries."""
+
+    batch: list
+    tokens: object
+    picks: list
+    counts: dict
 
 
 def request_span_id(request_id: str) -> str:
@@ -197,7 +223,6 @@ class InferenceEngine:
                  spill_tier: "HostTier | int | None" = None,
                  snapshot_step: int | None = None):
         if cfg.mesh is not None:
-            import dataclasses
             cfg = dataclasses.replace(cfg, mesh=None)
         if role not in ("both", "prefill"):
             raise ValueError(f"role={role!r}; expected 'both' or "
@@ -313,7 +338,7 @@ class InferenceEngine:
             decode = decode_lib.make_multi_decode_fn(decode,
                                                      self.decode_steps)
         #: how the decode program reaches the pool ("paged" / "window"):
-        #: decides which table _decode_batch hands it
+        #: decides which table _launch_decode hands it
         self.kv_path = decode.kv_path if decode is not None else None
         #: the passes over the stack each program was BUILT to run (the
         #: spans report what the step's program does, not a config file)
@@ -334,6 +359,13 @@ class InferenceEngine:
         self._experts_held = (cfg.experts.held * cfg.n_layers
                               if cfg.experts is not None else None)
         copy_fn = decode_lib.make_copy_fn()
+        # what the engine launches: the same programs, each choosing its
+        # greedy token into the device's ``chosen`` vector (step())
+        launch_prefill = decode_lib.launch_prefill(prefill)
+        launch_extend = (decode_lib.launch_extend(extend, self.window)
+                         if extend is not None else None)
+        launch_decode = (decode_lib.launch_decode(decode, self.decode_steps)
+                         if decode is not None else None)
 
         def gather_fn(pool, rows):
             return {n: pool[n][:, rows] for n in pool}
@@ -351,33 +383,9 @@ class InferenceEngine:
             rep = NamedSharding(mesh, P())
             slotv = NamedSharding(mesh, P(dp))
             slotm = NamedSharding(mesh, P(dp, None))
-            self._prefill = jax.jit(
-                prefill,
-                in_shardings=(shardings, pool_sh, rep, rep, rep),
-                out_shardings=(rep, pool_sh),
-                donate_argnums=(1,))
-            # one step: write_rows (B,), logits out; several: write_rows
-            # (B, k) and each slot's budget, tokens (B, k) out
-            many = self.decode_steps > 1
-            self._decode = jax.jit(
-                decode,
-                in_shardings=(shardings, pool_sh, slotv, slotv, slotv,
-                              slotm if many else slotv, slotm)
-                + ((slotv,) if many else ()),
-                out_shardings=(slotm, pool_sh),
-                donate_argnums=(1,)) \
-                if decode is not None else None
-            # the extend program serves two batch shapes: suffix
-            # prefill is (1, E) — too narrow to shard over dp, so it
-            # runs replicated like prefill — and speculative verify is
-            # (max_slots, k+1), sharded over dp like decode
-            self._extend_prefill = jax.jit(
-                extend,
-                in_shardings=(shardings, pool_sh, rep, rep, rep, rep,
-                              rep),
-                out_shardings=(rep, pool_sh),
-                donate_argnums=(1,)) \
-                if extend is not None else None
+            # speculative verify runs the extend program at (max_slots,
+            # k+1), sharded over dp like decode (a suffix prefill's
+            # (1, E) launch runs replicated, like prefill)
             self._extend_spec = jax.jit(
                 extend,
                 in_shardings=(shardings, pool_sh, slotm, slotm, slotv,
@@ -401,16 +409,44 @@ class InferenceEngine:
                 insert_fn, in_shardings=(pool_sh, rep, rep),
                 out_shardings=pool_sh,
                 donate_argnums=(0,))
+            self._prefill_next = jax.jit(
+                launch_prefill, in_shardings=(shardings, pool_sh, slotv, rep),
+                out_shardings=(slotv, pool_sh, rep), donate_argnums=(1,))
+            self._extend_next = jax.jit(
+                launch_extend, in_shardings=(shardings, pool_sh, slotv, rep),
+                out_shardings=(slotv, pool_sh, rep), donate_argnums=(1,)) \
+                if extend is not None else None
+            self._decode_next = jax.jit(
+                launch_decode,
+                in_shardings=(shardings, pool_sh, slotv, slotm),
+                out_shardings=(slotv, pool_sh, slotm),
+                donate_argnums=(1,)) if decode is not None else None
+            chosen = jax.device_put(jnp.zeros(max_slots, jnp.int32), slotv)
         else:
-            self._prefill = jax.jit(prefill, donate_argnums=(1,))
-            self._decode = (jax.jit(decode, donate_argnums=(1,))
-                            if decode is not None else None)
-            self._extend_prefill = (jax.jit(extend, donate_argnums=(1,))
-                                    if extend is not None else None)
-            self._extend_spec = self._extend_prefill
+            self._extend_spec = (jax.jit(extend, donate_argnums=(1,))
+                                 if extend is not None else None)
             self._copy = jax.jit(copy_fn, donate_argnums=(0,))
             self._gather = jax.jit(gather_fn)
             self._insert = jax.jit(insert_fn, donate_argnums=(0,))
+            self._prefill_next = jax.jit(launch_prefill, donate_argnums=(1,))
+            self._extend_next = (jax.jit(launch_extend, donate_argnums=(1,))
+                                 if extend is not None else None)
+            self._decode_next = (jax.jit(launch_decode, donate_argnums=(1,))
+                                 if decode is not None else None)
+            chosen = jnp.zeros(max_slots, jnp.int32)
+        #: each slot's last chosen token, on the device: every launch
+        #: takes it and returns it updated (the host reads the copy a
+        #: launch was fed, never waiting on that launch)
+        self._chosen = chosen
+        #: the decode launch whose tokens are not read yet (_Launch)
+        self._launched: _Launch | None = None
+        #: admitted sequences whose first token is not read yet, each
+        #: with its prefill's counts (on the device)
+        self._firsts: list[tuple[Sequence, list]] = []
+        #: completion records made outside step()'s own retire (a drain
+        #: before a preemption), handed out by the step
+        self._retired: list[dict] = []
+        self.scheduler.drain_hook = self._drain_for_preemption
 
         # shared inference namespace (Model.predict reports here too)
         reg = telemetry.get_registry()
@@ -639,6 +675,7 @@ class InferenceEngine:
                 "structure/shapes/dtypes); rebuild the engine for an "
                 "architecture change")
         previous = self.weights_version
+        self._drain("swap")
         requeued = self.scheduler.requeue_running()
         self.params, self.served_params = params, served
         if self.spec_k and self._draft_default:
@@ -824,9 +861,40 @@ class InferenceEngine:
             self.pool = self._copy(self.pool, jnp.asarray(src),
                                    jnp.asarray(dst))
 
+    # the launches called as the plain programs are, for a check of
+    # their logits (the benchmark's latent runner): the compiled programs
+    # are the ones the engine serves with, the ``chosen`` they return
+    # is dropped
+    def _prefill(self, params, pool, tokens, lengths, rows):
+        """``(params, pool, tokens (1, S), lengths (1,), rows (1, S))`` →
+        ``(last logits (1, V), pool, *counts)``, S the engine's
+        ``max_seq_len``."""
+        host = np.concatenate([[0], np.asarray(lengths), np.asarray(tokens)[0],
+                               np.asarray(rows)[0]]).astype(np.int32)
+        _, pool, last, *counts = self._prefill_next(
+            params, pool, self._chosen, jnp.asarray(host))
+        return (last, pool, *counts)
+
+    def _decode(self, params, pool, tokens, positions, lengths,
+                write_rows, table):
+        """One decode step over every slot: ``(params, pool, tokens,
+        positions, lengths, write_rows, table)`` → ``(logits (slots, V),
+        pool, *counts)``; ``positions`` must be ``lengths - 1``, as the
+        launch places each slot's token."""
+        lengths = np.asarray(lengths)
+        if not np.array_equal(np.asarray(positions), lengths - 1):
+            raise ValueError("a decode step writes at lengths - 1")
+        host = np.column_stack([tokens, lengths, np.ones_like(lengths),
+                                write_rows, table]).astype(np.int32)
+        _, pool, logits, *counts = self._decode_next(
+            params, pool, self._chosen, jnp.asarray(host))
+        return (logits, pool, *counts)
+
     def _prefill_one(self, seq: Sequence):
-        """Run one admitted sequence's prompt through the compiled
-        prefill and bank its first greedy token.
+        """Launch one admitted sequence's prompt through the compiled
+        prefill; its greedy first token lands in the device's ``chosen``
+        vector and is read by the step's read (:meth:`_read`), after the
+        step's decode is launched.
 
         Cold path: the full prompt through ``prefill`` (fixed
         (1, max_seq_len) shape — wider than max_prompt_len so a
@@ -843,7 +911,7 @@ class InferenceEngine:
                       if submit_mono is not None else None)
         C = seq.cached_tokens
         S = seq.prompt_len - C                      # suffix to compute
-        bs = self.cache_cfg.block_size
+        bs, W = self.cache_cfg.block_size, self.window
         program = "extend" if C else "prefill"
         E = (min(self.max_seq_len, 1 << max(3, (S - 1).bit_length()))
              if C else self.max_seq_len)            # program's width
@@ -858,51 +926,40 @@ class InferenceEngine:
                 program=program, kv_write=self.kv_write[program],
                 blocks_written=((seq.prompt_len - 1) // bs - C // bs + 1
                                 if C else len(seq.table.blocks)),
-                passes=self.prefill_passes) as psp:
-            lengths = np.asarray([seq.prompt_len], np.int32)
-            if C:
-                with telemetry.span("serve.prefill.build"):
+                passes=self.prefill_passes):
+            with telemetry.span("serve.prefill.build"):
+                # one upload: [slot, length, tokens, (positions,) rows
+                # (padding -> the trash block's row 0), (window rows)]
+                if C:
                     # a partially-matched tail block is SHARED: copy it
                     # before the suffix writes into it (and before the
                     # row indices below are derived from the table)
                     self._apply_copies(seq.table.ensure_writable(
                         C, seq.prompt_len, self.scheduler.allocator))
-                    toks = np.zeros((1, E), np.int32)
-                    toks[0, :S] = seq.request.tokens[C:]
-                    pos = np.full((1, E), self.window, np.int32)
-                    pos[0, :S] = np.arange(C, seq.prompt_len)
-                    rows = np.zeros((1, E), np.int32)  # pad -> trash row
-                    rows[0, :S] = seq.table.rows(
+                    host = np.zeros(2 + 3 * E + W, np.int32)
+                    host[2:2 + S] = seq.request.tokens[C:]
+                    host[2 + E:2 + 2 * E] = W       # pad -> masked query
+                    host[2 + E:2 + E + S] = np.arange(C, seq.prompt_len)
+                    host[2 + 2 * E:2 + 2 * E + S] = seq.table.rows(
                         np.arange(C, seq.prompt_len))
-                    win = seq.table.window_rows()[None]
-                with telemetry.span("serve.prefill.launch"):
-                    logits, self.pool, *picks = self._extend_prefill(
-                        self.served_params, self.pool, jnp.asarray(toks),
-                        jnp.asarray(pos), jnp.asarray(lengths),
-                        jnp.asarray(rows), jnp.asarray(win))
-                    last = logits[0, S - 1]
-            else:
-                with telemetry.span("serve.prefill.build"):
-                    toks = np.zeros((1, E), np.int32)
-                    toks[0, :seq.prompt_len] = seq.request.tokens
-                    rows = seq.table.rows(np.arange(E))[None]   # (1, E)
-                with telemetry.span("serve.prefill.launch"):
-                    last, self.pool, *picks = self._prefill(
-                        self.served_params, self.pool, jnp.asarray(toks),
-                        jnp.asarray(lengths), jnp.asarray(rows))
-                    last = last[0]
-            self.scheduler.commit_prefill(seq)
-            with telemetry.span("serve.prefill.wait"):
-                first = int(np.asarray(jnp.argmax(last)))
-            psp.update(self._expert_counts(picks))
+                    host[2 + 3 * E:] = seq.table.window_rows()
+                    launch = self._extend_next
+                else:
+                    host = np.zeros(2 + 2 * E, np.int32)
+                    host[2:2 + seq.prompt_len] = seq.request.tokens
+                    host[2 + E:] = seq.table.rows(np.arange(E))
+                    launch = self._prefill_next
+                host[:2] = seq.slot, seq.prompt_len
+            with telemetry.span("serve.prefill.launch"):
+                self._chosen, self.pool, _, *picks = launch(
+                    self.served_params, self.pool, self._chosen,
+                    jnp.asarray(host))
+        self.scheduler.commit_prefill(seq)
+        self.scheduler.launched(seq, 1)
+        self._firsts.append((seq, picks))
         self._m_prompt_tokens.increment(seq.prompt_len)
         if C:
             self._m_cached_tokens.increment(C)
-        if seq.request.max_new_tokens > 0:
-            self.scheduler.append_token(seq, first)
-        else:
-            seq.first_token_s = time.monotonic()
-            seq.score_token = first                    # scoring request
 
     def _emit_token(self, seq: Sequence):
         # per-token decode breadcrumb on the request's span: index
@@ -916,171 +973,192 @@ class InferenceEngine:
                    + len(seq.generated)),
             step=self._step_idx)
 
-    def _decode_batch(self, batch: list[Sequence]) -> dict:
-        """One incremental token for every running sequence. The decode
-        program has a fixed (max_slots,) batch; idle slots feed trash
-        rows with length 0 and their logits are never read. Returns what
-        the step's KV read touches, for the ``serve.decode`` span:
-        ``blocks_read``, ``token_steps`` (the tokens the launch computed:
-        one a sequence) and, while a span is recorded, ``rows_read`` (the
-        live rows: Σ lengths) and on the paged path ``runs_read`` (the
-        kernel's grid steps per cache layer)."""
-        B, W = self.max_slots, self.window
+    def _decode_span(self, seq: Sequence) -> int:
+        """The tokens one decode launch gives ``seq``: ``decode_steps``,
+        capped by the request's output budget left after the tokens in
+        flight and by the sequence-length ceiling."""
+        return max(1, min(self.decode_steps, seq.to_launch,
+                          self.max_seq_len - seq.length + 1))
+
+    def _launch_decode(self, batch: list[Sequence]) -> dict:
+        """Launch the decode program for every sequence of ``batch``
+        without reading anything: a sequence whose last token the host
+        has not read is fed the device's own (``chosen``). One launch
+        gives a sequence ``_decode_span`` tokens: one, or with
+        ``decode_steps`` = k up to k, each inner step fed the token the
+        last one chose. Slots outside the batch idle (length 0, the
+        trash block's row 0). The launch is read by the NEXT
+        :meth:`_read`; what it will have read of the pool travels with
+        it (``_Launch.counts``: ``token_steps``, ``blocks_read``, and
+        while a span is recorded ``rows_read`` and on the paged path
+        ``runs_read``). Returns the ``serve.decode`` span's ``launched``
+        and ``ahead`` (1 where a token the host had not read was fed)."""
+        B, W, K = self.max_slots, self.window, self.decode_steps
         paged = self.kv_path == "paged"
         bs = self.cache_cfg.block_size
+        T = W // bs if paged else W
         with telemetry.span("serve.decode.build"):
-            tokens = np.zeros(B, np.int32)
-            positions = np.zeros(B, np.int32)
-            lengths = np.zeros(B, np.int32)
-            write_rows = np.zeros(B, np.int32)     # trash block row 0
-            # the paged program walks each slot's block table; the window
-            # program gathers each slot's whole window of rows
-            table = (np.full((B, W // bs), TRASH_BLOCK, np.int32) if paged
-                     else np.zeros((B, W), np.int32))
-            for seq in batch:
-                s = seq.slot
-                if self.prefix_caching:
-                    # the write at position length-1 must not land in
-                    # a block a prefix-cache sibling shares: copy-on-
-                    # write first (without a cache no block is shared)
-                    self._apply_copies(seq.table.ensure_writable(
-                        seq.length - 1, seq.length,
-                        self.scheduler.allocator))
-                # feed the last banked token at position length-1 (it
-                # was appended by the previous prefill/decode step)
-                tokens[s] = seq.last_token
-                positions[s] = seq.length - 1
-                lengths[s] = seq.length
-                write_rows[s] = seq.table.row_of(seq.length - 1)
-                if paged:
-                    table[s, :len(seq.table.blocks)] = seq.table.blocks
-                else:
-                    table[s] = seq.table.window_rows()
-        with telemetry.span("serve.decode.launch"):
-            logits, self.pool, *picks = self._decode(
-                self.served_params, self.pool,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(lengths), jnp.asarray(write_rows),
-                jnp.asarray(table))
-        with telemetry.span("serve.decode.wait"):
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            experts = self._expert_counts(picks)
-        with telemetry.span("serve.decode.commit", tokens=len(batch)):
-            emit = telemetry.enabled()
-            for seq in batch:
-                self.scheduler.append_token(seq, int(nxt[seq.slot]))
-                if emit:
-                    self._emit_token(seq)
-        if not paged:
-            read = {"blocks_read": B * (W // bs)}
-        else:
-            read = {"blocks_read": int(np.sum(-(-lengths // bs)))}
-        read["token_steps"] = len(batch)
-        read.update(experts)
-        if telemetry.recording():
-            read["rows_read"] = int(lengths.sum())
+            # one upload, a row a slot: [fed, length, budget, the rows
+            # its K steps write, its table: the paged program walks the
+            # slot's blocks, the window program gathers its window]
+            host = np.zeros((B, 3 + K + T), np.int32)
+            host[:, 0] = -1                         # fed: the device's
             if paged:
-                read["runs_read"] = paged_attention.count_runs(
-                    self._kv_layout, table,
-                    -(-np.maximum(lengths - 1, 0) // bs), bs)
-        return read
+                host[:, 3 + K:] = TRASH_BLOCK
+            for seq in batch:
+                s, n, L = seq.slot, self._decode_span(seq), seq.length
+                if self.prefix_caching:
+                    # the writes at positions L-1.. must not land in a
+                    # block a prefix-cache sibling shares: copy-on-write
+                    # first (without a cache no block is shared)
+                    self._apply_copies(seq.table.ensure_writable(
+                        L - 1, L - 1 + n, self.scheduler.allocator))
+                if not seq.unread:
+                    host[s, 0] = seq.last_token
+                host[s, 1:3] = L, n
+                host[s, 3:3 + n] = [seq.table.row_of(L - 1 + i)
+                                    for i in range(n)]
+                if paged:
+                    host[s, 3 + K:3 + K + len(seq.table.blocks)] = \
+                        seq.table.blocks
+                else:
+                    host[s, 3 + K:] = seq.table.window_rows()
+        with telemetry.span("serve.decode.launch"):
+            self._chosen, self.pool, scores, *picks = self._decode_next(
+                self.served_params, self.pool, self._chosen,
+                jnp.asarray(host))
+        ahead = any(seq.unread for seq in batch)
+        budget = host[:, 2]
+        for seq in batch:
+            self.scheduler.launched(seq, int(budget[seq.slot]))
+        # inner step i of a slot sees length + i rows while i < budget
+        rows = np.where(np.arange(K) < budget[:, None],
+                        host[:, 1:2] + np.arange(K), 0)        # (B, K)
+        counts = {"token_steps": int(budget.sum()),
+                  "blocks_read": (B * (W // bs) * K if not paged
+                                  else int(np.sum(-(-rows // bs)))),
+                  "passes": self.decode_passes,
+                  "cache_layers": self.cache_cfg.n_layers}
+        if telemetry.recording():
+            counts["rows_read"] = int(rows.sum())
+            if paged:
+                counts["runs_read"] = sum(
+                    paged_attention.count_runs(
+                        self._kv_layout, host[:, 3 + K:],
+                        -(-np.maximum(rows[:, i] - 1, 0) // bs), bs)
+                    for i in range(K))
+        self._launched = _Launch(
+            [(seq, int(budget[seq.slot])) for seq in batch],
+            scores if K > 1 else None, picks, counts)
+        return {"launched": 1, "ahead": int(ahead)}
+
+    def _fetch(self, chosen, rec: "_Launch | None", firsts: list):
+        """Wait for and copy to the host, in one transfer, what a read
+        takes: ``chosen`` (the step reads the vector its own decode
+        launch was fed, so the read waits for the programs before that
+        launch and not for it), the tokens of the decode launch ``rec``
+        where they lie apart, and while a span is recorded the expert
+        counts of ``rec`` and of the admissions ``firsts``."""
+        picks = telemetry.recording() and self._experts_held is not None
+        want = (chosen, rec.tokens if rec is not None else None,
+                rec.picks[:1] if rec is not None and picks else [],
+                [p[:1] for _, p in firsts] if picks else [])
+        return jax.device_get(want)
+
+    def _commit(self, fetched, rec: "_Launch | None",
+                firsts: list) -> tuple[dict, int]:
+        """Bank what :meth:`_fetch` read: the decode launch's tokens,
+        then the admissions' first tokens. A token chosen after its
+        sequence ended (an end-of-sequence token read since it was
+        launched) or for a sequence no longer running is dropped, its
+        position given back. Returns the read launch's counts (for the
+        span of its read; empty when there was none) and the tokens
+        banked from it."""
+        chosen, tokens, picks, first_picks = fetched
+        sched, emit = self.scheduler, telemetry.enabled()
+        counts, banked = {}, 0
+        if rec is not None:
+            for seq, n in rec.batch:
+                got = (tokens[seq.slot, :n] if tokens is not None
+                       else chosen[seq.slot:seq.slot + 1])
+                used = 0
+                if sched.running.get(seq.slot) is seq:
+                    for t in got:
+                        if seq.done:
+                            break
+                        sched.read_token(seq, t)
+                        used += 1
+                        if emit:
+                            self._emit_token(seq)
+                sched.discard(seq, n - used)
+                banked += used
+            counts = dict(rec.counts, **self._expert_counts(picks))
+        for i, (seq, _) in enumerate(firsts):
+            first = int(chosen[seq.slot])
+            rid = seq.request.id
+            with telemetry.span("serve.prefill.commit", id=rid,
+                                span_id=request_span_id(rid),
+                                **self._expert_counts(
+                                    first_picks[i] if first_picks else [])):
+                live = sched.running.get(seq.slot) is seq
+                if live and seq.request.max_new_tokens:
+                    sched.read_token(seq, first)
+                    continue
+                sched.discard(seq, 1)
+                if live:                    # a scoring request's 'token'
+                    seq.first_token_s = time.monotonic()
+                    seq.score_token = first
+        return counts, banked
+
+    def _read(self, chosen, rec: "_Launch | None" = None) -> dict:
+        """The step's read, in ``serve.decode`` sub-spans: the decode
+        launch ``rec`` and the step's admissions, from ``chosen``.
+        Returns ``rec``'s counts, for the span of its read."""
+        firsts, self._firsts = self._firsts, []
+        if rec is None and not firsts:
+            return {}
+        with telemetry.span("serve.decode.wait"):
+            fetched = self._fetch(chosen, rec, firsts)
+        with telemetry.span("serve.decode.commit") as sp:
+            counts, sp["tokens"] = self._commit(fetched, rec, firsts)
+        return counts
+
+    def _drain(self, reason: str) -> bool:
+        """Read everything in flight now, where the host needs every
+        token (a preemption's replay, a version install, a migration, an
+        engine left with nothing running). True when there was something
+        to read."""
+        rec, firsts = self._launched, self._firsts
+        if rec is None and not firsts:
+            return False
+        self._launched, self._firsts = None, []
+        with telemetry.span("serve.drain", reason=reason) as sp:
+            sp["tokens"] = self._commit(
+                self._fetch(self._chosen, rec, firsts), rec, firsts)[1]
+        return True
+
+    def _drain_for_preemption(self) -> bool:
+        """The scheduler's ``drain_hook``: drain before a victim is
+        chosen, and retire what the tokens read ended."""
+        if not self._drain("preempt"):
+            return False
+        for seq in list(self.scheduler.finished()):
+            self._retired.append(self._complete(seq))
+        return True
 
     def _expert_counts(self, picks) -> dict:
         """What a program run's expert layers did, for its span: the
         layers' counts (``experts.COUNTS``, the last thing a program with
         expert layers returns) summed, ``experts_held`` (over the layers
-        too) and ``expert_layers``. Fetched
-        only while a span is recorded, after the read of the run's
-        tokens, which has waited for the run; nothing for a model
-        without an expert layer."""
-        if not picks or self._experts_held is None \
-                or not telemetry.recording():
+        too) and ``expert_layers``. Fetched only while a span is
+        recorded, with the run's tokens; nothing for a model without an
+        expert layer."""
+        if not picks or self._experts_held is None:
             return {}
         totals = np.asarray(picks[0]).sum(axis=0)
         return dict(zip(COUNTS, map(int, totals)),
                     experts_held=self._experts_held,
                     expert_layers=self.cfg.n_layers)
-
-    # -- several decode steps a launch ------------------------------------
-    def _decode_span(self, seq: Sequence) -> int:
-        """The tokens one launch of the multi-step decode program yields
-        ``seq``: ``decode_steps``, capped by the request's remaining
-        output budget and the sequence-length ceiling."""
-        return max(1, min(
-            self.decode_steps,
-            seq.request.max_new_tokens - len(seq.generated),
-            self.max_seq_len - seq.length + 1))
-
-    def _decode_steps_batch(self, batch: list[Sequence]) -> dict:
-        """:meth:`_decode_batch` for ``decode_steps`` > 1: ONE launch of
-        ``make_multi_decode_fn``'s program gives every running sequence
-        up to ``decode_steps`` tokens (:meth:`_decode_span`), each inner
-        step fed the token the last one chose; the host reads them all
-        at once and commits each sequence's up to its end. Returns what
-        :meth:`_decode_batch` returns, summed over the inner steps, and
-        ``token_steps``: the tokens the launch computed (Σ spans)."""
-        B, W, K = self.max_slots, self.window, self.decode_steps
-        paged = self.kv_path == "paged"
-        bs = self.cache_cfg.block_size
-        with telemetry.span("serve.decode.build"):
-            tokens = np.zeros(B, np.int32)
-            positions = np.zeros(B, np.int32)
-            lengths = np.zeros(B, np.int32)
-            budget = np.zeros(B, np.int32)
-            write_rows = np.zeros((B, K), np.int32)  # trash block row 0
-            table = (np.full((B, W // bs), TRASH_BLOCK, np.int32) if paged
-                     else np.zeros((B, W), np.int32))
-            for seq in batch:
-                s, n = seq.slot, self._decode_span(seq)
-                if self.prefix_caching:
-                    self._apply_copies(seq.table.ensure_writable(
-                        seq.length - 1, seq.length - 1 + n,
-                        self.scheduler.allocator))
-                tokens[s] = seq.last_token
-                positions[s] = seq.length - 1
-                lengths[s] = seq.length
-                budget[s] = n
-                write_rows[s, :n] = [seq.table.row_of(seq.length - 1 + i)
-                                     for i in range(n)]
-                if paged:
-                    table[s, :len(seq.table.blocks)] = seq.table.blocks
-                else:
-                    table[s] = seq.table.window_rows()
-        with telemetry.span("serve.decode.launch"):
-            chosen, self.pool = self._decode(
-                self.served_params, self.pool,
-                jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(lengths), jnp.asarray(write_rows),
-                jnp.asarray(table), jnp.asarray(budget))
-        with telemetry.span("serve.decode.wait"):
-            chosen = np.asarray(chosen)                      # (B, K)
-        with telemetry.span("serve.decode.commit") as sp:
-            emit = telemetry.enabled()
-            committed = 0
-            for seq in batch:
-                for t in chosen[seq.slot, :budget[seq.slot]]:
-                    self.scheduler.append_token(seq, int(t))
-                    committed += 1
-                    if emit:
-                        self._emit_token(seq)
-                    if seq.done:            # an end-of-sequence token
-                        break
-            sp["tokens"] = committed
-        # inner step i of a slot sees lengths + i rows while i < budget
-        rows = np.where(np.arange(K) < budget[:, None],
-                        lengths[:, None] + np.arange(K), 0)     # (B, K)
-        read = {"token_steps": int(budget.sum()),
-                "blocks_read": (B * (W // bs) * K if not paged
-                                else int(np.sum(-(-rows // bs))))}
-        if telemetry.recording():
-            read["rows_read"] = int(rows.sum())
-            if paged:
-                read["runs_read"] = sum(
-                    paged_attention.count_runs(
-                        self._kv_layout, table,
-                        -(-np.maximum(rows[:, i] - 1, 0) // bs), bs)
-                    for i in range(K))
-        return read
 
     # -- speculative decoding ---------------------------------------------
     def _spec_span(self, seq: Sequence) -> int:
@@ -1221,13 +1299,16 @@ class InferenceEngine:
                     ssp["evicted_blocks"] = cache.evictions - evict0
             for seq in admitted:
                 self._prefill_one(seq)
-            # scoring requests (max_new_tokens=0) finish at prefill
-            for seq in list(sched.finished()):
-                finished.append(self._complete(seq))
+            firsts = [seq for seq, _ in self._firsts]
             batch = []
-            if self._decode is not None:
+            if self._decode_next is None:
+                self._read(self._chosen)    # prefill only: read at once
+            else:
                 with telemetry.span("serve.decode") as dsp:
                     if self.spec_k:
+                        # drafts are built from the tokens the host has
+                        # read: speculation reads first and runs in step
+                        self._read(self._chosen)
                         spec_before = self._spec_proposed_n
                         acc_before = self._spec_accepted_n
                         batch = sched.grow_for_decode(
@@ -1239,17 +1320,27 @@ class InferenceEngine:
                         sp["accepted_drafts"] = (self._spec_accepted_n
                                                  - acc_before)
                     else:
-                        many = self.decode_steps > 1
-                        batch = sched.grow_for_decode(
-                            self._decode_span if many else 1)
+                        # launch this step's decode behind the
+                        # admissions, THEN read the last decode's tokens
+                        # and the admissions' first ones: the vector
+                        # this launch is fed holds them all
+                        batch = sched.grow_for_decode(self._decode_span)
                         dsp["kv_path"] = self.kv_path
+                        fed, last = self._chosen, self._launched
+                        self._launched = None
                         if batch:
-                            dsp.update(self._decode_steps_batch(batch)
-                                       if many
-                                       else self._decode_batch(batch))
-                            dsp["passes"] = self.decode_passes
-                            dsp["cache_layers"] = self.cache_cfg.n_layers
+                            dsp.update(self._launch_decode(batch))
+                        dsp.update(self._read(fed, last))
                     dsp["live"] = len(batch)
+            # admissions that their first token ended (scoring requests,
+            # a budget of one, an end token) finish at prefill
+            for seq in firsts:
+                if seq.done and sched.running.get(seq.slot) is seq:
+                    finished.append(self._complete(seq))
+            finished += self._retired
+            self._retired.clear()
+            if not sched.running:
+                self._drain("idle")
             sp["admitted"] = len(admitted)
             sp["decoded"] = len(batch)
             sp["finished"] = len(finished)
@@ -1369,6 +1460,7 @@ class InferenceEngine:
         rid = seq.request.id
         sched = self.scheduler
         self._migratable(f"export {rid}")
+        self._drain("export")
         if not seq.prefilled:
             raise ValueError(f"export {rid}: sequence not prefilled "
                              f"(nothing in the cache to migrate)")
@@ -1443,6 +1535,7 @@ class InferenceEngine:
         sched = self.scheduler
         bs = self.cache_cfg.block_size
         n_blocks = payload.arrays["k"].shape[1] // bs
+        self._drain("adopt")
         t0 = time.monotonic()
         with telemetry.span("kv.migrate", id=rid,
                             span_id=migrate_span_id(rid),
@@ -1525,6 +1618,7 @@ class InferenceEngine:
             except FaultInjected:
                 if not retry_faults:
                     raise
+        self._drain("idle")
         return out
 
     def generate(self, prompts, *, max_new_tokens: int = 16,

@@ -103,6 +103,9 @@ class Sequence:
         #: engine prefills only positions cached_tokens..prompt_len-1
         self.cached_tokens = cached_tokens
         self.generated: list[int] = []
+        #: tokens launched and counted in ``length`` whose values the
+        #: host has not read yet (chosen on the device)
+        self.unread = 0
         self.prefilled = False
         self.admitted_s = time.monotonic()
         self.first_token_s: float | None = None
@@ -121,6 +124,12 @@ class Sequence:
     def last_token(self) -> int:
         return (self.generated[-1] if self.generated
                 else self.request.tokens[-1])
+
+    @property
+    def to_launch(self) -> int:
+        """Tokens the request may still be given beyond those read and
+        those in flight."""
+        return self.request.max_new_tokens - len(self.generated) - self.unread
 
     @property
     def done(self) -> bool:
@@ -238,6 +247,11 @@ class ContinuousBatchingScheduler:
         #: preemption victim (migrate its live KV to another replica)
         #: instead of the replay requeue. See _preempt_newest.
         self.preempt_hook = None
+        #: optional callable() -> bool installed by the engine: read every
+        #: token in flight (and retire what that finishes) before a
+        #: preemption, so a victim's replay carries all it generated;
+        #: True when it read something, and the growth is tried again
+        self.drain_hook = None
         self.migrated_out = 0
         self.prefix_cache = (PrefixCache(self.allocator,
                                          cache_cfg.block_size)
@@ -337,7 +351,7 @@ class ContinuousBatchingScheduler:
         triggers newest-first preemption until the growth fits. Returns
         the decode batch."""
         batch = [s for s in self.running.values() if s.prefilled
-                 and not s.done]
+                 and not s.done and s.to_launch > 0]
         batch.sort(key=lambda s: s.slot)
         for seq in list(batch):
             if seq not in batch:
@@ -351,6 +365,14 @@ class ContinuousBatchingScheduler:
                     self._ensure_room(seq.table, n)
                     break
                 except OutOfBlocksError:
+                    if self.drain_hook is not None and self.drain_hook():
+                        # tokens in flight are read and what they ended
+                        # is retired: the batch and the free room differ
+                        batch[:] = [s for s in batch if not s.done
+                                    and self.running.get(s.slot) is s]
+                        if seq not in batch:
+                            break
+                        continue
                     victim = self._preempt_newest(exclude=seq)
                     if victim is None:
                         raise       # nothing left to preempt: misconfig
@@ -456,10 +478,33 @@ class ContinuousBatchingScheduler:
         return seq
 
     def append_token(self, seq: Sequence, token: int):
+        """A token chosen and read in one step (speculation's commit)."""
         seq.table.length += 1
+        self._bank(seq, token)
+
+    def launched(self, seq: Sequence, n: int):
+        """``n`` tokens of ``seq`` launched: their positions are taken
+        (``length``), their values are on the device until read."""
+        seq.table.length += n
+        seq.unread += n
+
+    def read_token(self, seq: Sequence, token: int):
+        """A launched token's value, read: it joins ``generated``."""
+        seq.unread -= 1
+        self._bank(seq, token)
+
+    @staticmethod
+    def _bank(seq: Sequence, token: int):
         seq.generated.append(int(token))
         if seq.first_token_s is None:
             seq.first_token_s = time.monotonic()
+
+    def discard(self, seq: Sequence, n: int):
+        """``n`` launched tokens of ``seq`` dropped unread (chosen after
+        its end, or for a sequence no longer running, or a scoring
+        request's one): their positions are given back."""
+        seq.unread -= n
+        seq.table.length -= n
 
     def finish(self, seq: Sequence):
         """Retire a finished sequence: blocks back to the pool, slot

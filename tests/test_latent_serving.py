@@ -295,14 +295,17 @@ def test_spans_carry_the_expert_counters(served):
         assert (e["experts_held"], e["expert_layers"]) == (
             CFG.experts.held * CFG.n_layers, CFG.n_layers)
         assert e["kv_path"] == "window" and e["rows_read"] > 0
+    # a prefill's counts come with its first token, at the read
+    read = {e["id"]: e for e in events
+            if e.get("ev") == "serve.prefill.commit"}
     cold = [e for e in prefills if e["program"] == "prefill"]
     warm = [e for e in prefills if e["program"] == "extend"]
     assert cold and warm
     for e in cold:
-        assert e["picks"] == PICKS * e["prompt_tokens"]
+        assert read[e["id"]]["picks"] == PICKS * e["prompt_tokens"]
     for e in warm:
-        assert e["picks"] == PICKS * (e["prompt_tokens"]
-                                      - e["cached_tokens"])
+        assert read[e["id"]]["picks"] == PICKS * (e["prompt_tokens"]
+                                                  - e["cached_tokens"])
 
 
 @pytest.mark.parametrize("fault,kw", [

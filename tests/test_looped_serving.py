@@ -249,7 +249,9 @@ def _paged(engine):
                                        implementation="interpret")
     if engine.decode_steps > 1:
         decode = decode_lib.make_multi_decode_fn(decode, engine.decode_steps)
-    engine._decode = jax.jit(decode, donate_argnums=(1,))
+    engine._decode_next = jax.jit(
+        decode_lib.launch_decode(decode, engine.decode_steps),
+        donate_argnums=(1,))
     engine.kv_path = decode.kv_path
     engine._kv_layout = decode.kv_layout
     return engine
@@ -294,7 +296,9 @@ def test_engine_serves_the_looped_model_with_prefix_caching(served,
     prefills = [s for n, s in spans if n == "serve.prefill"]
     assert [(p["program"], p["passes"]) for p in prefills] == [
         ("prefill", 3), ("extend", 3)]
-    decodes = [s for n, s in spans if n == "serve.decode" and s["live"]]
+    # the spans that read a launch carry what it read
+    decodes = [s for n, s in spans if n == "serve.decode"
+               and "token_steps" in s]
     assert decodes and all(
         d["passes"] == 3 and d["cache_layers"] == 6 for d in decodes)
     # one sequence at a time: the first decode step after a prompt of n
@@ -483,7 +487,8 @@ def test_a_hot_swap_of_float32_weights_compiles_nothing(rounded):
     cfg, params = rounded
     engine = _engine(cfg, params)
     before = _serve_a_mixed_batch(engine)
-    programs = (engine._prefill, engine._decode, engine._extend_prefill)
+    programs = (engine._prefill_next, engine._decode_next,
+                engine._extend_next)
     sizes = [p._cache_size() for p in programs]
     assert all(sizes)
     digest = engine.weights_digest
@@ -503,13 +508,12 @@ def _weight_converts(engine, params):
     lowered for ``params``, by program."""
     cfg, B, W = engine.cfg, engine.max_slots, engine.window
     i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
-    args = {"decode": (engine._decode,
-                       (i32(B), i32(B), i32(B), i32(B), i32((B, W)))),
-            "prefill": (engine._prefill, (i32((1, 32)), i32(1),
-                                          i32((1, 32)))),
-            "extend": (engine._extend_prefill,
-                       (i32((1, 8)), i32((1, 8)), i32(1), i32((1, 8)),
-                        i32((1, W))))}
+    # what each launch takes after params and pool: the chosen tokens
+    # and the one array the host sends (the CPU's window path)
+    args = {"decode": (engine._decode_next, (i32(B), i32((B, 4 + W)))),
+            "prefill": (engine._prefill_next,
+                        (i32(B), i32(2 + 2 * engine.max_seq_len))),
+            "extend": (engine._extend_next, (i32(B), i32(2 + 3 * 8 + W)))}
     shapes = set()
     for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
         if _is_matrix(path):
@@ -665,10 +669,16 @@ def test_the_spans_of_a_multi_step_launch_count_its_inner_steps(tmp_path):
              if plane.name.startswith("/host:")
              for line in plane.lines for e in line.events
              if e.name in ("serve.decode", "serve.decode.commit")]
-    decodes = [s for n, s in spans if n == "serve.decode" and s["live"]]
+    decodes = [s for n, s in spans if n == "serve.decode"
+               and "token_steps" in s]
+    launches = [s for n, s in spans if n == "serve.decode"
+                and s.get("launched")]
     commits = [s for n, s in spans if n == "serve.decode.commit"]
-    # the prefill's token, then launches of 3 and 2 tokens
+    # the prefill's token, then launches of 3 and 2 tokens, each read in
+    # the step after its own (the first read takes the prefill's alone)
     assert [d["token_steps"] for d in decodes] == [3, 2]
-    assert [c["tokens"] for c in commits] == [3, 2]
+    assert [c["tokens"] for c in commits] == [0, 3, 2]
     assert [d["rows_read"] for d in decodes] == [20 + 21 + 22, 23 + 24]
-    assert all(d["passes"] == 3 and d["live"] == 1 for d in decodes)
+    assert all(d["passes"] == 3 for d in decodes)
+    # each launch fed a token the host had not read yet
+    assert [(d["live"], d["ahead"]) for d in launches] == [(1, 1), (1, 1)]

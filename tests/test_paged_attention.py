@@ -816,20 +816,11 @@ def test_compiled_checksum_holds_no_copy_of_its_leaf(one_chip,
     assert memory.temp_size_in_bytes < 1 << 20
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill", "extend"])
-def test_compiled_latent_programs_hold_nothing_of_the_pools_size(
-        one_chip, no_compile_cache, program):
+def _latent_serving_specs(one_chip):
     """One shortcut-connected layer at the published widths of the
-    benchmark's latent configuration (hidden 6144, 64 heads over a row of
-    512 + 64, 16 held experts of width 2048; 1.24B parameters in
-    bfloat16) over its pool of 4,608 blocks, compiled for one v5e chip:
-    the latent decode kernel and the grouped expert product compile at
-    these widths, no program produces an array of the pool's or a cache
-    layer's size (the pool lies row-major, its rows in 640 values, and
-    the (layer, row) scatter writes it in place), and none converts,
-    copies or slices out a weight matrix (each is sliced where it is
-    used; the experts' stacks go to their kernel whole)."""
-    import chip_smoke
+    benchmark's latent configuration over its pool, described and not
+    allocated: ``(cfg, cache config, spec(shape, dtype=int32), params,
+    pool)`` with every array on ``one_chip``."""
     from distributed_tensorflow_tpu.models import scmoe
 
     cfg = TransformerConfig(
@@ -855,6 +846,26 @@ def test_compiled_latent_programs_hold_nothing_of_the_pools_size(
         node[path[-1]] = spec(shape, jnp.bfloat16)
     rows = cc.num_blocks * cc.block_size
     pool = {"latent": spec((cc.n_layers, rows) + cc.row_shape, cc.dtype)}
+    return cfg, cc, spec, params, pool
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "extend"])
+def test_compiled_latent_programs_hold_nothing_of_the_pools_size(
+        one_chip, no_compile_cache, program):
+    """One shortcut-connected layer at the published widths of the
+    benchmark's latent configuration (hidden 6144, 64 heads over a row of
+    512 + 64, 16 held experts of width 2048; 1.24B parameters in
+    bfloat16) over its pool of 4,608 blocks, compiled for one v5e chip:
+    the latent decode kernel and the grouped expert product compile at
+    these widths, no program produces an array of the pool's or a cache
+    layer's size (the pool lies row-major, its rows in 640 values, and
+    the (layer, row) scatter writes it in place), and none converts,
+    copies or slices out a weight matrix (each is sliced where it is
+    used; the experts' stacks go to their kernel whole)."""
+    import chip_smoke
+
+    cfg, cc, spec, params, pool = _latent_serving_specs(one_chip)
+    rows = cc.num_blocks * cc.block_size
     slots, window = 64, cfg.max_seq_len
     vec, one = spec((slots,)), spec((1,))
     if program == "decode":
@@ -905,3 +916,51 @@ def test_compiled_latent_programs_hold_nothing_of_the_pools_size(
     assert kernels == ({"paged_attn_decode_latent", "expert_grouped_matmul"}
                        if program == "decode"
                        else {"expert_grouped_matmul"})
+
+
+@pytest.mark.parametrize("model,program", [
+    ("tbig", "decode"), ("tbig", "prefill"), ("tbig", "extend"),
+    ("latent", "decode")])
+def test_compiled_launches_choose_the_next_token_on_the_device(
+        one_chip, no_compile_cache, model, program):
+    """What the engine launches (``decode.launch_*``) at the benchmark's
+    shapes, compiled for one v5e chip beside the program it wraps: it
+    takes the chosen tokens and the one array the host sends, returns the
+    chosen tokens ((slots,) int32: the argmax lies inside) beside the
+    logits the plain program returns, updates the pool in place, keeps
+    the kernels, and holds no more
+    than the plain program with its outputs (so a second launch queued
+    behind a first adds nothing to the peak the plain step had)."""
+    cfg, cc, spec, params, pool = (_tbig_serving_specs if model == "tbig"
+                                   else _latent_serving_specs)(one_chip)
+    slots, window = 64, cfg.max_seq_len
+    T, E = window // cc.block_size, 64
+    if program == "decode":
+        fn = decode_lib.make_decode_fn(cfg, cc, implementation="paged")
+        launch, host = decode_lib.launch_decode(fn), spec((slots, 4 + T))
+        plain = (spec((slots,)),) * 4 + (spec((slots, T)),)
+    elif program == "prefill":
+        fn = decode_lib.make_prefill_fn(cfg, cc, implementation="paged")
+        launch, host = decode_lib.launch_prefill(fn), spec((2 + 2 * window,))
+        plain = (spec((1, window)), spec((1,)), spec((1, window)))
+    else:
+        fn = decode_lib.make_extend_fn(cfg, cc, implementation="paged")
+        launch = decode_lib.launch_extend(fn, window)
+        host = spec((2 + 3 * E + window,))
+        plain = (spec((1, E)),) * 2 + (spec((1,)), spec((1, E)),
+                                       spec((1, window)))
+    chosen = spec((slots,))
+    out = jax.eval_shape(launch, params, pool, chosen, host)
+    assert (out[0].shape, out[0].dtype) == ((slots,), jnp.int32)
+    got = jax.jit(launch, donate_argnums=(1,)).lower(
+        params, pool, chosen, host).compile()
+    base = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pool, *plain).compile()
+    assert got.as_text().count("tpu_custom_call") == \
+        base.as_text().count("tpu_custom_call")
+    m, b = got.memory_analysis(), base.memory_analysis()
+    assert m.alias_size_in_bytes == b.alias_size_in_bytes > 0
+    assert (m.temp_size_in_bytes + m.output_size_in_bytes
+            <= b.temp_size_in_bytes + b.output_size_in_bytes + (1 << 20)), (
+        m.temp_size_in_bytes, m.output_size_in_bytes,
+        b.temp_size_in_bytes, b.output_size_in_bytes)

@@ -178,21 +178,34 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
         schedule = inside[1]
         assert schedule[3].get("cached_tokens", 0) == admitted[i]
         assert schedule[3]["admitted"] == step[3]["admitted"]
-        tail = names[-5:]
-        if step[3]["decoded"]:
-            assert tail == ["serve.decode", "serve.decode.build",
-                            "serve.decode.launch", "serve.decode.wait",
-                            "serve.decode.commit"]
+        # the step's decode: its launch (build, launch), then the read
+        # (wait, commit) of the LAST step's launch and of this step's
+        # admissions' first tokens, which the vector it was fed holds
+        decode = next(s for s in inside if s[0] == "serve.decode")
+        sub = [s for s in inside if s[0].startswith("serve.decode.")
+               and decode[1] <= s[1] and s[2] <= decode[2]]
+        launched = step[3]["decoded"]
+        read = steps[i - 1][3]["decoded"] if i else 0
+        assert [s[0] for s in sub] == (
+            ["serve.decode.build", "serve.decode.launch"] * bool(launched)
+            + ["serve.decode.wait", "serve.decode.commit"]
+            * bool(read or step[3]["admitted"]))
+        fields = dict(decode[3])
+        assert (fields.pop("live"), fields.pop("kv_path")) == (launched,
+                                                               "window")
+        if launched:
+            # every launch here is fed a token the host has not read:
+            # the first the admissions', the others the last launch's
+            assert (fields.pop("launched"), fields.pop("ahead")) == (1, 1)
+        if read:
             # the CPU takes the window path: every slot's whole window
-            decode = dict(inside[-5][3])
-            assert decode.pop("rows_read") >= step[3]["decoded"]
-            assert decode == {
-                "live": step[3]["decoded"], "kv_path": "window",
-                "token_steps": step[3]["decoded"],
-                "blocks_read": e.max_slots * e.window
-                // e.cache_cfg.block_size,
-                "passes": 1, "cache_layers": e.cfg.n_layers}
-            assert inside[-1][3] == {"tokens": step[3]["decoded"]}
+            assert fields.pop("rows_read") >= read
+            assert sub[-1][3] == {"tokens": read}
+        assert fields == ({
+            "token_steps": read,
+            "blocks_read": e.max_slots * e.window
+            // e.cache_cfg.block_size,
+            "passes": 1, "cache_layers": e.cfg.n_layers} if read else {})
     first = [s for s in spans
              if steps[0][1] <= s[1] and s[2] <= steps[0][2]]
     prefills = [s for s in first if s[0] == "serve.prefill"]
@@ -207,11 +220,13 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
     assert (a["kv_write"], a["blocks_written"]) == ("scatter", 1)
     assert (b["kv_write"], b["blocks_written"]) == ("scatter", 2)
     assert steps[0][3]["cached_tokens"] == admitted[0] == 16
-    for p in prefills:                  # build, launch, wait under each
+    for p in prefills:                  # build and launch under each
         sub = [s[0] for s in first if s is not p
                and p[1] <= s[1] and s[2] <= p[2]]
-        assert sub == ["serve.prefill.build", "serve.prefill.launch",
-                       "serve.prefill.wait"]
+        assert sub == ["serve.prefill.build", "serve.prefill.launch"]
+    # each admission's first token is read in the step that launched it
+    assert [s[3]["id"] for s in first
+            if s[0] == "serve.prefill.commit"] == ["a", "b"]
     assert all(s[1] >= steps[0][1] for s in spans), "a span outside a step"
 
 

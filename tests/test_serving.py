@@ -283,13 +283,17 @@ class TestDecodeParity:
     def test_eos_stops_generation(self, tiny):
         cfg, params = tiny
         ref = reference_greedy(cfg, params, [5, 6, 7], 6)
-        eos = ref[2]                           # stop at the 3rd token
+        # the greedy output repeats itself: the end token is one that
+        # first occurs after the first token, and the output ends at
+        # that first occurrence
+        eos = next(t for t in ref[1:] if t != ref[0])
+        want = ref[:ref.index(eos) + 1]
         engine = InferenceEngine(cfg, params, num_blocks=32, block_size=8,
                                  max_slots=2, max_prompt_len=16)
         engine.submit(Request(id="e", tokens=(5, 6, 7),
                               max_new_tokens=6, eos_id=eos))
         done = engine.run_until_idle()
-        assert done["e"]["tokens"] == ref[:3]
+        assert done["e"]["tokens"] == want
 
     def test_bert_scoring_path(self):
         """Non-causal (BERT-family) configs serve scoring requests:
