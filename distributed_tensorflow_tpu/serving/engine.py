@@ -122,13 +122,16 @@ class _Launch:
     sequence it advanced with the tokens it launched for it, where those
     lie on the device (``tokens`` (slots, k) for several steps a launch;
     None: in the ``chosen`` vector it returned), the expert counts it
-    returned, and what it read of the pool (``counts``), which the span
-    of its read carries."""
+    returned, the array it was handed (``host``: what the span of its
+    read counts of the pool is computed from) and its number among the
+    engine's dispatches (``launch``), which that span names as its
+    ``read``."""
 
     batch: list
     tokens: object
     picks: list
-    counts: dict
+    host: np.ndarray
+    launch: int
 
 
 def request_span_id(request_id: str) -> str:
@@ -441,8 +444,11 @@ class InferenceEngine:
         #: the decode launch whose tokens are not read yet (_Launch)
         self._launched: _Launch | None = None
         #: admitted sequences whose first token is not read yet, each
-        #: with its prefill's counts (on the device)
-        self._firsts: list[tuple[Sequence, list]] = []
+        #: with its prefill's counts (on the device) and launch number
+        self._firsts: list[tuple[Sequence, list, int]] = []
+        #: program dispatches so far (_dispatched), counted whether or
+        #: not a session records, so the numbers never depend on it
+        self._launches = 0
         #: completion records made outside step()'s own retire (a drain
         #: before a preemption), handed out by the step
         self._retired: list[dict] = []
@@ -853,13 +859,26 @@ class InferenceEngine:
         scales) BEFORE the divergent write they protect."""
         if not copies:
             return
-        with telemetry.span("kv.copy_on_write", blocks=len(copies)):
+        with telemetry.span("kv.copy_on_write", blocks=len(copies)) as sp:
             src = np.concatenate([np.arange(s, s + n, dtype=np.int32)
                                   for s, _, n in copies])
             dst = np.concatenate([np.arange(d, d + n, dtype=np.int32)
                                   for _, d, n in copies])
             self.pool = self._copy(self.pool, jnp.asarray(src),
                                    jnp.asarray(dst))
+            self._dispatched(sp, self._copy)
+
+    def _dispatched(self, sp: dict, program) -> int:
+        """Number the dispatch of ``program`` that the span ``sp`` makes
+        and return the number; while a session records, the span names
+        the program (``program``: its name on the device's program line
+        less ``jit_``) and the number (``launch``), which the span that
+        banks the run's result names as its ``read``."""
+        self._launches += 1
+        if telemetry.recording():
+            sp["program"] = program.__name__
+            sp["launch"] = self._launches
+        return self._launches
 
     # the launches called as the plain programs are, for a check of
     # their logits (the benchmark's latent runner): the compiled programs
@@ -950,13 +969,18 @@ class InferenceEngine:
                     host[2 + E:] = seq.table.rows(np.arange(E))
                     launch = self._prefill_next
                 host[:2] = seq.slot, seq.prompt_len
-            with telemetry.span("serve.prefill.launch"):
+            with telemetry.span("serve.prefill.launch") as sp:
                 self._chosen, self.pool, _, *picks = launch(
                     self.served_params, self.pool, self._chosen,
                     jnp.asarray(host))
+                n = self._dispatched(sp, launch)
+                if telemetry.recording():
+                    # the host's share of the admission's first token:
+                    # from the scheduler making the sequence to here
+                    sp["since_admit_s"] = time.monotonic() - seq.admitted_s
         self.scheduler.commit_prefill(seq)
         self.scheduler.launched(seq, 1)
-        self._firsts.append((seq, picks))
+        self._firsts.append((seq, picks, n))
         self._m_prompt_tokens.increment(seq.prompt_len)
         if C:
             self._m_cached_tokens.increment(C)
@@ -988,15 +1012,13 @@ class InferenceEngine:
         ``decode_steps`` = k up to k, each inner step fed the token the
         last one chose. Slots outside the batch idle (length 0, the
         trash block's row 0). The launch is read by the NEXT
-        :meth:`_read`; what it will have read of the pool travels with
-        it (``_Launch.counts``: ``token_steps``, ``blocks_read``, and
-        while a span is recorded ``rows_read`` and on the paged path
-        ``runs_read``). Returns the ``serve.decode`` span's ``launched``
-        and ``ahead`` (1 where a token the host had not read was fed)."""
+        :meth:`_read`, whose span counts what it read of the pool
+        (:meth:`_decode_counts`). Returns the ``serve.decode`` span's
+        ``launched`` and ``ahead`` (1 where a token the host had not read
+        was fed)."""
         B, W, K = self.max_slots, self.window, self.decode_steps
         paged = self.kv_path == "paged"
-        bs = self.cache_cfg.block_size
-        T = W // bs if paged else W
+        T = W // self.cache_cfg.block_size if paged else W
         with telemetry.span("serve.decode.build"):
             # one upload, a row a slot: [fed, length, budget, the rows
             # its K steps write, its table: the paged program walks the
@@ -1023,14 +1045,29 @@ class InferenceEngine:
                         seq.table.blocks
                 else:
                     host[s, 3 + K:] = seq.table.window_rows()
-        with telemetry.span("serve.decode.launch"):
+        with telemetry.span("serve.decode.launch") as sp:
             self._chosen, self.pool, scores, *picks = self._decode_next(
                 self.served_params, self.pool, self._chosen,
                 jnp.asarray(host))
+            n = self._dispatched(sp, self._decode_next)
         ahead = any(seq.unread for seq in batch)
         budget = host[:, 2]
         for seq in batch:
             self.scheduler.launched(seq, int(budget[seq.slot]))
+        self._launched = _Launch(
+            [(seq, int(budget[seq.slot])) for seq in batch],
+            scores if K > 1 else None, picks, host, n)
+        return {"launched": 1, "ahead": int(ahead)}
+
+    def _decode_counts(self, host: np.ndarray) -> dict:
+        """What the decode launch handed ``host`` read of the pool, for
+        the span of its read (built only while a span is recorded):
+        ``token_steps``, ``blocks_read``, ``passes``, ``cache_layers``,
+        ``rows_read`` and on the paged path ``runs_read``."""
+        B, W, K = self.max_slots, self.window, self.decode_steps
+        paged = self.kv_path == "paged"
+        bs = self.cache_cfg.block_size
+        budget = host[:, 2]
         # inner step i of a slot sees length + i rows while i < budget
         rows = np.where(np.arange(K) < budget[:, None],
                         host[:, 1:2] + np.arange(K), 0)        # (B, K)
@@ -1038,19 +1075,15 @@ class InferenceEngine:
                   "blocks_read": (B * (W // bs) * K if not paged
                                   else int(np.sum(-(-rows // bs)))),
                   "passes": self.decode_passes,
-                  "cache_layers": self.cache_cfg.n_layers}
-        if telemetry.recording():
-            counts["rows_read"] = int(rows.sum())
-            if paged:
-                counts["runs_read"] = sum(
-                    paged_attention.count_runs(
-                        self._kv_layout, host[:, 3 + K:],
-                        -(-np.maximum(rows[:, i] - 1, 0) // bs), bs)
-                    for i in range(K))
-        self._launched = _Launch(
-            [(seq, int(budget[seq.slot])) for seq in batch],
-            scores if K > 1 else None, picks, counts)
-        return {"launched": 1, "ahead": int(ahead)}
+                  "cache_layers": self.cache_cfg.n_layers,
+                  "rows_read": int(rows.sum())}
+        if paged:
+            counts["runs_read"] = sum(
+                paged_attention.count_runs(
+                    self._kv_layout, host[:, 3 + K:],
+                    -(-np.maximum(rows[:, i] - 1, 0) // bs), bs)
+                for i in range(K))
+        return counts
 
     def _fetch(self, chosen, rec: "_Launch | None", firsts: list):
         """Wait for and copy to the host, in one transfer, what a read
@@ -1062,7 +1095,7 @@ class InferenceEngine:
         picks = telemetry.recording() and self._experts_held is not None
         want = (chosen, rec.tokens if rec is not None else None,
                 rec.picks[:1] if rec is not None and picks else [],
-                [p[:1] for _, p in firsts] if picks else [])
+                [p[:1] for _, p, _ in firsts] if picks else [])
         return jax.device_get(want)
 
     def _commit(self, fetched, rec: "_Launch | None",
@@ -1071,11 +1104,15 @@ class InferenceEngine:
         then the admissions' first tokens. A token chosen after its
         sequence ended (an end-of-sequence token read since it was
         launched) or for a sequence no longer running is dropped, its
-        position given back. Returns the read launch's counts (for the
-        span of its read; empty when there was none) and the tokens
-        banked from it."""
+        position given back. Each admission's banking is a
+        ``serve.prefill.commit`` span, which while a session records
+        names the launch it read (``read``) and the admission's TTFT
+        (``ttft_s``, :meth:`_ttft`). Returns the read launch's counts
+        (for the span of its read, while one is recorded; empty when
+        there was none) and the tokens banked from it."""
         chosen, tokens, picks, first_picks = fetched
         sched, emit = self.scheduler, telemetry.enabled()
+        recording = telemetry.recording()
         counts, banked = {}, 0
         if rec is not None:
             for seq, n in rec.batch:
@@ -1092,22 +1129,29 @@ class InferenceEngine:
                             self._emit_token(seq)
                 sched.discard(seq, n - used)
                 banked += used
-            counts = dict(rec.counts, **self._expert_counts(picks))
-        for i, (seq, _) in enumerate(firsts):
+            if recording:
+                counts = dict(self._decode_counts(rec.host),
+                              **self._expert_counts(picks))
+        for i, (seq, _, launch) in enumerate(firsts):
             first = int(chosen[seq.slot])
             rid = seq.request.id
             with telemetry.span("serve.prefill.commit", id=rid,
                                 span_id=request_span_id(rid),
                                 **self._expert_counts(
-                                    first_picks[i] if first_picks else [])):
+                                    first_picks[i] if first_picks else [])
+                                ) as sp:
                 live = sched.running.get(seq.slot) is seq
                 if live and seq.request.max_new_tokens:
                     sched.read_token(seq, first)
-                    continue
-                sched.discard(seq, 1)
-                if live:                    # a scoring request's 'token'
-                    seq.first_token_s = time.monotonic()
-                    seq.score_token = first
+                else:
+                    sched.discard(seq, 1)
+                    if live:                # a scoring request's 'token'
+                        seq.first_token_s = time.monotonic()
+                        seq.score_token = first
+                if recording:
+                    sp["read"] = launch
+                    if live:
+                        sp["ttft_s"] = self._ttft(seq)
         return counts, banked
 
     def _read(self, chosen, rec: "_Launch | None" = None) -> dict:
@@ -1121,6 +1165,8 @@ class InferenceEngine:
             fetched = self._fetch(chosen, rec, firsts)
         with telemetry.span("serve.decode.commit") as sp:
             counts, sp["tokens"] = self._commit(fetched, rec, firsts)
+            if rec is not None and telemetry.recording():
+                sp["read"] = rec.launch
         return counts
 
     def _drain(self, reason: str) -> bool:
@@ -1135,6 +1181,8 @@ class InferenceEngine:
         with telemetry.span("serve.drain", reason=reason) as sp:
             sp["tokens"] = self._commit(
                 self._fetch(self._chosen, rec, firsts), rec, firsts)[1]
+            if rec is not None and telemetry.recording():
+                sp["read"] = rec.launch
         return True
 
     def _drain_for_preemption(self) -> bool:
@@ -1221,16 +1269,19 @@ class InferenceEngine:
                 write_rows[s, :ke + 1] = [seq.table.row_of(p)
                                           for p in range(L - 1, L + ke)]
                 window_rows[s] = seq.table.window_rows()
-        with telemetry.span("serve.decode.launch"):
+        with telemetry.span("serve.decode.launch") as sp:
             logits, self.pool = self._extend_spec(
                 self.served_params, self.pool, jnp.asarray(tokens),
                 jnp.asarray(positions), jnp.asarray(lengths),
                 jnp.asarray(write_rows), jnp.asarray(window_rows))
+            launch = self._dispatched(sp, self._extend_spec)
         with telemetry.span("serve.decode.wait"):
             target_next = np.asarray(jnp.argmax(logits, axis=-1))  # (B, E)
 
         # 3. commit the agreeing prefix + the target's next token
         with telemetry.span("serve.decode.commit") as sp:
+            if telemetry.recording():
+                sp["read"] = launch
             emit = telemetry.enabled()
             committed_total = 0
             for seq in batch:
@@ -1299,7 +1350,7 @@ class InferenceEngine:
                     ssp["evicted_blocks"] = cache.evictions - evict0
             for seq in admitted:
                 self._prefill_one(seq)
-            firsts = [seq for seq, _ in self._firsts]
+            firsts = [seq for seq, _, _ in self._firsts]
             batch = []
             if self._decode_next is None:
                 self._read(self._chosen)    # prefill only: read at once

@@ -4,6 +4,7 @@ engine step is split where the work happens, every Pallas kernel carries
 a stable name, and the two numbers the engine reports to its operators
 (prefix hits, time to first token) mean what they say."""
 
+import contextlib
 import glob
 import json
 import time
@@ -169,6 +170,7 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
     spans = _program_spans(str(tmp_path))
     steps = [s for s in spans if s[0] == "serve.step"]
     assert len(steps) == len(admitted) >= 3
+    last_launch = None                  # the decode launch read next
     for i, step in enumerate(steps):
         inside = [s for s in spans if s is not step
                   and step[1] <= s[1] and s[2] <= step[2]]
@@ -198,9 +200,14 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
             # the first the admissions', the others the last launch's
             assert (fields.pop("launched"), fields.pop("ahead")) == (1, 1)
         if read:
-            # the CPU takes the window path: every slot's whole window
+            # the CPU takes the window path: every slot's whole window;
+            # the commit names the launch it banks, the last step's
             assert fields.pop("rows_read") >= read
-            assert sub[-1][3] == {"tokens": read}
+            assert sub[-1][3] == {"tokens": read, "read": last_launch}
+        if launched:
+            launch = sub[1][3]
+            assert launch["program"] == e._decode_next.__name__ == "decode"
+            last_launch = launch["launch"]
         assert fields == ({
             "token_steps": read,
             "blocks_read": e.max_slots * e.window
@@ -228,6 +235,150 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
     assert [s[3]["id"] for s in first
             if s[0] == "serve.prefill.commit"] == ["a", "b"]
     assert all(s[1] >= steps[0][1] for s in spans), "a span outside a step"
+
+
+# ---------------------------------------------------------------------------
+# launch and read numbers: which device run a launch starts, which launch a
+# read banks
+# ---------------------------------------------------------------------------
+
+def _shared_prefixes(e):
+    """A document, then two asks that share it (an extend each, the
+    first a copy-on-write of the partly matched tail block) beside a cold
+    prompt; returns the completion records."""
+    e.submit(Request(id="doc", tokens=tuple(X), max_new_tokens=3))
+    e.step()                            # doc prefilled: its blocks cached
+    e.submit(Request(id="same", tokens=tuple(X), max_new_tokens=4))
+    e.submit(Request(id="more", tokens=tuple(X + [40, 41]),
+                     max_new_tokens=5))
+    e.submit(Request(id="cold", tokens=tuple(range(50, 59)),
+                     max_new_tokens=6))
+    return e.run_until_idle()
+
+
+def _preempting(e):
+    """Three sequences on a pool too small for them: a preemption drains
+    what is in flight before it picks a victim."""
+    for i, p in enumerate([[7, 7, 7], [8, 8, 8, 8], [9, 9]]):
+        e.submit(Request(id=f"p{i}", tokens=tuple(p), max_new_tokens=8))
+    return e.run_until_idle()
+
+
+LAUNCH_CASES = {
+    "shared": ({}, _shared_prefixes),
+    "steps4": ({"decode_steps": 4}, _shared_prefixes),
+    "speculative": ({"speculative_k": 2}, _shared_prefixes),
+    "preempt": ({"num_blocks": 6, "block_size": 4, "max_slots": 4,
+                 "max_prompt_len": 16, "prefix_caching": False},
+                _preempting),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAUNCH_CASES))
+def test_every_launch_names_its_program_and_one_read_names_it(tiny, tmp_path,
+                                                              case):
+    """With an event log: each span that dispatches a program names it
+    (``program``, the jitted function's ``__name__``) and numbers the
+    dispatch (``launch``, consecutive over all programs); once
+    ``run_until_idle`` returns, each admission's and decode's launch is
+    named by exactly one ``read`` (a commit's or a drain's) and a copy's
+    by none; each ``serve.prefill.commit`` carries the TTFT that the
+    request's completion record reports, and each admission's launch the
+    seconds since the scheduler made its sequence."""
+    kw, run = LAUNCH_CASES[case]
+    e = _engine(tiny, **kw)
+    telemetry.configure(str(tmp_path), process_id=0)
+    try:
+        done = run(e)
+    finally:
+        telemetry.shutdown()
+    evs = telemetry.read_events(telemetry.event_log_path(str(tmp_path), 0))
+    launches = [x for x in evs if "launch" in x]
+    numbers = sorted(x["launch"] for x in launches)
+    assert numbers == list(range(1, e._launches + 1))
+    decode = e._extend_spec if case == "speculative" else e._decode_next
+    programs = {
+        "serve.prefill.launch": {e._prefill_next.__name__,
+                                 e._extend_next.__name__},
+        "serve.decode.launch": {decode.__name__},
+        "kv.copy_on_write": {e._copy.__name__}}
+    for x in launches:
+        assert x["program"] in programs[x["ev"]], x
+        # an admission's launch times the host's share of it
+        assert (x.get("since_admit_s", -1) >= 0) == (
+            x["ev"] == "serve.prefill.launch"), x
+    # an admission's launch is the last span to end inside its
+    # serve.prefill, and names the program that span says it ran
+    for before, x in zip(evs, evs[1:]):
+        if x["ev"] == "serve.prefill":
+            assert before["ev"] == "serve.prefill.launch"
+            assert before["program"] == x["program"]
+    kind = {x["launch"]: x["ev"] for x in launches}
+    reads = [x for x in evs if "read" in x]
+    for x in reads:
+        assert kind[x["read"]] == ("serve.prefill.launch"
+                                   if x["ev"] == "serve.prefill.commit"
+                                   else "serve.decode.launch"), x
+    times = {n: 0 for n in kind}
+    for x in reads:
+        times[x["read"]] += 1
+    assert times == {n: int(ev != "kv.copy_on_write")
+                     for n, ev in kind.items()}
+    assert {x["ev"] for x in launches} >= {"serve.prefill.launch",
+                                           "serve.decode.launch"}
+    if case == "shared":
+        assert "kv.copy_on_write" in kind.values()
+        assert {x["program"] for x in launches
+                if x["ev"] == "serve.prefill.launch"} == {"prefill", "extend"}
+    if case == "preempt":
+        assert e.scheduler.preemptions > 0
+        assert any(x["ev"] == "serve.drain" and x["reason"] == "preempt"
+                   for x in reads)
+    # the last first token banked for a request (a preempted one's is
+    # its replay's) is the TTFT its completion record reports
+    ttft = {x["id"]: x["ttft_s"] for x in evs
+            if x["ev"] == "serve.prefill.commit" and "ttft_s" in x}
+    assert ttft == {rid: r["ttft_s"] for rid, r in done.items()}
+
+
+def test_with_nothing_recording_the_spans_get_none_of_it(tiny, tmp_path,
+                                                         monkeypatch):
+    """No session, no log: no span is given a program, a launch number, a
+    read, a TTFT or the seconds since an admission, and a read's counts of the pool are never built; the
+    engine numbers its dispatches all the same, as a recorded run of the
+    same requests does."""
+    recorded = _engine(tiny)
+    telemetry.configure(str(tmp_path), process_id=0)
+    try:
+        _shared_prefixes(recorded)
+    finally:
+        telemetry.shutdown()
+    added = []
+    span = telemetry.span
+
+    @contextlib.contextmanager
+    def watched(name, **fields):
+        with span(name, **fields) as sp:
+            yield sp
+        added.append((name, dict(sp)))
+
+    def never(host):
+        raise AssertionError("a read's counts were built with nothing "
+                             "recording")
+
+    monkeypatch.setattr(telemetry, "span", watched)
+    e = _engine(tiny)
+    monkeypatch.setattr(e, "_decode_counts", never)
+    assert not telemetry.recording()
+    done = _shared_prefixes(e)
+    assert set(done) == {"doc", "same", "more", "cold"}
+    new = {"program", "launch", "read", "ttft_s", "since_admit_s",
+           "token_steps",
+           "blocks_read", "rows_read", "passes", "cache_layers"}
+    assert added and all(not new & set(sp) for _, sp in added), added
+    assert {"serve.prefill.commit", "serve.decode.commit",
+            "kv.copy_on_write"} <= {name for name, _ in added}
+    assert e._launches == recorded._launches > 0
 
 
 # ---------------------------------------------------------------------------
