@@ -315,11 +315,12 @@ def program_check(engine: InferenceEngine) -> None:
     output pool is its input). Asked of decode, of prefill where it
     writes by blocks or the device keeps the pool row-major (XLA's
     scatter writes that layout in place), and of extend where it writes
-    by blocks: its window gather still slices a cache layer out, so
-    there only the pool's own size counts."""
+    by blocks; where its layers still read through the window gather
+    (which slices a cache layer out) only the pool's own size counts."""
     print(f"  decode kv_path: {engine.kv_path}", flush=True)
     print(f"  prefill kv_write: {engine.kv_write['prefill']}", flush=True)
     print(f"  extend kv_write: {engine.kv_write['extend']}", flush=True)
+    print(f"  extend kv_read: {engine.kv_read}", flush=True)
     if engine.kv_path != "paged":
         return
     cc, slots = engine.cache_cfg, engine.max_slots
@@ -350,10 +351,11 @@ def program_check(engine: InferenceEngine) -> None:
             not by_blocks)
     if engine.kv_write["extend"] == "paged":
         span = min(64, engine.max_seq_len)
+        in_place = engine.kv_read == "paged"
         programs["extend"] = (
             engine._extend_next,
             (chosen, jnp.zeros(2 + 3 * span + engine.window, jnp.int32)),
-            {whole}, False)
+            {whole, layer} if in_place else {whole}, in_place)
     for name, (program, args, sizes, small) in programs.items():
         compiled = program.lower(engine.served_params, engine.pool,
                                  *args).compile()

@@ -1,4 +1,4 @@
-"""Paged attention for the serving decode step (Pallas, TPU).
+"""Paged attention for the serving decode and extend steps (Pallas, TPU).
 
 One query per running slot against the K and V of that slot's LIVE
 blocks only, read out of the block-allocated pool (serving/kv_cache.py)
@@ -62,6 +62,22 @@ registers when the step runs, so :func:`paged_attention_decode` merges
 that one key into the softmax after the kernel (the query sees its own
 position whatever the order of read and write), and the pool is written
 once per step, after the last layer, by :func:`write_rows`.
+
+**The extend program reads a ``"lanes"`` pool the same way**
+(``paged_attn_extend``, :func:`paged_attention_extend`): E queries a slot
+(a suffix after a prefix-cache hit, or a speculative verify), of which
+every one sees every key before the span's first position, so the run
+list is :func:`decode_plan`'s over the table with that position as the
+length and the only mask is its lanes'. Per run the ``(H, E, hd)``
+queries meet the ``(H, hd, 128)`` keys on the MXU, batched over the heads,
+and the probabilities the ``(H, hd, 128)`` values in a product contracted
+over the lanes; the grid is the tiles of at most ``EXTEND_QUERIES``
+queries outside the flat runs.
+The span's own keys are in registers: they are merged after the kernel,
+an ``E x E`` block causal by position, as the decoded token's key is, so
+the kernel never reads a row the span writes. XLA's gather of a slot's
+window out of that layout relays a whole pool layer first, in every layer
+of every extend (PERF.md section 6, PR 40).
 
 **One kernel writes the ``"lanes"`` pool** (``paged_kv_write``,
 :func:`write_blocks`), for the decode step's one row a slot and for the
@@ -238,6 +254,20 @@ def count_runs(layout: str, block_table, n_blocks, block_size: int) -> int:
     return int(np.sum(opens & live))
 
 
+def _live_lanes(shape, bits, tail, block_size: int):
+    """Which lanes of a run's 128-row group are its slot's live rows, as a
+    ``shape`` mask (the lanes minor-most): those of the blocks ``bits``
+    holds, less the rows past the slot's last one where ``tail`` (a
+    :func:`decode_plan` tail, or ``GROUP_ROWS * 256`` on a run that is not
+    the slot's last) cuts its block."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    lane_sub = lane >> (block_size.bit_length() - 1)
+    held = (jnp.full(shape, bits, jnp.int32) >> lane_sub) & 1
+    cut = (lane_sub == (tail >> 8)) & ((lane & (block_size - 1))
+                                       >= (tail & 255))
+    return (held == 1) & jnp.logical_not(cut)
+
+
 def _decode_kernel(layer_ref, slot_ref, group_ref, bits_ref, first_ref,
                    count_ref, tail_ref,                     # scalar prefetch
                    q_ref, k_ref, v_ref,                     # inputs
@@ -261,16 +291,8 @@ def _decode_kernel(layer_ref, slot_ref, group_ref, bits_ref, first_ref,
             qb_scr[h] = jnp.broadcast_to(q_ref[0, :, h:h + 1],
                                          (hd, GROUP_ROWS))
 
-    # which lanes are this slot's live rows
-    shape = (n_heads, GROUP_ROWS)
-    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    shift = block_size.bit_length() - 1
-    lane_sub = lane >> shift
-    held = (jnp.full(shape, bits_ref[i], jnp.int32) >> lane_sub) & 1
     tail = jnp.where(is_last, tail_ref[b], GROUP_ROWS * 256)
-    cut = (lane_sub == (tail >> 8)) & ((lane & (block_size - 1))
-                                       >= (tail & 255))
-    valid = (held == 1) & jnp.logical_not(cut)
+    valid = _live_lanes((n_heads, GROUP_ROWS), bits_ref[i], tail, block_size)
 
     for h in range(n_heads):
         kf = k_ref[h].astype(jnp.float32)                    # (hd, 128)
@@ -298,14 +320,16 @@ def _decode_kernel(layer_ref, slot_ref, group_ref, bits_ref, first_ref,
         l_ref[0] = l_scr[...]
 
 
-def _dot(a, b, contract: int):
+def _dot(a, b, contract: int, batch: bool = False):
     """``a`` (M, K) times ``b`` contracted over its dimension
     ``contract``, accumulated in float32 and as exact as float32 products
     of the values the operands hold: operands of one type are one product
     (float32 ones at the highest precision); a float32 ``a`` against a
     narrower ``b`` goes in two parts of ``b``'s type (16 bits of its
-    mantissa), so that ``b`` is never converted."""
-    dims = (((1,), (contract,)), ((), ()))
+    mantissa), so that ``b`` is never converted. With ``batch`` both have
+    a leading dimension more, the same, over which the products are
+    independent: ``a`` (N, M, K)."""
+    dims = (((1 + batch,), (contract,)), ((0,), (0,)) if batch else ((), ()))
 
     def dot(x):
         return jax.lax.dot_general(
@@ -455,14 +479,14 @@ def _pool_attention(q, k_pool, v_pool, layer, plan, *, sm_scale: float,
 
 def _unseen_masked(plan, o, m, l, heads_last: bool = False):
     """A slot with no run was never written: take nothing from its rows.
-    ``o`` is ``(B, H, hd)``, or ``(B, hd, H)`` with ``heads_last``."""
-    seen = (plan["count"] > 0)[:, None]
-    wide = seen[..., None]
+    ``o`` is ``(B, H, ..., hd)``, or ``(B, hd, H)`` with ``heads_last``;
+    ``m`` and ``l`` ``(B, H, ..., 128)``, the statistic on every lane."""
+    seen = (plan["count"] > 0).reshape((-1,) + (1,) * (m.ndim - 2))
     if heads_last:
         o = jnp.transpose(o, (0, 2, 1))
-    return (jnp.where(wide, o, 0.0),
-            jnp.where(seen, m[:, :, 0], DEFAULT_MASK_VALUE),
-            jnp.where(seen, l[:, :, 0], 0.0))
+    return (jnp.where(seen[..., None], o, 0.0),
+            jnp.where(seen, m[..., 0], DEFAULT_MASK_VALUE),
+            jnp.where(seen, l[..., 0], 0.0))
 
 
 def paged_attention_decode(q, k_new, v_new, k_pool, v_pool, layer, plan,
@@ -492,6 +516,146 @@ def paged_attention_decode(q, k_new, v_new, k_pool, v_pool, layer, plan,
     out = ((w_pool * o + w_new * v_new.astype(jnp.float32))
            / (w_pool * l[..., None] + w_new))
     return jnp.where((lengths > 0)[:, None, None], out, 0.0).astype(q.dtype)
+
+
+#: Queries of one slot a grid step of the extend kernel takes at most.
+EXTEND_QUERIES = 128
+
+
+def _extend_kernel(layer_ref, slot_ref, group_ref, bits_ref, first_ref,
+                   count_ref, tail_ref,                     # scalar prefetch
+                   q_ref, k_ref, v_ref,                     # inputs
+                   o_ref, m_ref, l_ref,                     # outputs
+                   acc_scr, m_scr, l_scr,
+                   *, sm_scale: float, block_size: int):
+    """One run against a tile of one slot's queries: 128 rows of K and V,
+    ``(H, hd, 128)`` each, and ``(H, T, hd)`` queries. Every query sees
+    every live row (the pool holds only keys before the span). The heads
+    are the batch dimension of both products, so the body holds two
+    products whatever the heads: a program lowers the kernel anew at each
+    width of the span, and that is set-up time no compile cache saves."""
+    del layer_ref, group_ref                     # the index maps read them
+    i = pl.program_id(1)
+    b = slot_ref[i]
+    is_last = i == first_ref[b] + count_ref[b] - 1
+    H, T, _ = acc_scr.shape
+
+    @pl.when(i == first_ref[b])
+    def _():
+        m_scr[...] = jnp.full(m_scr.shape, DEFAULT_MASK_VALUE, jnp.float32)
+        l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    tail = jnp.where(is_last, tail_ref[b], GROUP_ROWS * 256)
+    valid = _live_lanes((H, T, GROUP_ROWS), bits_ref[i], tail, block_size)
+    s = _dot(q_ref[0], k_ref[...], 1, batch=True) * sm_scale     # (H, T, 128)
+    s = jnp.where(valid, s, DEFAULT_MASK_VALUE)
+    m_prev = m_scr[...]                                          # (H, T, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=2, keepdims=True)
+    m_scr[...] = m_new
+    acc_scr[...] = acc_scr[...] * alpha + _dot(p, v_ref[...], 2, batch=True)
+
+    @pl.when(is_last)
+    def _():
+        o_ref[0] = acc_scr[...]
+        m_ref[0] = jnp.broadcast_to(m_scr[...], (H, T, GROUP_ROWS))
+        l_ref[0] = jnp.broadcast_to(l_scr[...], (H, T, GROUP_ROWS))
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block_size",
+                                             "interpret"))
+def _extend_pool_attention(q, k_pool, v_pool, layer, plan, *,
+                           sm_scale: float, block_size: int, interpret: bool):
+    """The extend kernel call, as :func:`_pool_attention`'s ``"lanes"``
+    one: ``q`` (B, H, E, hd) over the pool keys the plan lists, each
+    query against all of them, unnormalised: float32 ``o`` (B, H, E, hd),
+    ``m`` and ``l`` (B, H, E). The grid is the tiles of ``T`` queries
+    (the span padded to the sublanes of a tile) outside the runs."""
+    B, H, E, hd = q.shape
+    T = min(-(-E // 16) * 16, EXTEND_QUERIES)
+    pad = -E % T
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    kt = jnp.transpose(k_pool, (0, 2, 3, 1))
+    vt = jnp.transpose(v_pool, (0, 2, 3, 1))
+
+    def slot_map(t, i, layer_r, slot_r, *_):
+        return (slot_r[i], 0, t, 0)
+
+    def pool_map(t, i, layer_r, slot_r, group_r, *_):
+        return (layer_r[0], 0, 0, group_r[i])
+
+    stat = pl.BlockSpec((1, H, T, GROUP_ROWS), slot_map)
+    col = pltpu.VMEM((H, T, 1), jnp.float32)
+    o, m, l = pl.pallas_call(
+        functools.partial(_extend_kernel, sm_scale=sm_scale,
+                          block_size=block_size),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=((E + pad) // T, plan["n_runs"][0]),
+            in_specs=[
+                pl.BlockSpec((1, H, T, hd), slot_map),
+                pl.BlockSpec((None, H, hd, GROUP_ROWS), pool_map),
+                pl.BlockSpec((None, H, hd, GROUP_ROWS), pool_map),
+            ],
+            out_specs=[pl.BlockSpec((1, H, T, hd), slot_map), stat, stat],
+            scratch_shapes=[pltpu.VMEM((H, T, hd), jnp.float32), col, col],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, H, E + pad, hd), jnp.float32)]
+        + [jax.ShapeDtypeStruct((B, H, E + pad, GROUP_ROWS), jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="paged_attn_extend",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), plan["slot"], plan["group"],
+      plan["bits"], plan["first"], plan["count"], plan["tail"], q, kt, vt)
+    return _unseen_masked(plan, o[:, :, :E], m[:, :, :E], l[:, :, :E])
+
+
+def paged_attention_extend(q, k_new, v_new, k_pool, v_pool, layer, plan,
+                           positions, lengths, *, block_size: int,
+                           sm_scale: float | None = None,
+                           interpret: bool = False):
+    """Attention of a span of E queries per slot over positions
+    ``0..length-1``: the extend program's (a suffix after a prefix-cache
+    hit, or a speculative verify).
+
+    ``q`` (B, H, E, hd) sits at ``positions`` (B, E): a slot's real
+    queries are consecutive from ``positions[:, 0]`` up to ``lengths -
+    1``, its padded ones at or past ``lengths``. ``k_new`` / ``v_new``
+    (B, H, E, hd) are the span's K and V in the pool's dtype (as the pool
+    holds them). The positions before the span are read out of layer
+    ``layer`` of the ``"lanes"`` pools ``k_pool`` / ``v_pool`` (L, rows,
+    H, hd) through ``plan`` = :func:`decode_plan` of the block table and
+    the span's first position (0 for an idle slot): every query sees all
+    of them. The span's own keys are merged into each query's softmax
+    from registers, causally by position, as :func:`paged_attention_decode`
+    merges the decoded token's. Returns (B, H, E, hd) in ``q.dtype``; a
+    padded query returns zeros."""
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    o, m, l = _extend_pool_attention(q, k_pool, v_pool, layer, plan,
+                                     sm_scale=sm_scale, block_size=block_size,
+                                     interpret=interpret)
+    positions = positions.astype(jnp.int32)
+    live = positions < lengths[:, None]                           # (B, E)
+    sees = (live[:, None, :] & (positions[:, None, :] <= positions[:, :, None])
+            )[:, None]                                            # (B, 1, E, E)
+    highest = jax.lax.Precision.HIGHEST
+    s_new = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                       k_new.astype(jnp.float32), precision=highest) * sm_scale
+    s_new = jnp.where(sees, s_new, DEFAULT_MASK_VALUE)
+    top = jnp.maximum(m, jnp.max(s_new, axis=-1))                 # (B, H, E)
+    w_pool = jnp.exp(m - top)[..., None]
+    p_new = jnp.where(sees, jnp.exp(s_new - top[..., None]), 0.0)
+    num = w_pool * o + jnp.einsum("bhqk,bhkd->bhqd", p_new,
+                                  v_new.astype(jnp.float32), precision=highest)
+    den = w_pool * l[..., None] + jnp.sum(p_new, axis=-1, keepdims=True)
+    # a real query sees its own key: only a padded one can see nothing
+    out = num / jnp.where(den > 0, den, 1.0)
+    return jnp.where(live[:, None, :, None], out, 0.0).astype(q.dtype)
 
 
 def _decode_latent_kernel(layer_ref, slot_ref, rows_ref, blocks_ref,

@@ -31,12 +31,14 @@ tree (stacked-layers layout) and the block-allocated KV pool
   tokens a sequence.
 - :func:`make_extend_fn` — the MULTI-token cache-aware forward: E new
   tokens per slot at explicit absolute positions, written then attended
-  against each slot's block window. This is both the prefix-cache
-  *start-offset prefill* (a prompt whose first C tokens hash-matched
-  cached blocks runs only the suffix through it) and the speculative-
-  decoding *verify* step (the target model scores the draft's k tokens
-  plus the bonus position in one forward). At E=1 it is exactly
-  :func:`make_decode_fn`.
+  against each slot's keys (the window gather, or where prefill writes
+  by blocks a kernel that reads the keys before the span through the
+  block table and merges the span's own from registers). This is both
+  the prefix-cache *start-offset prefill* (a prompt whose first C tokens
+  hash-matched cached blocks runs only the suffix through it) and the
+  speculative-decoding *verify* step (the target model scores the
+  draft's k tokens plus the bonus position in one forward). At E=1 it is
+  exactly :func:`make_decode_fn`.
 
 A model with latent attention (``cfg.latent``: one row a token and
 cache layer shared by all heads, in the pool array ``latent``) and
@@ -52,11 +54,11 @@ quantize on the way in, gathers dequantize on the way out, so the whole
 quantisation story lives in :func:`_pool_write` / :func:`_pool_window`
 and the attention math never sees anything but the compute dtype.
 
-Everything but the paged decode path and the block write of prefill
-and extend is plain jnp (no Pallas custom calls), so on a serving mesh
-GSPMD partitions the programs directly: slots over ``dp``,
-heads/mlp/vocab over ``tp`` (:func:`param_shardings`), the pool laid out
-by ``kv_cache.pool_shardings``. GSPMD cannot partition a
+Everything but the paged decode path, the paged read of extend and the
+block write of prefill and extend is plain jnp (no Pallas custom calls),
+so on a serving mesh GSPMD partitions the programs directly: slots over
+``dp``, heads/mlp/vocab over ``tp`` (:func:`param_shardings`), the pool
+laid out by ``kv_cache.pool_shardings``. GSPMD cannot partition a
 ``pallas_call``, so an engine on a mesh asks for the plain paths
 (``"window"``, ``"scatter"``).
 """
@@ -816,15 +818,22 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
     """``extend(params, pool, tokens, positions, lengths, write_rows,
     window_rows)`` → ``(logits, pool)`` — E tokens per slot in one
     cache-aware forward; ``extend.kv_write`` and ``implementation`` as
-    :func:`make_prefill_fn`'s (the write of every layer goes that way;
-    the read is the window gather on either).
+    :func:`make_prefill_fn`'s, and ``extend.kv_read`` says how the
+    layers read the keys before the span: ``"paged"`` where they write by
+    blocks (the ``"lanes"`` layout), one kernel call a layer that reads
+    the slot's blocks through its table where they lie
+    (``paged_attention.paged_attention_extend``); ``"window"`` otherwise,
+    a gather of each slot's whole window out of the layer
+    (``_pool_window``), which on that layout relays the layer.
 
     ``tokens`` (B, E) the new tokens (right-padded), ``positions``
-    (B, E) their ABSOLUTE cache positions (padded entries must point at
-    or past ``lengths`` so the factored mask zeroes them), ``lengths``
-    (B,) the post-write visible length, ``write_rows`` (B, E) flat pool
-    rows (padded entries at the trash block), ``window_rows`` (B, W)
-    the block-window gather index. Returns logits for ALL E positions
+    (B, E) their ABSOLUTE cache positions, a slot's real ones consecutive
+    up to ``lengths - 1`` (padded entries must point at or past
+    ``lengths`` so the factored mask zeroes them), ``lengths`` (B,) the
+    post-write visible length, ``write_rows`` (B, E) flat pool rows
+    (padded entries at the trash block), ``window_rows`` (B, W) the
+    block-window gather index, whose every ``block_size``-th row names a
+    block of the slot's table. Returns logits for ALL E positions
     — row ``i`` is the next-token distribution after the token fed at
     ``positions[:, i]``.
 
@@ -834,8 +843,8 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
     banked token plus k draft proposals, scored in one step). Per-query
     math is position-independent, so row 0 of a (B, E) extend is
     bitwise the row a (B,) decode on the window path produces at the
-    same position (on the paged path the two agree to float32 rounding
-    before the output cast: the kernel's softmax is online) — the
+    same position (on the paged paths the two agree to float32 rounding
+    before the output cast: the kernels' softmax is online) — the
     greedy-parity contract extends to both callers."""
     if not cfg.causal:
         raise ValueError("extend requires a causal model; serve "
@@ -845,6 +854,9 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
         return _make_latent_extend_fn(cfg, cache_cfg, implementation)
     quantized = cache_cfg.quantized if cache_cfg is not None else False
     write = _block_writer(implementation, cache_cfg)
+    # the kernel reads the pool the block writer writes: rows on the lanes
+    paged = write is not None
+    interpret = implementation == "interpret"
 
     def extend(params, pool, tokens, positions, lengths, write_rows,
                window_rows):
@@ -852,8 +864,14 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
         B, E = tokens.shape
         x = params["embed"].astype(dt)[tokens]          # (B, E, D)
         rows = write_rows.reshape(-1)                   # (B*E,)
-        if write is not None:
+        if paged:
+            bs = cache_cfg.block_size
             plan = _write_plan(rows, cache_cfg)         # every layer's
+            with jax.named_scope("kv.gather"):
+                # the keys already in the pool: those before the span
+                read = paged_attention.decode_plan(
+                    window_rows[:, ::bs] // bs,
+                    jnp.minimum(positions[:, 0], lengths), block_size=bs)
 
         def layer(x, pool, p, cl):
             h = _rms_norm(x, p["RMSNorm_0"]["scale"], dt, cfg.norm_eps)
@@ -863,20 +881,30 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
             v = _heads(h, att["value"].astype(dt), cfg.n_heads)
             q = rotary_at(q, positions, base=cfg.rope_base)  # (B, H, E, hd)
             k = rotary_at(k, positions, base=cfg.rope_base)
-            # write THEN gather: query i must see keys 0..i of the span
             flat_k = k.transpose(0, 2, 1, 3).reshape(B * E, k.shape[1],
                                                      k.shape[3])
             flat_v = v.transpose(0, 2, 1, 3).reshape(B * E, v.shape[1],
                                                      v.shape[3])
-            if write is not None:
+            if paged:
                 pool = write(pool, flat_k[None], flat_v[None], plan, cl)
+                # the span's own K and V as the pool holds them: merged
+                # into the softmax from registers; the kernel reads the
+                # rows before the span, which the write leaves as they are
+                with jax.named_scope("kv.gather"):
+                    o = paged_attention.paged_attention_extend(
+                        q, k.astype(pool["k"].dtype),
+                        v.astype(pool["v"].dtype), pool["k"], pool["v"],
+                        cl, read, positions, lengths, block_size=bs,
+                        interpret=interpret)
             else:
+                # write THEN gather: query i must see keys 0..i of the span
                 pool = _pool_write(pool, cl, rows, flat_k, flat_v,
                                    quantized)
-            kw, vw = _pool_window(pool, cl, window_rows, dt, quantized)
-            with jax.named_scope("attn"):
-                o = mha_reference(q, kw, vw, causal=True, lengths=lengths,
-                                  q_positions=positions)  # (B, H, E, hd)
+                kw, vw = _pool_window(pool, cl, window_rows, dt, quantized)
+                with jax.named_scope("attn"):
+                    o = mha_reference(q, kw, vw, causal=True,
+                                      lengths=lengths,
+                                      q_positions=positions)
             o = jnp.einsum("bhsk,hkd->bsd", o, att["out"].astype(dt))
             x = x + _post_norm(cfg, p, "post_attn_norm", o)
             return _mlp_residual(cfg, p, x), pool, None
@@ -884,7 +912,8 @@ def make_extend_fn(cfg: TransformerConfig, cache_cfg=None, *,
         x, pool, _ = _run_stack(cfg, params, x, pool, layer)
         return _logits(cfg, params, x), pool
 
-    extend.kv_write = "scatter" if write is None else "paged"
+    extend.kv_write = "paged" if paged else "scatter"
+    extend.kv_read = "paged" if paged else "window"
     return extend
 
 
@@ -1194,6 +1223,7 @@ def _make_latent_extend_fn(cfg: TransformerConfig, cache_cfg,
         return _logits(cfg, params, x), pool, _counts(outs)
 
     extend.kv_write = "scatter"
+    extend.kv_read = "window"
     extend.counts = True
     return extend
 

@@ -357,6 +357,9 @@ class InferenceEngine:
         #: ``program``
         self.kv_write = {"prefill": prefill.kv_write,
                          "extend": extend.kv_write if extend else None}
+        #: how extend's layers read the keys before the span ("paged":
+        #: through the block table, where they lie / "window")
+        self.kv_read = extend.kv_read if extend else None
         #: the held experts a program run could touch (every expert
         #: layer's), for the spans' ``experts_held``; None without any
         self._experts_held = (cfg.experts.held * cfg.n_layers
@@ -921,9 +924,9 @@ class InferenceEngine:
         already-generated tokens, always fits). Prefix-cache hit: only
         the unmatched suffix runs, through the multi-token ``extend``
         program at a power-of-two bucket width (bounded recompiles),
-        attending the cached blocks through the normal block-window
-        gather — the start-offset path that turns repeated-prefix
-        traffic into O(suffix) prefill."""
+        attending the cached blocks as ``kv_read`` says (in place through
+        the block table, or the block-window gather) — the start-offset
+        path that turns repeated-prefix traffic into O(suffix) prefill."""
         rid = seq.request.id
         submit_mono = self._submit_mono.get(rid)
         queue_wait = (seq.admitted_s - submit_mono
@@ -943,6 +946,7 @@ class InferenceEngine:
                               if queue_wait is not None else None),
                 replayed=len(seq.request.generated_prefix) or None,
                 program=program, kv_write=self.kv_write[program],
+                kv_read=self.kv_read if C else None,
                 blocks_written=((seq.prompt_len - 1) // bs - C // bs + 1
                                 if C else len(seq.table.blocks)),
                 passes=self.prefill_passes):

@@ -1,7 +1,7 @@
 """The paged paths: ``ops/paged_attention.py``, the decode program
 ``serving/decode.make_decode_fn`` builds from it, and the admission
 programs (``make_prefill_fn``, ``make_extend_fn``) that write the pool
-by blocks.
+by blocks, and extend's read of the keys before its span in place.
 
 On the CPU the kernels run in interpret mode. The contract is the window
 path's: the same keys attended with the same float32 arithmetic
@@ -43,8 +43,9 @@ def _pool(rng, dtype):
             for n in ("k", "v")}
 
 
-def _tables(rng, lengths, order="scattered", share=()):
-    """Block tables (B, MAX_BLOCKS) for ``lengths``: blocks handed out
+def _tables(rng, lengths, order="scattered", share=(),
+            max_blocks=MAX_BLOCKS):
+    """Block tables (B, max_blocks) for ``lengths``: blocks handed out
     ``contiguous``, ``scattered`` or ``descending``; ``share`` lists
     (slot, from_slot, n_blocks) prefix sharing."""
     free = list(range(1, NB))
@@ -52,7 +53,7 @@ def _tables(rng, lengths, order="scattered", share=()):
         free = list(rng.permutation(free))
     elif order == "descending":
         free.reverse()
-    table = np.full((len(lengths), MAX_BLOCKS), TRASH_BLOCK, np.int32)
+    table = np.full((len(lengths), max_blocks), TRASH_BLOCK, np.int32)
     for b, n in enumerate(lengths):
         for j in range(-(-int(n) // BS)):
             table[b, j] = free.pop(0)
@@ -126,6 +127,82 @@ def test_kernel_whatever_the_blocks_order(order):
     out = _paged(pool, 1, q, k_new, v_new, lengths, table)
     ref = _reference(pool, 1, q, k_new, v_new, lengths, table)
     np.testing.assert_allclose(out, ref, **TOL[jnp.float32])
+
+
+def _extend_case(rng, order, E, dtype):
+    """Four slots extended by spans of up to ``E`` tokens (padded to
+    ``E``) over 16-block windows: a prefix that ends mid-block, a slot
+    with nothing in the pool yet (count 0: verify's idle or fresh slot),
+    a one-token span, and a slot that shares the first's first two blocks
+    and ends mid-block in its own copy of the third. Returns ``(pool,
+    table, positions, lengths, write rows, q, k, v)``, the span's K and V
+    as the pool holds them."""
+    prefix = [40, 0, 17, 45]
+    spans = [E - 3, E // 2, 1, E // 4]
+    lengths = np.array([p + s for p, s in zip(prefix, spans)], np.int32)
+    table = _tables(rng, lengths, order=order, share=[(3, 0, 2)],
+                    max_blocks=16)
+    W = 16 * BS
+    positions = np.full((4, E), W, np.int32)             # pad -> masked
+    rows = np.zeros((4, E), np.int32)                    # pad -> trash row
+    for b, (p, n) in enumerate(zip(prefix, spans)):
+        positions[b, :n] = np.arange(p, p + n)
+        rows[b, :n] = table[b, positions[b, :n] // BS] * BS + \
+            positions[b, :n] % BS
+    q, k, v = (jnp.asarray(rng.standard_normal((4, H, E, HD)), dtype)
+               for _ in range(3))
+    return _pool(rng, dtype), table, positions, lengths, rows, q, k, v
+
+
+@pytest.mark.parametrize("E", [8, 16, 64, 160])
+@pytest.mark.parametrize("order", ["contiguous", "scattered", "descending"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_extend_kernel_matches_window_reference(dtype, order, E):
+    """``paged_attention_extend`` (the kernel over the keys before each
+    span, the span's own merged after it) against the window path of the
+    extend program: the span written into the layer, the whole window
+    gathered, ``mha_reference`` causal by position. Queries given in
+    float32 so that both sides return what they computed before the
+    output cast: the same float32 arithmetic but for the online softmax.
+    In the pool's dtype, the output agrees to one rounding of it. Padded
+    queries return zeros; 160 queries are two tiles of the kernel's grid.
+    The kernel never reads a row the span writes: handed the pool before
+    the write, it returns the same bits."""
+    rng = np.random.default_rng(E)
+    pool, table, positions, lengths, rows, q, k, v = _extend_case(
+        rng, order, E, dtype)
+    flat = jnp.asarray(rows.reshape(-1))
+    written = decode_lib._pool_write(
+        pool, 1, flat, k.transpose(0, 2, 1, 3).reshape(-1, H, HD),
+        v.transpose(0, 2, 1, 3).reshape(-1, H, HD), False)
+    window = (table[:, :, None] * BS + np.arange(BS)).reshape(4, -1)
+    kw, vw = decode_lib._pool_window(written, 1, jnp.asarray(window),
+                                     jnp.float32, False)
+    pos, lens = jnp.asarray(positions), jnp.asarray(lengths)
+    plan = pa.decode_plan(jnp.asarray(table), jnp.minimum(pos[:, 0], lens),
+                          block_size=BS)
+    assert list(np.asarray(plan["count"]) > 0) == [True, False, True, True]
+
+    def paged(q, pool):
+        return pa.paged_attention_extend(
+            q, k, v, pool["k"], pool["v"], 1, plan, pos, lens,
+            block_size=BS, interpret=True)
+
+    for q_in, tol in ((q.astype(jnp.float32), TOL[jnp.float32]),
+                      (q, TOL[dtype])):
+        ref = mha_reference(q_in, kw.astype(q_in.dtype),
+                            vw.astype(q_in.dtype), causal=True,
+                            lengths=lens, q_positions=pos)
+        out = paged(q_in, written)
+        assert out.dtype == q_in.dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), **tol)
+    padded = positions >= lengths[:, None]
+    assert padded.any() and not np.any(
+        np.asarray(out, np.float32).transpose(0, 2, 1, 3)[padded])
+    np.testing.assert_array_equal(np.asarray(paged(q, pool), np.float32),
+                                  np.asarray(out, np.float32))
 
 
 @pytest.mark.parametrize("order", ["contiguous", "scattered", "descending"])
@@ -397,9 +474,14 @@ def _admissions(cc):
 @pytest.mark.parametrize("kv_dtype", ["f32", "bf16"])
 def test_admission_programs_match_the_scatter_path(tiny, kv_dtype):
     """``make_prefill_fn`` and ``make_extend_fn`` both ways over one
-    pool: the same logits, and the same pool on every row outside the
-    trash block, bit for bit (the rows are a cast of the same K and V;
-    only what lands in the trash block differs: the kernel skips it)."""
+    pool. Prefill: the same logits, and the same pool on every row
+    outside the trash block, bit for bit (the rows are a cast of the same
+    K and V; only what lands in the trash block differs: the kernel skips
+    it). Extend, whose layers read the keys before the span in place on
+    the paged path: the same logits to float32 rounding (the kernel's
+    softmax is online), the first layer's rows bit for bit, a later
+    layer's (which follow the earlier layers' attention) to one rounding
+    of the pool's dtype, and every row it does not write as it was."""
     cfg, params = tiny
     params = decode_lib.canonical_params(cfg, params)
     cc = CacheConfig.for_model(cfg, num_blocks=32, block_size=8,
@@ -412,20 +494,33 @@ def test_admission_programs_match_the_scatter_path(tiny, kv_dtype):
         extend = decode_lib.make_extend_fn(cfg, cc, implementation=impl)
         want = "scatter" if impl == "scatter" else "paged"
         assert (prefill.kv_write, extend.kv_write) == (want, want)
+        assert extend.kv_read == ("window" if impl == "scatter"
+                                  else "paged")
         last, pool = jax.jit(prefill)(params, dict(start), *prefill_args)
         logits, after = jax.jit(extend)(params, pool, *extend_args)
         outs[impl] = (np.asarray(last), np.asarray(logits), pool, after)
     plain, paged = outs["scatter"], outs["interpret"]
     np.testing.assert_array_equal(paged[0], plain[0])
-    np.testing.assert_array_equal(paged[1][:, :5], plain[1][:, :5])
+    np.testing.assert_allclose(paged[1][:, :5], plain[1][:, :5],
+                               rtol=2e-5, atol=2e-5)
     live = cc.block_size                           # rows past the trash block
-    for a, b in ((paged[2], plain[2]), (paged[3], plain[3])):
-        for n in ("k", "v"):
-            np.testing.assert_array_equal(
-                np.asarray(a[n], np.float32)[:, live:],
-                np.asarray(b[n], np.float32)[:, live:])
-            assert np.any(np.asarray(a[n], np.float32)[:, live:]
-                          != np.asarray(start[n], np.float32)[:, live:])
+    written = np.asarray(extend_args[3]).reshape(-1)
+    written = written[written >= live]
+    kept = np.setdiff1d(np.arange(live, start["k"].shape[1]), written)
+    tol = (TOL[jnp.bfloat16] if kv_dtype == "bf16"
+           else dict(rtol=2e-5, atol=2e-5))
+    for n in ("k", "v"):
+        (pre, post), (pre_s, post_s) = (
+            [np.asarray(x[n], np.float32) for x in out[2:]]
+            for out in (paged, plain))
+        np.testing.assert_array_equal(pre[:, live:], pre_s[:, live:])
+        np.testing.assert_array_equal(post[:, kept], post_s[:, kept])
+        np.testing.assert_array_equal(post[0, written], post_s[0, written])
+        np.testing.assert_allclose(post[1:, written], post_s[1:, written],
+                                   **tol)
+        first = np.asarray(start[n], np.float32)
+        assert np.any(pre[:, live:] != first[:, live:])
+        assert np.any(post[:, written] != pre[:, written])
     # the kernel leaves the trash block as it was
     np.testing.assert_array_equal(
         np.asarray(paged[3]["k"], np.float32)[:, :live],
@@ -491,8 +586,10 @@ def test_engine_tokens_equal_on_both_paths(tiny, scenario, monkeypatch):
 def test_engine_tokens_equal_on_both_write_paths(tiny, scenario,
                                                  monkeypatch):
     """A cold prompt, a prefix hit (extend) and a preempted replay
-    through admission programs that scatter and that write by blocks:
-    the rows are the same bits, so the tokens are the same."""
+    through admission programs that scatter and read the window, and
+    that write by blocks and read the blocks in place: the rows are the
+    same bits (an extend's later layers the same to float32 rounding),
+    so the tokens are the same."""
     cfg, params = tiny
     spec = SCENARIOS[scenario]
     outs, engines = {}, {}
@@ -504,6 +601,8 @@ def test_engine_tokens_equal_on_both_write_paths(tiny, scenario,
                                            "extend": "scatter"}
     assert engines["interpret"].kv_write == {"prefill": "paged",
                                              "extend": "paged"}
+    assert (engines["scatter"].kv_read,
+            engines["interpret"].kv_read) == ("window", "paged")
     assert outs["interpret"] == outs["scatter"]
     e = engines["interpret"]
     acct = e.block_accounting()
@@ -522,17 +621,24 @@ def test_engine_on_the_cpu_takes_the_window_path(tiny):
 
 
 @pytest.mark.parametrize("where,paths", [
-    ("cpu", ("window", "scatter")),
-    ("tpu", ("paged", "paged")),
-    ("tpu, int8 pool", ("window", "scatter")),
-    ("tpu, mesh", ("window", "scatter")),
+    ("cpu", ("window", "scatter", "window")),
+    ("tpu", ("paged", "paged", "paged")),
+    ("tpu, int8 pool", ("window", "scatter", "window")),
+    ("tpu, mesh", ("window", "scatter", "window")),
+    ("tpu, rows pool", ("paged", "scatter", "window")),
+    ("tpu, latent pool", ("paged", "scatter", "window")),
 ])
 def test_engine_chooses_its_paths_by_what_it_sees(tiny, where, paths,
                                                   monkeypatch, request):
     """The kernels' paths only where the backend is a TPU (the builders
     ask ``jax.default_backend``; answered for them here), the pool is
     one a kernel reads, and no mesh would have to partition a
-    ``pallas_call``. Built, not run."""
+    ``pallas_call``: ``paths`` are decode's ``kv_path``, the admission
+    programs' ``kv_write`` and extend's ``kv_read``. Extend reads in
+    place, and both admissions write by blocks, only where the pool lies
+    with its rows on the lanes; a row-major pool (heads of 128) and a
+    latent one take XLA's scatter and the window gather, which read and
+    write those layouts where they lie. Built, not run."""
     cfg, params = tiny
     kw = dict(num_blocks=32, block_size=8, max_slots=4)
     if "tpu" in where:
@@ -541,9 +647,52 @@ def test_engine_chooses_its_paths_by_what_it_sees(tiny, where, paths,
         kw["kv_dtype"] = "int8"
     if "mesh" in where:
         kw["mesh"] = request.getfixturevalue("mesh2d")
+    if "rows" in where:
+        cfg = TransformerConfig.tiny(max_seq_len=64, d_model=1024,
+                                     n_heads=8)
+        params = TransformerLM(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    if "latent" in where:
+        from distributed_tensorflow_tpu.models import scmoe
+        cfg = TransformerConfig(
+            vocab_size=128, d_model=64, n_layers=1, n_heads=4, d_ff=96,
+            max_seq_len=64, dtype=jnp.float32, param_dtype=jnp.float32,
+            tie_embeddings=False, sub_blocks=2,
+            latent=dict(q_rank=32, kv_rank=16, nope_dim=16, rope_dim=8,
+                        v_dim=16),
+            experts=dict(n_routed=8, n_identity=4, top_k=2, d_expert=48,
+                         held=8))
+        params = scmoe.init_params(cfg, jax.random.PRNGKey(0))
     e = InferenceEngine(cfg, params, **kw)
-    assert (e.kv_path, e.kv_write["prefill"], e.kv_write["extend"]) == (
-        paths[0], paths[1], paths[1])
+    assert (e.kv_path, e.kv_write["prefill"], e.kv_write["extend"],
+            e.kv_read) == (paths[0], paths[1], paths[1], paths[2])
+    if "rows" in where or "latent" in where:
+        assert e._kv_layout == where.split()[1]
+
+
+def test_speculative_engine_tokens_equal_on_both_read_paths(tiny,
+                                                            monkeypatch):
+    """Speculative verify is the extend program at (slots, k + 1): on a
+    ``"lanes"`` pool it reads the keys before each slot's span in place,
+    idle slots (nothing in the pool) among them. Drafts, verifies and the
+    prefix hits' suffixes through admission programs that read the
+    window, and that read the blocks: the same greedy tokens."""
+    cfg, params = tiny
+    spec = SCENARIOS["prefix_hit"]
+    outs, engines = {}, {}
+    for admit in ("scatter", "interpret"):
+        e = engines[admit] = _engine(cfg, params, "window", monkeypatch,
+                                     admit=admit, speculative_k=2,
+                                     **spec["engine"])
+        outs[admit] = e.generate(spec["prompts"], max_new_tokens=24)
+    assert (engines["scatter"].kv_read,
+            engines["interpret"].kv_read) == ("window", "paged")
+    assert outs["interpret"] == outs["scatter"]
+    for e in engines.values():
+        assert e._spec_proposed_n > 0
+        assert e.stats()["prefix_cache"]["hit_tokens"] > 0
+        acct = e.block_accounting()
+        assert acct["conserved"] and acct["leaked_refs"] == 0
 
 
 @pytest.mark.parametrize("why,cache,impl", [
@@ -682,26 +831,25 @@ def test_compiled_admission_holds_nothing_of_the_pools_size(
         one_chip, no_compile_cache, program, impl, clean):
     """transformer-big's admission programs at the benchmark's shapes
     (a 1024-wide cold prefill; a 64-wide extend over a 1024-row window),
-    compiled for one v5e chip. Written by blocks, prefill produces no
-    array of the pool's or a layer's size and extend none of the pool's
-    (its window gather still slices a layer out, PERF.md section 7);
-    both hold under a gigabyte of temporaries beside a pool that is
-    updated where it lies. The scatter costs either two copies of each
-    pool."""
+    compiled for one v5e chip. Written by blocks (and extend's keys
+    before the span read through the block table, ``paged_attn_extend``),
+    neither produces an array of the pool's or a layer's size; both hold
+    under a gigabyte of temporaries beside a pool that is updated where
+    it lies. The scatter costs either two copies of each pool."""
     import chip_smoke
 
     cfg, cc, spec, params, pool = _tbig_serving_specs(one_chip)
     rows = cc.num_blocks * cc.block_size
     row = cc.n_heads * cc.head_dim
-    sizes = {cc.n_layers * rows * row}
+    sizes = {cc.n_layers * rows * row, rows * row}
     if program == "prefill":
         fn = decode_lib.make_prefill_fn(cfg, cc, implementation=impl)
         args = (spec((1, 1024)), spec((1,)), spec((1, 1024)))
-        sizes.add(rows * row)
     else:
         fn = decode_lib.make_extend_fn(cfg, cc, implementation=impl)
         args = (spec((1, 64)), spec((1, 64)), spec((1,)), spec((1, 64)),
                 spec((1, 1024)))
+        assert fn.kv_read == ("paged" if clean else "window")
     assert fn.kv_write == impl
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         params, pool, *args).compile()
@@ -709,6 +857,7 @@ def test_compiled_admission_holds_nothing_of_the_pools_size(
     found = chip_smoke.pool_sized_ops(hlo, sizes)
     assert (not found) == clean, found[:5]
     assert ("paged_kv_write" in hlo) == clean
+    assert ("paged_attn_extend" in hlo) == (clean and program == "extend")
     assert not _weight_shaped_work(hlo, params)
     if program == "prefill":
         # the head runs on the one row a prompt's first token needs: a

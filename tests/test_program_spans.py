@@ -226,6 +226,10 @@ def test_engine_step_spans_nest_in_order_with_their_counts(tiny, tmp_path):
     # next one's room in two
     assert (a["kv_write"], a["blocks_written"]) == ("scatter", 1)
     assert (b["kv_write"], b["blocks_written"]) == ("scatter", 2)
+    # and how extend's layers read the keys before its span (the CPU
+    # gathers the window); a cold prefill reads none
+    assert a["kv_read"] == e.kv_read == "window"
+    assert "kv_read" not in b
     assert steps[0][3]["cached_tokens"] == admitted[0] == 16
     for p in prefills:                  # build and launch under each
         sub = [s[0] for s in first if s is not p
@@ -412,6 +416,43 @@ def test_prefix_hits_count_an_admission_once_however_often_deferred(tiny):
     assert st["hit_requests"] == 1 and st["hit_tokens"] == 16
     assert st["lookups"] == 2
     assert st["lookup_tokens"] == len(X) - 1 + len(ask) - 1
+
+
+@pytest.mark.parametrize("admit,read", [("scatter", "window"),
+                                        ("interpret", "paged")])
+def test_extend_span_says_how_its_layers_read_the_pool(tiny, tmp_path,
+                                                       monkeypatch, admit,
+                                                       read):
+    """``serve.prefill`` says, for an extend and only for one, how its
+    layers read the keys before the span: what the engine's extend
+    program was built to do (a TPU reads a pool that lies with its rows
+    on the lanes in place; here the builders are steered to the window
+    or to the kernels, interpreted)."""
+    from distributed_tensorflow_tpu.serving import decode as decode_lib
+    from distributed_tensorflow_tpu.serving import engine as engine_lib
+    for name in ("make_prefill_fn", "make_extend_fn"):
+        monkeypatch.setattr(
+            engine_lib.decode_lib, name,
+            lambda c, cc, implementation=None, real=getattr(decode_lib, name):
+            real(c, cc, implementation=admit))
+    e = _engine(tiny)
+    monkeypatch.undo()
+    assert e.kv_read == read
+    log = telemetry.configure(str(tmp_path), process_id=0)
+    try:
+        e.submit(Request(id="doc", tokens=tuple(X), max_new_tokens=2))
+        e.run_until_idle()
+        e.submit(Request(id="ask", tokens=tuple(X + [40, 41]),
+                         max_new_tokens=2))
+        e.run_until_idle()
+    finally:
+        telemetry.shutdown()
+    prefills = {r["id"]: r for r in telemetry.read_events(log.path)
+                if r.get("ev") == "serve.prefill"}
+    assert (prefills["doc"]["program"], prefills["doc"]["kv_read"]) == (
+        "prefill", None)
+    assert (prefills["ask"]["program"], prefills["ask"]["kv_read"]) == (
+        "extend", read)
 
 
 def test_ttft_runs_from_submit_and_queue_wait_says_how_much(tiny,
